@@ -1,0 +1,783 @@
+#!/usr/bin/env python3
+"""Chip smoke: the trainer and the serve engine, once, on the TPU.
+
+    python chip_smoke.py             # one chip: train phase + serve phase
+    python chip_smoke.py --chips 4   # four chips: the mesh phase, only
+
+Drives the system through the entry points a user calls
+(``unicore_tpu_cli.train.cli_main``, ``unicore_tpu.serve.cli.main``), in
+this one process — a chip belongs to one process — at the published
+BERT-base width and the ``transformer_lm_base`` width, on seeded
+synthetic data and seeded random weights.  Every phase prints one JSON
+line (also appended to ``chiprun_out/chip_smoke.jsonl``); a failed check
+exits non-zero at once; the last line of a run that passed is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+With no TPU it exits non-zero and runs nothing.  The phase functions
+take their sizes as an argument and check only what holds on any
+backend, so ``tests/test_chip_smoke.py`` rehearses them at tiny widths on
+the CPU; what only a chip can show (a ``tpu`` device, a Pallas kernel in
+the compiled text, memory statistics, cache hits) is checked in ``main``.
+"""
+
+import argparse
+import contextlib
+import gc
+import importlib
+import json
+import os
+import shutil
+import sys
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, REPO)
+
+WORK = os.path.join(REPO, ".chip_smoke")
+REPORT = os.path.join(REPO, "chiprun_out", "chip_smoke.jsonl")
+
+# BERT-base as published (12 x 768 x 3072, 12 heads, 30,522 word pieces:
+# 4 specials + 30,517 symbols + [MASK]), seq 512, global batch 32
+BERT = dict(
+    layers=12, dim=768, ffn=3072, heads=12, seq=512, batch=32,
+    symbols=30517, updates=6, save_at=3, mesh_updates=3,
+)
+# transformer_lm_base (12 x 768, 12 heads, head dim 64), served from a
+# checkpoint of 3 updates
+LM = dict(
+    layers=12, dim=768, ffn=3072, heads=12, seq=512, batch=8,
+    symbols=8188, updates=3,
+    page_size=64, num_pages=64, max_batch=8, prefill_chunk=64,
+    max_new_tokens=8, prefix_len=128,
+    # mixed prompt lengths, three of them longer than the prefill chunk
+    prompt_lens=(5, 17, 33, 40, 64, 70, 100, 150),
+)
+# |loss(variant) - loss(one device)| <= LOSS_RTOL * |loss(one device)| on
+# every update: same data, same init, no dropout; what differs is the
+# order of bf16 reductions and, under tp, the attention path (einsum in
+# place of the flash kernel)
+LOSS_RTOL = 2e-2
+# an engine token that is not the full forward's argmax must be within
+# this much of it in logit: two programs that round differently may
+# break an exact tie differently, nothing more
+LOGIT_TIE_TOL = 2e-2
+
+
+class SmokeFailure(AssertionError):
+    pass
+
+
+def check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def emit(phase, **fields):
+    line = json.dumps({"phase": phase, **fields}, default=str)
+    print(line, flush=True)
+    os.makedirs(os.path.dirname(REPORT), exist_ok=True)
+    with open(REPORT, "a") as f:
+        f.write(line + "\n")
+
+
+# -- compile accounting ------------------------------------------------
+
+class _Compiles:
+    """jax's own monitoring events: persistent-cache hits and the
+    seconds of every backend compile."""
+
+    def __init__(self):
+        self.hits = 0
+        self.seconds = []
+
+    def on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+    def on_duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds.append(duration)
+
+
+_COMPILES = None
+
+
+def compiles():
+    global _COMPILES
+    if _COMPILES is None:
+        import jax
+
+        _COMPILES = _Compiles()
+        jax.monitoring.register_event_listener(_COMPILES.on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _COMPILES.on_duration)
+    return _COMPILES
+
+
+# -- data --------------------------------------------------------------
+
+def write_corpus(data_dir, seed, symbols, seq, n_train, n_valid):
+    """A seeded corpus in the repo's own format: ``dict.txt`` plus
+    ``{train,valid}.rec`` of token lists, Zipf-distributed, most of them
+    as long as the sequence allows."""
+    import numpy as np
+
+    from unicore_tpu.data import IndexedRecordWriter
+
+    os.makedirs(data_dir, exist_ok=True)
+    words = ["w%d" % i for i in range(symbols)]
+    with open(os.path.join(data_dir, "dict.txt"), "w") as f:
+        for i, w in enumerate(words):
+            f.write(f"{w} {symbols - i}\n")
+    rng = np.random.RandomState(seed)
+    p = 1.0 / np.arange(1, symbols + 1)
+    p /= p.sum()
+    for split, n in (("train", n_train), ("valid", n_valid)):
+        with IndexedRecordWriter(os.path.join(data_dir, split + ".rec")) as w:
+            for _ in range(n):
+                length = int(rng.randint(max(2, seq // 2), seq - 1))
+                w.write([words[i] for i in rng.choice(symbols, length, p=p)])
+    return data_dir
+
+
+# -- the train entry point, in process ---------------------------------
+
+def _import_example(name):
+    """``--user-dir examples/<name>`` imports the example under its bare
+    name, the serve loader imports it as ``examples.<name>``.  In one
+    process both must be ONE module, or its ``@register_*`` run twice."""
+    mod = importlib.import_module(f"examples.{name}")
+    sys.modules.setdefault(name, mod)
+
+
+@contextlib.contextmanager
+def recording_trainer():
+    """Let ``cli_main`` build its Trainer as always, and keep hold of
+    it: the per-update losses, the compiled step and the state are what
+    the checks read."""
+    import unicore_tpu_cli.train as train_cli
+
+    made = []
+
+    class Recording(train_cli.Trainer):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            self.smoke_losses = []
+            made.append(self)
+
+        def _reduce_and_log_stats(self, *a, **kw):
+            out = super()._reduce_and_log_stats(*a, **kw)
+            self.smoke_losses.append(float(out["loss"]))
+            return out
+
+    orig = train_cli.Trainer
+    train_cli.Trainer = Recording
+    try:
+        yield made
+    finally:
+        train_cli.Trainer = orig
+
+
+def run_train_cli(argv):
+    """``unicore-train <argv>`` in this process; returns its Trainer."""
+    import unicore_tpu_cli.train as train_cli
+
+    saved = sys.argv
+    sys.argv = ["unicore-train"] + [str(a) for a in argv]
+    try:
+        with recording_trainer() as made:
+            train_cli.cli_main()
+    finally:
+        sys.argv = saved
+    check(len(made) == 1, f"cli_main built {len(made)} trainers")
+    return made[0]
+
+
+def _common_train_args(data_dir, save_dir, size, updates):
+    return [
+        data_dir, "--valid-subset", "valid", "--num-workers", "0",
+        "--optimizer", "adam", "--adam-betas", "(0.9, 0.98)",
+        "--adam-eps", "1e-6", "--clip-norm", "1.0",
+        "--lr-scheduler", "polynomial_decay", "--lr", "1e-4",
+        "--warmup-updates", "2", "--total-num-update", "1000",
+        "--batch-size", size["batch"], "--max-seq-len", size["seq"],
+        "--required-batch-size-multiple", "1",
+        "--update-freq", "1", "--seed", "1", "--bf16",
+        "--max-update", updates, "--log-interval", "1",
+        "--log-format", "simple", "--no-progress-bar",
+        "--no-epoch-checkpoints", "--no-last-checkpoints",
+        "--save-dir", save_dir,
+        "--tmp-save-dir", save_dir + "_tmp",
+    ]
+
+
+def _bert_args(data_dir, save_dir, size, updates):
+    return _common_train_args(data_dir, save_dir, size, updates) + [
+        "--user-dir", os.path.join(REPO, "examples", "bert"),
+        "--task", "bert", "--loss", "masked_lm", "--arch", "bert_base",
+        "--pre-tokenized",
+        "--encoder-layers", size["layers"],
+        "--encoder-embed-dim", size["dim"],
+        "--encoder-ffn-embed-dim", size["ffn"],
+        "--encoder-attention-heads", size["heads"],
+    ]
+
+
+def _step_report(trainer):
+    """What the compiled train step and the devices say."""
+    import jax
+
+    text = trainer._compiled_train_step.as_text()
+    leaf = jax.tree_util.tree_leaves(trainer.state["params"])[0]
+    return {
+        "platform": sorted({d.platform for d in leaf.devices()}),
+        "mesh": dict(zip(trainer.mesh.axis_names,
+                         trainer.mesh.devices.shape)),
+        "tpu_custom_call": text.count("tpu_custom_call"),
+        "all_reduce": text.count("all-reduce"),
+        "memory_analysis_gb": trainer._memory_analysis,
+        "bytes_in_use": [
+            (d.memory_stats() or {}).get("bytes_in_use")
+            for d in jax.local_devices()
+        ],
+        "peak_bytes_in_use": [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.local_devices()
+        ],
+    }
+
+
+def _check_losses(losses, updates, what):
+    import math
+
+    check(len(losses) >= updates,
+          f"{what}: {len(losses)} updates logged, wanted {updates}")
+    check(all(math.isfinite(x) for x in losses),
+          f"{what}: non-finite loss in {losses}")
+    check(len(set(losses)) > 1, f"{what}: loss is constant: {losses}")
+
+
+# -- phase: train ------------------------------------------------------
+
+def train_phase(work, seed, size):
+    """BERT MLM through ``unicore-train``: updates, one interval
+    checkpoint, then the compile cache: the step is compiled again after
+    ``jax.clear_caches()`` and the cache's own events say where it came
+    from."""
+    import jax
+
+    from unicore_tpu.distributed import utils as dist_utils
+    from unicore_tpu.ops import backend, tuning
+    from unicore_tpu.ops.tuning import cache as tune_cache
+
+    _import_example("bert")
+    dist_utils.reset_mesh()
+    overlay = tune_cache.TuneCache(paths=[tune_cache.overlay_cache_path()])
+    data = write_corpus(
+        os.path.join(work, "bert_data"), seed, size["symbols"],
+        size["seq"], n_train=size["batch"] * (size["updates"] + 2),
+        n_valid=size["batch"],
+    )
+    save_dir = os.path.join(work, "bert_ckpt")
+    cc = compiles()
+    hits_start, n_start = cc.hits, len(cc.seconds)
+    t0 = time.perf_counter()
+    trainer = run_train_cli(
+        _bert_args(data, save_dir, size, size["updates"]) + [
+            "--save-interval-updates", size["save_at"],
+            "--disable-validation",
+        ])
+    wall = time.perf_counter() - t0
+    first_compile = max(cc.seconds[n_start:])
+    _check_losses(trainer.smoke_losses, size["updates"], "bert")
+    ckpts = sorted(f for f in os.listdir(save_dir) if f.endswith(".pt"))
+    check(any(f"_{size['save_at']}.pt" in f for f in ckpts),
+          f"no checkpoint of update {size['save_at']} in {ckpts}")
+
+    # The same step compiled again from nothing but the persistent
+    # cache: a second, one-update run through the SAME entry point after
+    # jax.clear_caches().  Lowering the step from here, from abstract
+    # arguments, was a miss on the chip (PERF.md, PR 21): a Mosaic
+    # kernel's payload embeds the Python frames it was traced under, so
+    # a program that holds one can key differently by another path.
+    report = _step_report(trainer)
+    losses, updates = trainer.smoke_losses, trainer.get_num_updates()
+    del trainer
+    gc.collect()
+    hits0, n0 = cc.hits, len(cc.seconds)
+    jax.clear_caches()
+    t0 = time.perf_counter()
+    again = run_train_cli(
+        _bert_args(data, save_dir + "_again", size, 1)
+        + ["--no-save", "--disable-validation"])
+    recompile_wall = time.perf_counter() - t0
+    check(again.get_num_updates() == 1, "the second run took no update")
+    del again
+    gc.collect()
+    cache_dir = jax.config.jax_compilation_cache_dir
+    report.update({
+        "updates": updates,
+        "losses": losses,
+        "checkpoints": ckpts,
+        "wall_s": round(wall, 1),
+        "first_compile_s": first_compile,
+        "first_run_cache_hits": hits0 - hits_start,
+        "second_run_wall_s": round(recompile_wall, 1),
+        # the three longest compiles of the second run
+        "second_run_compile_s": sorted(
+            round(x, 2) for x in cc.seconds[n0:])[-3:],
+        "second_run_cache_hits": cc.hits - hits0,
+        "cache_dir": cache_dir,
+        "cache_entries": (len(os.listdir(cache_dir))
+                          if cache_dir and os.path.isdir(cache_dir) else 0),
+        "tuner_mode": tuning.autotune_mode(),
+        # state outside git that steers which program is compiled
+        "tune_overlay_entries": sum(
+            len(es) for es in overlay.all_entries().values()),
+        "kernel_dispatch": backend.dispatch_report(),
+    })
+    shutil.rmtree(save_dir)  # gigabytes at the real width
+    return report
+
+
+# -- phase: serve ------------------------------------------------------
+
+def _prompts(seed, size, vocab):
+    """Mixed-length prompts; the first and the last share a prefix of
+    whole pages.  The last is admitted only when a slot frees (there
+    are more requests than ``max_batch``), by which time the first has
+    registered the prefix."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed + 1)
+
+    def rnd(n):
+        return [int(t) for t in rng.randint(4, vocab, size=(n,))]
+
+    prefix = rnd(size["prefix_len"])
+    prompts = [prefix + rnd(7)]
+    prompts += [rnd(n) for n in size["prompt_lens"]]
+    prompts.append(prefix + rnd(11))
+    return prompts
+
+
+def _teacher_forced(model, params, prompt, tokens, pad_to, pad):
+    """The engine-free full forward over prompt + generated tokens: one
+    causal pass gives the logits every generated token was the argmax
+    of.  Returns (exact matches, worst logit gap of the mismatches)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    seq = list(prompt) + list(tokens)
+    toks = np.full((1, pad_to), pad, np.int32)
+    toks[0, :len(seq)] = seq
+    logits = jax.jit(lambda p, t: model.apply({"params": p}, t))(
+        params, jnp.asarray(toks))
+    logits = np.asarray(logits[0].astype(jnp.float32))
+    exact, worst = 0, 0.0
+    for i, tok in enumerate(tokens):
+        row = logits[len(prompt) - 1 + i]
+        if int(row.argmax()) == tok:
+            exact += 1
+        else:
+            worst = max(worst, float(row.max() - row[tok]))
+    return exact, worst
+
+
+def serve_phase(work, seed, size):
+    """``unicore-train`` on the LM example for a checkpoint, then the
+    ``unicore_tpu.serve`` CLI on it: mixed prompts through chunked
+    prefill, decode and the prefix cache, and two requests compared
+    with the full forward."""
+    from unicore_tpu.deploy import load_serve_model
+    from unicore_tpu.distributed import utils as dist_utils
+    from unicore_tpu.ops import backend
+    from unicore_tpu.serve import cli as serve_cli
+
+    _import_example("lm")
+    dist_utils.reset_mesh()
+    data = write_corpus(
+        os.path.join(work, "lm_data"), seed, size["symbols"], size["seq"],
+        n_train=size["batch"] * (size["updates"] + 2),
+        n_valid=size["batch"],
+    )
+    save_dir = os.path.join(work, "lm_ckpt")
+    trainer = run_train_cli(
+        _common_train_args(data, save_dir, size, size["updates"]) + [
+            "--user-dir", os.path.join(REPO, "examples", "lm"),
+            "--task", "lm", "--loss", "lm_cross_entropy",
+            "--arch", "transformer_lm_base",
+            "--rotary", "True", "--rel-pos", "False", "--abs-pos", "False",
+            "--decoder-layers", size["layers"],
+            "--decoder-embed-dim", size["dim"],
+            "--decoder-ffn-embed-dim", size["ffn"],
+            "--decoder-attention-heads", size["heads"],
+            "--save-interval-updates", size["updates"],
+            "--disable-validation",
+        ])
+    _check_losses(trainer.smoke_losses, size["updates"], "lm")
+    lm_losses = trainer.smoke_losses
+    del trainer
+    gc.collect()
+    ckpt = os.path.join(save_dir, f"checkpoint_1_{size['updates']}.pt")
+    check(os.path.exists(ckpt), f"no {ckpt}")
+
+    dict_path = os.path.join(data, "dict.txt")
+    vocab = size["symbols"] + 4
+    prompts = _prompts(seed, size, vocab)
+    prompts_path = os.path.join(work, "prompts.txt")
+    with open(prompts_path, "w") as f:
+        for p in prompts:
+            f.write(" ".join(map(str, p)) + "\n")
+    out_path = os.path.join(work, "serve_report.json")
+    t0 = time.perf_counter()
+    rc = serve_cli.main([
+        "--checkpoint", ckpt, "--dict", dict_path,
+        "--prompts", prompts_path, "--json", out_path,
+        "--max-new-tokens", str(size["max_new_tokens"]),
+        "--page-size", str(size["page_size"]),
+        "--num-pages", str(size["num_pages"]),
+        "--max-batch", str(size["max_batch"]),
+        "--prefill-chunk", str(size["prefill_chunk"]),
+    ])
+    wall = time.perf_counter() - t0
+    check(rc == 0, f"serve CLI returned {rc}")
+    with open(out_path) as f:
+        rep = json.load(f)
+    results, stats = rep["results"], rep["stats"]
+    check(len(results) == len(prompts) >= 8,
+          f"{len(results)} results for {len(prompts)} requests")
+    reasons = sorted({r["finish_reason"] for r in results})
+    check(set(reasons) <= {"eos", "length"},
+          f"finish reasons {reasons}: wanted eos/length only")
+    check(stats["host_faults"] == 0 and stats["quarantined"] == 0
+          and stats["shed"] == 0, f"faults in {stats}")
+    check(stats["prefix_hits"] >= 1, f"no prefix-cache hit: {stats}")
+    check(rep["pool_clean"], "the pool did not end idle")
+    widths = rep["kernel_dispatch"].get("ragged_paged_attention", {})
+    check(len(widths) >= 2,
+          f"attention path recorded for {sorted(widths)}: wanted the "
+          "decode width and the prefill-chunk width")
+
+    # two requests against the engine-free full forward: a short one and
+    # the prefix-sharing one that was served through the prefix cache
+    model, params = load_serve_model(ckpt, dict_path)
+    pad_to = -(-(max(map(len, prompts)) + size["max_new_tokens"])
+               // 128) * 128
+    pad_to = min(pad_to, model.max_seq_len)
+    compared = {}
+    for idx in (1, len(prompts) - 1):
+        r = results[idx]
+        exact, worst = _teacher_forced(
+            model, params, r["prompt"], r["tokens"], pad_to,
+            model.padding_idx)
+        compared[r["request_id"]] = {
+            "tokens": len(r["tokens"]), "exact": exact,
+            "worst_logit_gap": round(worst, 5)}
+        check(worst <= LOGIT_TIE_TOL,
+              f"{r['request_id']}: an engine token is {worst:.4f} below "
+              f"the full forward's argmax (> {LOGIT_TIE_TOL})")
+    return {
+        "lm_losses": lm_losses,
+        "requests": len(results),
+        "finish_reasons": reasons,
+        "generated_tokens": stats["generated_tokens"],
+        "decode_steps": stats["decode_steps"],
+        "prefills": stats["prefills"],
+        "prefix_hits": stats["prefix_hits"],
+        "host_faults": stats["host_faults"],
+        "quarantined": stats["quarantined"],
+        "wall_s": round(wall, 1),
+        "attention_paths": widths,
+        "kernel_dispatch": backend.dispatch_report(),
+        "vs_full_forward": compared,
+    }
+
+
+# -- phase: mesh (four chips) ------------------------------------------
+
+def mesh_phase(work, seed, size, devices):
+    """BERT on one device and on dp / fsdp / tp meshes over ``devices``:
+    same seed, same global batch, no dropout.  Losses must agree, every
+    device must hold buffers, and the state must really be sharded."""
+    import jax
+
+    from unicore_tpu import parallel
+    from unicore_tpu.distributed import utils as dist_utils
+
+    _import_example("bert")
+    n = len(devices)
+    check(list(jax.devices()) == list(devices),
+          "the mesh phase takes every device jax sees")
+    data = write_corpus(
+        os.path.join(work, "bert_data"), seed, size["symbols"],
+        size["seq"], n_train=size["batch"] * (size["mesh_updates"] + 2),
+        n_valid=size["batch"],
+    )
+    variants = [
+        ("one", devices[:1], []),
+        (f"data{n}", devices, []),
+        ("fsdp2", devices, ["--fsdp-size", "2"]),
+        ("tp2", devices, ["--tensor-parallel-size", "2"]),
+    ]
+    out = {}
+    for name, devs, extra in variants:
+        # the mesh over a subset of the devices is built as
+        # __graft_entry__ builds it; the Trainer finds it installed
+        dist_utils.reset_mesh(dist_utils.get_mesh(None, devices=devs)
+                              if len(devs) < n else None)
+        trainer = run_train_cli(
+            _bert_args(data, os.path.join(work, "mesh_" + name), size,
+                       size["mesh_updates"]) + [
+                "--no-save", "--disable-validation",
+                "--dropout", "0.0", "--emb-dropout", "0.0",
+                "--attention-dropout", "0.0",
+                "--activation-dropout", "0.0",
+            ] + extra)
+        _check_losses(trainer.smoke_losses, size["mesh_updates"], name)
+        rep = _step_report(trainer)
+        rep["losses"] = trainer.smoke_losses
+        state = trainer.state
+        rep["opt_state_sharded"] = any(
+            leaf.ndim >= 1 and not leaf.sharding.is_fully_replicated
+            for leaf in jax.tree_util.tree_leaves(state["opt_state"]))
+        attn = state["params"]["sentence_encoder"]["layers_0"][
+            "self_attn"]["in_proj"]["kernel"]
+        rep["attention_kernel_sharded"] = (
+            not attn.sharding.is_fully_replicated)
+        out[name] = rep
+        del trainer, state, attn
+        parallel.disable_tensor_parallel()
+        dist_utils.reset_mesh()
+        gc.collect()
+
+    base = out["one"]["losses"]
+    for name, rep in out.items():
+        worst = max(abs(a - b) / abs(b)
+                    for a, b in zip(rep["losses"], base))
+        rep["loss_rel_err_vs_one"] = round(worst, 6)
+        check(worst <= LOSS_RTOL,
+              f"{name}: loss {rep['losses']} is {worst:.4f} (rel) from "
+              f"one device's {base} (> {LOSS_RTOL})")
+    check(out["one"]["mesh"]["data"] == 1
+          and out[f"data{n}"]["mesh"]["data"] == n
+          and out["fsdp2"]["mesh"]["fsdp"] == 2
+          and out["tp2"]["mesh"]["tensor"] == 2,
+          f"meshes: { {k: v['mesh'] for k, v in out.items()} }")
+    check(out[f"data{n}"]["all_reduce"] > 0,
+          f"no all-reduce in the compiled data{n} step")
+    check(out["fsdp2"]["opt_state_sharded"],
+          "fsdp2 left the optimizer state replicated")
+    check(out["tp2"]["attention_kernel_sharded"],
+          "tp2 left the attention weights replicated")
+    return out
+
+
+# -- do forked data workers leave the chip's client alone? -------------
+
+def fork_phase(timeout_s=120):
+    """``--worker-impl process`` forks its pool after the parent owns the
+    chip (``data/iterators.py``, one pool per epoch stream).  Touch the
+    device, read an epoch through forked workers, touch the device
+    again: the workers only index and collate numpy, so the client must
+    neither be touched nor broken.  A deadlocked child fails the phase
+    through an alarm instead of hanging the run."""
+    import signal
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from unicore_tpu.data import UnicoreDataset, data_utils, iterators
+
+    class Rows(UnicoreDataset):
+        def __getitem__(self, i):
+            return np.arange(i, i + 8)
+
+        def __len__(self):
+            return 64
+
+        def collater(self, samples):
+            return np.stack(samples)
+
+    def on_alarm(signum, frame):
+        raise SmokeFailure(f"forked data workers hung for {timeout_s}s")
+
+    before = float(jnp.sum(jnp.ones((256, 256)) @ jnp.ones((256, 256))))
+    base = Rows()
+    sampler = data_utils.batch_by_size(np.arange(64), batch_size=8)
+
+    def epoch():
+        it = iterators.EpochBatchIterator(
+            dataset=base, collate_fn=base.collater, batch_sampler=sampler,
+            seed=1, num_workers=2,
+        )
+        return [np.asarray(b).tolist()
+                for b in it.next_epoch_itr(shuffle=True)]
+
+    threads = epoch()
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.alarm(timeout_s)
+    iterators.set_worker_impl("process")
+    try:
+        forked = epoch()
+    finally:
+        iterators.set_worker_impl("thread")
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+    after = float(jnp.sum(jnp.ones((256, 256)) @ jnp.ones((256, 256))))
+    check(forked == threads, "forked workers gave another stream")
+    check(before == after == 256.0 ** 3,
+          f"the device answered {before} before the fork, {after} after")
+    return {"batches": len(forked), "same_stream_as_threads": True,
+            "device_ok_after_fork": True}
+
+
+# -- is block_until_ready honest? --------------------------------------
+
+def barrier_phase(n=8192, reps=8):
+    """Time a chain of large matmuls to its end two ways:
+    ``jax.block_until_ready`` and a fetch of real bytes.  An honest
+    barrier cannot return before the arithmetic can be done."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    @jax.jit
+    def chain(x):
+        for _ in range(reps):
+            x = (x @ x) * (1.0 / n)
+        return x
+
+    x = jnp.ones((n, n), jnp.bfloat16)
+    jax.block_until_ready(chain(x))  # compile
+    times = {"block_until_ready": [], "fetch_bytes": []}
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(x))
+        times["block_until_ready"].append(time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        np.asarray(chain(x)[:1, :1])
+        times["fetch_bytes"].append(time.perf_counter() - t0)
+    return {
+        "matmul": f"{reps} x bf16 {n}^3",
+        "flop": 2.0 * n ** 3 * reps,
+        "block_until_ready_ms": [round(t * 1e3, 2)
+                                 for t in times["block_until_ready"]],
+        "fetch_bytes_ms": [round(t * 1e3, 2)
+                           for t in times["fetch_bytes"]],
+    }
+
+
+# -- main --------------------------------------------------------------
+
+def _chip_checks_step(rep, what, want_kernel=True):
+    check(rep["platform"] == ["tpu"],
+          f"{what}: the step ran on {rep['platform']}, not on a tpu")
+    check(rep["tpu_custom_call"] > 0 or not want_kernel,
+          f"{what}: no tpu_custom_call in the compiled train step — no "
+          "Pallas kernel is in the program")
+    check(rep["memory_analysis_gb"], f"{what}: empty memory analysis")
+    check(all(rep["peak_bytes_in_use"]),
+          f"{what}: memory_stats() came back empty: "
+          f"{rep['peak_bytes_in_use']}")
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=1)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu":
+        sys.stderr.write(
+            f"chip_smoke: no tpu: jax found {dev.platform!r} "
+            f"({dev.device_kind}); nothing was run\n")
+        return 1
+    if len(devices) != args.chips:
+        sys.stderr.write(
+            f"chip_smoke: --chips {args.chips} but jax sees "
+            f"{len(devices)} device(s); nothing was run\n")
+        return 1
+
+    from unicore_tpu.utils import configure_compile_cache
+
+    cache_dir = configure_compile_cache()
+    shutil.rmtree(WORK, ignore_errors=True)
+    os.makedirs(WORK)
+    if os.path.exists(REPORT):
+        os.remove(REPORT)
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices)}
+    emit("start", device=device, jax=jax.__version__, chips=args.chips,
+         compile_cache=cache_dir)
+    try:
+        if args.chips == 4:
+            rep = mesh_phase(WORK, args.seed, BERT, devices)
+            emit("mesh", **rep)
+            for name, r in rep.items():
+                # under tp the attention heads are sharded and take the
+                # einsum path: pallas_call has no partitioning rule
+                _chip_checks_step(r, name, want_kernel=name != "tp2")
+                held = r["bytes_in_use"][:1 if name == "one" else 4]
+                check(all(held), f"{name}: a device holds no buffers: "
+                      f"{r['bytes_in_use']}")
+        else:
+            from bench import PEAK_BF16_FLOPS  # the one table of peaks
+
+            rep = barrier_phase()
+            floor_ms = rep["flop"] / PEAK_BF16_FLOPS[dev.device_kind] * 1e3
+            rep["arithmetic_floor_ms"] = round(floor_ms, 2)
+            rep["honest"] = min(rep["block_until_ready_ms"]) >= floor_ms
+            emit("barrier", **rep)
+            check(rep["honest"],
+                  "block_until_ready returned before the matmuls could "
+                  f"have run: {rep}")
+
+            emit("fork", **fork_phase())
+
+            rep = train_phase(WORK, args.seed, BERT)
+            emit("train", **rep)
+            _chip_checks_step(rep, "bert")
+            check(rep["tune_overlay_entries"] == 0,
+                  f"{rep['tune_overlay_entries']} kernel-tune overlay "
+                  "entries steer this run from outside the checkout")
+            check(rep["cache_entries"] > 0,
+                  f"nothing was written to {rep['cache_dir']}")
+            # a hit is the cache's own event; that it was the train step
+            # (the one long compile) shows in the seconds
+            check(rep["second_run_cache_hits"] >= 1
+                  and max(rep["second_run_compile_s"])
+                  < max(5.0, 0.5 * rep["first_compile_s"]),
+                  "the second run's train step was not served from the "
+                  "persistent cache: "
+                  f"{rep['second_run_cache_hits']} hits, compiles of "
+                  f"{rep['second_run_compile_s']} s after a first of "
+                  f"{rep['first_compile_s']} s")
+            flash = rep["kernel_dispatch"].get("flash_attention", {})
+            check(flash and set(flash.values()) == {"pallas"},
+                  f"flash attention dispatch: {flash}")
+
+            rep = serve_phase(WORK, args.seed, LM)
+            emit("serve", **rep)
+            check(set(rep["attention_paths"].values()) == {"pallas"},
+                  "a serve width did not take the ragged kernel: "
+                  f"{rep['attention_paths']}")
+    except SmokeFailure as e:
+        emit("failed", error=str(e))
+        sys.stderr.write(f"chip_smoke: FAILED: {e}\n")
+        return 1
+    finally:
+        shutil.rmtree(WORK, ignore_errors=True)
+    sys.stdout.flush()
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -242,9 +242,7 @@ def test_sharding_hole_silent_when_sharded_or_undesignated():
 # ---------------------------------------------------------------------
 
 def test_fp64_leak_fires_under_x64():
-    from jax.experimental import enable_x64
-
-    with enable_x64(True):
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: x * np.float64(2.0)
         )(jnp.ones((4,), jnp.float64))

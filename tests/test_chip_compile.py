@@ -1,0 +1,157 @@
+"""What the chip's compiler accepts: the main path's Pallas kernels,
+compiled at their production shapes for a v5e that is DESCRIBED, not
+attached (``on-chip-measurement`` guide, section 2, rehearsal 3).
+
+Interpret-mode tests cannot see a Mosaic refusal — a block spec the TPU
+lowering rejects, a DMA slice that is not tile-aligned, more scoped VMEM
+than a kernel may use — and the dispatch has no fallback behind it, so a
+refusal here is a run that dies on the chip.  A compile that passes is
+not a chip run: ``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture (one process
+holds libtpu; nothing may touch it at import or collection time), the
+kernels are steered to their compiled (not interpreted) form by
+monkeypatching ``backend._on_tpu`` here in the test, and the persistent
+compilation cache is off around the whole file (an executable compiled
+for a described chip cannot be read back without one).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever stops libtpu here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Kernels lower for the chip (``interpret=False``) although the
+    process's default backend is the CPU."""
+    from unicore_tpu.ops import backend
+
+    on_tpu = functools.lru_cache(None)(lambda: True)
+    monkeypatch.setattr(backend, "_on_tpu", on_tpu)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+BF16, F32, I32, U32 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.uint32
+
+# name -> (batch, seq, heads, head_dim, dtype, bias, pad, causal,
+#          dropout, backward)
+FLASH_CASES = {
+    # the BERT-base train step: trainable [1, H, T, T] bias + padding
+    # mask + dropout, head-batched single-block kernels, at batch 64
+    # (the scoped-VMEM watch point) and at chip_smoke's batch 32
+    "bert_b64_bias_pad_dropout": (64, 512, 12, 64, BF16, True, True,
+                                  False, True, True),
+    "bert_b32_bias_pad_dropout": (32, 512, 12, 64, BF16, True, True,
+                                  False, True, True),
+    "bert_b64_pad_dropout": (64, 512, 12, 64, BF16, False, True, False,
+                             True, True),
+    # the fp32 forward the trainer traces at parameter init
+    "bert_init_f32_forward": (32, 512, 12, 64, F32, True, True, False,
+                              False, False),
+    # transformer_lm_base training, and the full forward the serve
+    # check compares with
+    "lm_b8_causal_pad_dropout": (8, 512, 12, 64, BF16, False, True, True,
+                                 True, True),
+    "lm_f32_causal_forward": (1, 256, 12, 64, F32, False, True, True,
+                              False, False),
+    # long context: multi-block causal
+    "causal_t8192": (1, 8192, 12, 64, BF16, False, False, True, False,
+                     True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_attention_compiles(case, one_chip, compiled_kernels):
+    from unicore_tpu.ops.pallas.flash_attention import flash_attention
+
+    b, t, h, d, dt, has_bias, has_pad, causal, dropout, bwd = (
+        FLASH_CASES[case])
+
+    def f(q, k, v, bias, pad, key):
+        out = flash_attention(
+            q, k, v, bias=bias if has_bias else None,
+            key_padding_mask=pad if has_pad else None, causal=causal,
+            dropout_prob=0.1 if dropout else 0.0,
+            rng=key if dropout else None, is_training=dropout,
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    fn = jax.grad(f, argnums=(0, 1, 2, 3)) if bwd else f
+    qkv = ((b, t, h, d), dt)
+    _compile(fn, one_chip, qkv, qkv, qkv, ((1, h, t, t), dt),
+             ((b, t), I32), ((2,), U32))
+
+
+def test_softmax_dropout_compiles(one_chip, compiled_kernels):
+    from unicore_tpu.ops.pallas.softmax_dropout import softmax_dropout
+
+    def f(x, bias, key):
+        return jnp.sum(softmax_dropout(
+            x, 0.1, rng=key, is_training=True, bias=bias,
+        ).astype(jnp.float32))
+
+    _compile(jax.grad(f, argnums=(0, 1)), one_chip,
+             ((64, 12, 512, 512), BF16), ((1, 12, 512, 512), BF16),
+             ((2,), U32))
+
+
+def test_sr_rounding_compiles(one_chip, compiled_kernels):
+    from unicore_tpu.ops.pallas.rounding import fp32_to_bf16_sr
+
+    _compile(fp32_to_bf16_sr, one_chip, ((768 * 768,), F32), ((2,), U32))
+
+
+# the serve engine's two compiled widths (decode, prefill chunk) at
+# max_batch 8, for the serve CLI's default page size and chip_smoke's,
+# the head counts of transformer_lm (8) and transformer_lm_base (12),
+# and both pool dtypes (a checkpoint serves its fp32 master params)
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads", [12, 8])
+@pytest.mark.parametrize("page_size", [16, 64])
+@pytest.mark.parametrize("width", [1, 32, 64])
+def test_ragged_paged_attention_compiles(width, page_size, heads, dtype,
+                                         one_chip, compiled_kernels):
+    from unicore_tpu.ops.pallas import paged_attention as pa
+
+    bsz, d, context, num_pages = 8, 64, 512, 64
+    assert pa.supported(heads, d, page_size, jnp.dtype(dtype).itemsize)
+    fn = functools.partial(
+        pa.ragged_paged_attention, page_size=page_size, scale=d ** -0.5)
+    pool = ((num_pages * page_size, heads * d), dtype)
+    _compile(fn, one_chip, ((bsz, width, heads, d), dtype), pool, pool,
+             ((bsz, context // page_size), I32), ((bsz, width), I32),
+             ((bsz,), I32))

@@ -7,10 +7,11 @@ Tiers:
   1-based segments, per-segment position reset, pad fill);
 - segment-causal mask units on ``_segment_bias`` + ``_attend``: no
   cross-segment attention, pad keys unattendable;
-- model-level parity: packed rows produce BIT-EXACT per-token logits vs
-  the padded run of the same logical samples (masked scores take the
-  -1e30 fill whose softmax terms underflow to exact 0.0), loss/grads
-  agree to reduction-order tolerance;
+- model-level parity: packed rows produce the padded run's per-token
+  logits to a few ulps (masked scores take the -1e30 fill whose softmax
+  terms underflow to exact 0.0; what remains is XLA:CPU ordering a
+  reduction by the operand's shape), loss/grads agree to
+  reduction-order tolerance;
 - rel_pos refusal: the global-offset bias cannot reset per segment;
 - trainer integration: checkpoint save -> resume on packed batches is
   bit-exact vs the uninterrupted run.
@@ -176,7 +177,16 @@ def _mixed_batches():
     return lens, (pad_src, pad_tgt), (pk_src, pk_tgt, pk_seg, pk_pos)
 
 
-def test_packed_vs_padded_logits_bitexact():
+def test_packed_vs_padded_logits_match_to_ulps():
+    """Per-token logits of a packed row equal the padded batch's to a few
+    ulps.  They are the same arithmetic on the same numbers; they are not
+    the same bits, because XLA:CPU orders a matmul's K-reduction by the
+    shape of the whole operand: the very same rows times the very same
+    weights differ in the last bit between a [3, T, D] and a [1, T, D]
+    operand (checked with a bare ``x @ W``), and a packed row's keys sit
+    at other column offsets than a padded row's.  Measured: 1-2 ulps
+    (6e-8 at |logit| <= 0.33); the bound is 8 ulps of the largest
+    logit."""
     lens, (pad_src, _), (pk_src, _, pk_seg, pk_pos) = _mixed_batches()
     model = _lm_model()
     params = model.init(jax.random.PRNGKey(0), jnp.asarray(pad_src))["params"]
@@ -186,16 +196,18 @@ def test_packed_vs_padded_logits_bitexact():
                                 deterministic=True,
                                 segment_ids=jnp.asarray(pk_seg),
                                 positions=jnp.asarray(pk_pos)))
+    atol = 8 * np.finfo(np.float32).eps * float(np.abs(lp).max())
     off = 0
     for i, n in enumerate(lens):
-        np.testing.assert_array_equal(lp[i, :n], lk[0, off:off + n])
+        np.testing.assert_allclose(lp[i, :n], lk[0, off:off + n],
+                                   rtol=0, atol=atol)
         off += n
 
 
 def test_packed_vs_padded_loss_and_grad_parity():
     """Total loss and grads agree to reduction-order tolerance (the sums
-    traverse tokens in a different order; the per-token terms are
-    bit-identical per the logits test above)."""
+    traverse tokens in a different order; the per-token terms agree to
+    a few ulps per the logits test above)."""
     lens, (pad_src, pad_tgt), (pk_src, pk_tgt, pk_seg, pk_pos) = \
         _mixed_batches()
     model = _lm_model()

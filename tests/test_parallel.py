@@ -99,10 +99,8 @@ def test_ulysses_matches_full(rng, mesh, qkv, causal):
     q, k, v = qkv
     from jax.sharding import PartitionSpec as P
 
-    from unicore_tpu.parallel._compat import shard_map
-
     spec = P(None, "seq", None, None)
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         lambda q_, k_, v_: ulysses_attention(
             q_, k_, v_, axis_name="seq", causal=causal
         ),
@@ -241,3 +239,47 @@ def test_module_seq_parallel_dropout_no_raise(rng, mesh, qkv):
         assert out is not None and np.isfinite(np.asarray(out)).all()
     finally:
         parallel.disable_sequence_parallel()
+
+
+def test_flash_per_shard_matches_unsharded(rng):
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device
+    mesh the flash kernel runs per batch shard through shard_map.  Same
+    output, same gradients (the shared bias's summed over the shards),
+    and — the seeds being per GLOBAL batch row — the same dropout mask
+    as the unsharded call."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from unicore_tpu.modules.multihead_attention import _flash_per_shard
+    from unicore_tpu.ops import backend
+
+    b, t, h, d = 8, 128, 2, 16
+    q, k, v = (jnp.asarray(rng.randn(b, t, h, d), jnp.float32)
+               for _ in range(3))
+    bias = jnp.asarray(rng.randn(1, h, t, t), jnp.float32)
+    pad = jnp.asarray(rng.rand(b, t) > 0.9).at[:, 0].set(False)
+    w = jnp.asarray(rng.randn(b, t, h, d), jnp.float32)
+    key = jax.random.PRNGKey(3)
+
+    def loss(q, k, v, bias):
+        out = _flash_per_shard(
+            q, k, v, bias, pad, key, causal=False, dropout_prob=0.2,
+            is_training=True, scale=d ** -0.5)
+        return jnp.sum(out * w), out
+
+    step = jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)
+    with backend.kernel_backend("pallas"):
+        (want_l, want_o), want_g = jax.jit(step)(q, k, v, bias)
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2, 1, 1),
+                    ("data", "fsdp", "seq", "tensor"))
+        backend.set_spmd_mesh(mesh)
+        rows = NamedSharding(mesh, P(("data", "fsdp")))
+        (got_l, got_o), got_g = jax.jit(step)(
+            *(jax.device_put(x, rows) for x in (q, k, v)),
+            jax.device_put(bias, NamedSharding(mesh, P())))
+    assert not got_o.sharding.is_fully_replicated
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-5)
+    for a, b_ in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-4, atol=1e-4)

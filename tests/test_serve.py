@@ -116,8 +116,9 @@ def test_pool_exhaustion_and_double_free():
 
 def _random_paged_case(rng, B=3, P=5, ps=4, heads=4, d=16):
     num_pages = B * P + 1
-    pool_k = jnp.asarray(rng.randn(num_pages * ps, heads, d), jnp.float32)
-    pool_v = jnp.asarray(rng.randn(num_pages * ps, heads, d), jnp.float32)
+    # the pool folds heads into the minor dim: [num_slots, H*D]
+    pool_k = jnp.asarray(rng.randn(num_pages * ps, heads * d), jnp.float32)
+    pool_v = jnp.asarray(rng.randn(num_pages * ps, heads * d), jnp.float32)
     perm = rng.permutation(num_pages - 1)[: B * P] + 1
     table = jnp.asarray(perm.reshape(B, P).astype(np.int32))
     lengths = jnp.asarray(rng.randint(1, P * ps + 1, size=(B,)), jnp.int32)
@@ -140,8 +141,8 @@ def test_paged_attention_eager_matches_dense(rng):
     )
     from unicore_tpu.serve.attention import gather_slots
 
-    k_seq = gather_slots(pool_k, table, ps)
-    v_seq = gather_slots(pool_v, table, ps)
+    k_seq = gather_slots(pool_k, table, ps).reshape(B, -1, heads, d)
+    v_seq = gather_slots(pool_v, table, ps).reshape(B, -1, heads, d)
     for b in range(B):
         n = int(lengths[b])
         s = jnp.einsum(
@@ -154,8 +155,13 @@ def test_paged_attention_eager_matches_dense(rng):
         )
 
 
-@pytest.mark.parametrize("pages_per_block", [1, 2, 3])
-def test_ragged_kernel_matches_eager(rng, pages_per_block):
+@pytest.mark.parametrize("pages_per_block,heads,d", [
+    (1, 4, 16), (2, 4, 16), (3, 4, 16),
+    # the serve widths: two 64-wide heads per 128-lane slab, and one
+    # 128-wide head per slab
+    (2, 4, 64), (2, 2, 128), (2, 3, 64),
+])
+def test_ragged_kernel_matches_eager(rng, pages_per_block, heads, d):
     """Pallas ragged kernel (interpret mode on CPU) vs the eager gather
     path on a MIXED batch — a decode row, prefill-chunk rows of
     different widths, ragged lengths, and an inactive (length-0) row
@@ -165,7 +171,7 @@ def test_ragged_kernel_matches_eager(rng, pages_per_block):
     )
     from unicore_tpu.serve.attention import paged_attention_reference
 
-    B, P, ps, heads, d, T = 4, 5, 4, 4, 16, 3
+    B, P, ps, T = 4, 5, 4, 3
     pool_k, pool_v, table, lengths = _random_paged_case(rng, B, P, ps,
                                                        heads, d)
     lengths = lengths.at[2].set(0)  # inactive batch slot
@@ -699,6 +705,29 @@ def test_host_fault_fails_inflight_not_engine(lm):
         [Request(prompt=[6, 2, 9], max_new_tokens=5,
                  request_id="clean")])
     assert clean.tokens == solo_greedy(model, params, [6, 2, 9], 5)
+
+
+def test_first_call_fault_of_a_step_propagates(lm):
+    """A step that cannot be traced, lowered or compiled is a broken
+    PROGRAM, not a bad request: failing the in-flight requests and
+    carrying on would end a run with exit 0 and no token served.  The
+    first call of a (width, sampling) step raises past the per-request
+    isolation; once a step has run, a later fault is isolated as
+    before (the test above)."""
+    from unicore_tpu.serve.engine import StepCompileError
+
+    model, params = lm
+    engine = ServeEngine(model, params, num_pages=12, page_size=4,
+                         max_batch=2)
+
+    def refused(*args):
+        raise ValueError("Block spec for args[2] ... (the TPU lowering)")
+
+    engine._ragged_step_fn = lambda width, sampling: refused
+    with pytest.raises(StepCompileError, match="first call"):
+        engine.generate([Request(prompt=[3, 7, 2], max_new_tokens=2,
+                                 request_id="a")])
+    assert engine.stats["host_faults"] == 0
 
 
 def test_row_assembly_fault_fails_only_that_request(lm):

@@ -346,34 +346,36 @@ def test_flash_decision_memoized_for_fwd_bwd_agreement(tune_env):
     assert fa.picked_blocks(256, 256, dtype=jnp.float32, d=64) == (128, 128)
 
 
-def test_flash_probe_key_threads_tuned_blocks(tune_env):
-    """probe_ok must key on the blocks production will lower: a changed
-    tune-cache entry yields a DIFFERENT probe key (no stale verdicts)."""
-    from unicore_tpu.ops import backend
-    from unicore_tpu.ops.pallas import flash_attention as fa
+def test_dispatch_report_names_the_path_taken(tune_env):
+    """There is no compile probe behind a dispatch site: the path taken
+    is the path compiled, and ``dispatch_report`` names it — the kernel
+    under a forced backend, the reference once the tuner has recorded
+    "eager" for the bucket."""
+    from unicore_tpu.ops import backend, softmax_dropout
 
-    probed = []
+    x = jnp.zeros((2, 512, 128), jnp.float32)
+    desc = "x(2, 512, 128) float32 mask=None bias=None dropout=False"
 
-    def spy(key, build):
-        probed.append(key)
-        return True
+    def path():
+        softmax_dropout(x, 0.0, is_training=False)
+        return backend.dispatch_report()["softmax_dropout"][desc]
 
-    orig = backend.kernel_probe_ok
-    backend.kernel_probe_ok = spy
+    with backend.kernel_backend("pallas"):
+        assert path() == "pallas"
+    # the report is a copy: editing it does not edit the record
+    backend.dispatch_report()["softmax_dropout"][desc] = "edited"
+    assert backend.dispatch_report()["softmax_dropout"][desc] == "pallas"
+
+    wl = tuning.sd_workload(x.shape, "float32", dropout_on=False)
+    tune_env.record(
+        bucket_key(candidates.OPS["softmax_dropout"].bucket(wl)), "eager")
+    tuning.reset_memo()
+    orig = backend._on_tpu
+    backend._on_tpu = lambda: True  # auto backend, as on the chip
     try:
-        fa.probe_ok(jnp.float32, 256, 256, 64, None, None, False, False,
-                    False)
-        wl = tuning.flash_workload((1, 256, 1, 64), 256, "float32")
-        key = bucket_key(candidates.OPS["flash_attention"].bucket(wl))
-        tune_env.record(key, {"block_q": 128, "block_k": 128})
-        tuning.reset_memo()
-        fa.probe_ok(jnp.float32, 256, 256, 64, None, None, False, False,
-                    False)
+        assert path() == "reference"
     finally:
-        backend.kernel_probe_ok = orig
-    assert len(probed) == 2 and probed[0] != probed[1]
-    assert probed[0][-2:] == fa._pick_blocks(256, 256, 0)
-    assert probed[1][-2:] == (128, 128)
+        backend._on_tpu = orig
 
 
 def test_off_mode_ignores_cache(tune_env):
@@ -393,7 +395,7 @@ def test_off_mode_ignores_cache(tune_env):
 
 def test_heuristic_crossover_gate(rng):
     """Satellite: the no-cache default must not lower a kernel slower
-    than eager for small-row/batched-bias shapes (the BENCH_r05
+    than eager for small-row/batched-bias shapes (the r5-era
     evoformer case) while keeping the shapes where the kernel wins."""
     from unicore_tpu.ops.softmax_dropout import _heuristic_kernel_win
 
@@ -402,11 +404,11 @@ def test_heuristic_crossover_gate(rng):
     me = jnp.zeros((1, 128, 1, 1, 128), jnp.bfloat16)
     be = jnp.zeros((1, 1, 4, 128, 128), jnp.bfloat16)
     assert not _heuristic_kernel_win(xe, me, be)
-    # BERT shape: wins (BENCH_r05 1.134x)
+    # BERT shape: wins (r5-era record, deleted: 1.134x)
     xb = jnp.zeros((32, 12, 512, 512), jnp.bfloat16)
     bb = jnp.zeros((1, 12, 512, 512), jnp.bfloat16)
     assert _heuristic_kernel_win(xb, None, bb)
-    # long-k rows: wins (BENCH_r05 1.108x)
+    # long-k rows: wins (r5-era record, deleted: 1.108x)
     xk = jnp.zeros((4, 8, 1024, 2048), jnp.bfloat16)
     bk = jnp.zeros((1, 8, 1024, 2048), jnp.bfloat16)
     assert _heuristic_kernel_win(xk, None, bk)
@@ -606,7 +608,7 @@ def test_ce_runner_builds_fused_and_eager(tune_env):
 
 
 def test_evoformer_static_verdict_out_of_the_box(tune_env):
-    """The BENCH_r05 evoformer bucket (~0.99x kernel-vs-eager) carries a
+    """The r5-era evoformer bucket (~0.99x kernel-vs-eager) carries a
     committed "eager" verdict: with an EMPTY cache, dispatch must route
     to eager for both dropout states — and a measured cache entry must
     still override the static verdict."""

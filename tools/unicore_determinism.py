@@ -123,7 +123,8 @@ def digest_stream(closed, flat_args):
     import jax
     import numpy as np
 
-    core = jax.core
+    from jax.extend import core
+
     jaxpr = closed.jaxpr
     env = {}
 

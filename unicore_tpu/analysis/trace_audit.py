@@ -14,7 +14,7 @@ classes rounds 3-5 paid for at bench time (see docs/static_analysis.md):
   the "flash path expected, materialized path traced" tripwire.
 - UL003 donation-miss: no argument donated while the arguments carry
   real state — the doubled-HBM failure mode.
-- UL004 host-callback: callback / infeed / outfeed primitives inside the
+- UL004 host-callback: callback and debug-print primitives inside the
   step (each one is a device->host round trip per step).
 - UL005 sharding-hole: big train-state leaves left fully replicated on a
   mesh whose fsdp/tensor axes are real (the r4 involuntary-full-remat
@@ -40,9 +40,10 @@ _ELEMENTWISE_ARITH = {
     "select_n", "nextafter",
 }
 
+# every host-callback primitive the installed jax can trace
+# (jax.debug.print is its own primitive, not a debug_callback)
 _CALLBACK_PRIMS = {
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "host_callback_call", "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 }
 
 

@@ -1,6 +1,6 @@
 """Running-statistic meters for training metrics.
 
-Behavioral parity target: the meter taxonomy of
+Behavioral parity target: the meter kinds of
 ``unicore/logging/meters.py`` — a weighted average, a raw sum, an
 events-per-second rate, a stopwatch, and a priority-ordered serializable
 collection with derived (computed-from-other-meters) entries.  Independent

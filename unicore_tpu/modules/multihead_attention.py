@@ -48,6 +48,18 @@ def _padding_bias(key_padding_mask, dtype):
 
 
 def _flash_ok(q, k, bias, has_pad, dropout_on, causal=False):
+    from unicore_tpu.ops.backend import note_dispatch
+
+    desc = "q%s k%d %s bias=%s pad=%s causal=%s dropout=%s" % (
+        tuple(q.shape), k.shape[1], q.dtype.name,
+        None if bias is None else tuple(bias.shape),
+        has_pad, causal, dropout_on,
+    )
+    return note_dispatch("flash_attention", desc, _flash_wins(
+        q, k, bias, has_pad, dropout_on, causal))
+
+
+def _flash_wins(q, k, bias, has_pad, dropout_on, causal):
     from unicore_tpu.ops.backend import use_pallas
     from unicore_tpu.ops.pallas import flash_attention as fa
 
@@ -69,6 +81,16 @@ def _flash_ok(q, k, bias, has_pad, dropout_on, causal=False):
     ks = (k.shape[0], k.shape[2], k.shape[1], k.shape[3])
     if not fa.eligible(qs, ks, None if bias is None else bias.shape):
         return False
+    from unicore_tpu.ops.backend import spmd_mesh
+
+    mesh = spmd_mesh()
+    if mesh is not None:
+        # the kernel runs per batch shard (_flash_per_shard): the batch
+        # must split evenly and a bias must be shared or split with it
+        if q.shape[0] % _batch_shards(mesh):
+            return False
+        if bias is not None and bias.shape[0] not in (1, q.shape[0]):
+            return False
     # autotuner eager-crossover: a cache entry that says the measured
     # winner for this bucket is the einsum composition routes around the
     # kernel entirely (a forced "pallas" backend still takes flash — the
@@ -115,16 +137,54 @@ def _flash_ok(q, k, bias, has_pad, dropout_on, causal=False):
         ) is not None
         if not single_block and k.shape[1] < 1024 and not tuned_applies:
             return False
-    # fail-open: compile-probe THIS config once per process (dtype/seq
-    # lens/bias kind change the BlockSpecs); if it doesn't lower on this
-    # backend, use the materialized path instead of crashing training
-    return fa.probe_ok(
-        q.dtype, q.shape[1], k.shape[1], q.shape[3],
-        None if bias is None else bias.shape[2],
-        None if bias is None else bias.dtype,
-        has_pad, causal, dropout_on, heads=q.shape[2],
-        bias_heads=None if bias is None else bias.shape[1],
-    )
+    return True
+
+
+def _batch_shards(mesh):
+    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
+    return shape.get("data", 1) * shape.get("fsdp", 1)
+
+
+def _flash_per_shard(q, k, v, bias, key_padding_mask, rng, **kw):
+    """The flash kernel, partitioned by hand where GSPMD cannot do it:
+    under a multi-device mesh every batch shard runs the kernel on its
+    own rows (``shard_map`` over the batch axes; a shared bias is
+    replicated and its gradient summed by the transpose).  The dropout
+    seeds are per GLOBAL batch row, so the masks are those of the
+    unsharded call."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from unicore_tpu.ops.backend import spmd_mesh
+    from unicore_tpu.ops.pallas.flash_attention import flash_attention
+    from unicore_tpu.parallel._seed_utils import batch_shard_index
+
+    mesh = spmd_mesh()
+    if mesh is None:
+        return flash_attention(q, k, v, bias=bias,
+                               key_padding_mask=key_padding_mask, rng=rng,
+                               **kw)
+    axes = tuple(a for a in _BATCH_AXES if a in mesh.axis_names)
+    local_rows = q.shape[0] // _batch_shards(mesh)
+    rows = P(axes)
+    # optional operands ride along as a dict: absent ones are not traced
+    extra = {"bias": bias, "key_padding_mask": key_padding_mask,
+             "rng": rng}
+    extra = {n: x for n, x in extra.items() if x is not None}
+    specs = {"bias": rows if bias is not None and bias.shape[0] != 1
+             else P(), "key_padding_mask": rows, "rng": P()}
+
+    def local(q, k, v, extra):
+        return flash_attention(
+            q, k, v, batch_seed_offset=batch_shard_index(axes) * local_rows,
+            **extra, **kw)
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(rows, rows, rows, {n: specs[n] for n in extra}),
+        out_specs=rows,
+        check_vma=False,  # pallas_call's out_shape carries no vma
+    )(q, k, v, extra)
 
 
 _warned_seq_parallel_dropout = [False]
@@ -248,12 +308,10 @@ def _attend(q, k, v, scaling, dropout, key_padding_mask, bias, deterministic,
         q, k, bias, key_padding_mask is not None, rng is not None,
         causal=causal,
     ):
-        from unicore_tpu.ops.pallas.flash_attention import flash_attention
-
-        return flash_attention(
-            q, k, v, bias=bias, key_padding_mask=key_padding_mask,
-            causal=causal, dropout_prob=dropout, rng=rng,
-            is_training=not deterministic, scale=scaling,
+        return _flash_per_shard(
+            q, k, v, bias, key_padding_mask, rng, causal=causal,
+            dropout_prob=dropout, is_training=not deterministic,
+            scale=scaling,
         )
 
     mask = _padding_bias(key_padding_mask, dtype)
@@ -499,15 +557,14 @@ class SelfMultiheadAttention(nn.Module):
         each sequence attends the pages its table names, masked to its
         own positions (``unicore_tpu/serve/attention.py`` owns the math
         and the eager/Pallas dispatch).  Pool buffers live in collection
-        ``"pagedkv"`` — one [num_slots, H, Dh] pair per layer, allocated
+        ``"pagedkv"`` — one [num_slots, H*Dh] pair per layer, allocated
         once at engine init and donated through every jitted step."""
-        head_dim = self.embed_dim // self.num_heads
         is_initialized = self.has_variable("pagedkv", "k_pages")
         nslots = None if is_initialized else int(paged.num_slots)
         k_pages = self.variable("pagedkv", "k_pages", jnp.zeros,
-                                (nslots, self.num_heads, head_dim), k.dtype)
+                                (nslots, self.embed_dim), k.dtype)
         v_pages = self.variable("pagedkv", "v_pages", jnp.zeros,
-                                (nslots, self.num_heads, head_dim), v.dtype)
+                                (nslots, self.embed_dim), v.dtype)
         if not is_initialized:
             import jax
 
@@ -519,10 +576,8 @@ class SelfMultiheadAttention(nn.Module):
             return jnp.einsum("bhqk,bkhd->bqhd", p, v)
         from unicore_tpu.serve.attention import paged_attention
 
-        flat_k = k.astype(k_pages.value.dtype).reshape(
-            -1, self.num_heads, head_dim)
-        flat_v = v.astype(v_pages.value.dtype).reshape(
-            -1, self.num_heads, head_dim)
+        flat_k = k.astype(k_pages.value.dtype).reshape(-1, self.embed_dim)
+        flat_v = v.astype(v_pages.value.dtype).reshape(-1, self.embed_dim)
         k_pages.value = k_pages.value.at[paged.slot_mapping].set(flat_k)
         v_pages.value = v_pages.value.at[paged.slot_mapping].set(flat_v)
         return paged_attention(

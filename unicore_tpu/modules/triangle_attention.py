@@ -38,13 +38,15 @@ def group_flash_attention(q, k, v, pair_bias, mask, dropout, deterministic,
     materialized einsum path pays at realistic residue counts.  At
     T <= 512 the single-block fused backward computes dq/dk/dv/dbias in
     one sweep.  Returns ``[B, G, T, H, Dh]``, or None when the kernel
-    does not apply (non-128-multiple T, batched bias, probe failure) —
+    does not apply (non-128-multiple T, batched bias, tuner verdict) —
     callers fall back to the einsum + fused-softmax path."""
-    from unicore_tpu.ops.backend import get_kernel_backend, use_pallas
+    from unicore_tpu.ops.backend import (
+        get_kernel_backend, needs_shard_map, use_pallas,
+    )
     from unicore_tpu.ops.pallas import flash_attention as fa
 
-    if not use_pallas():
-        return None
+    if not use_pallas() or needs_shard_map():
+        return None  # GSPMD cannot partition a Mosaic kernel
     B, G, T, H, D = q.shape
     if get_kernel_backend() != "pallas":
         # measured on v5e (C_z=128, H=4 -> D=32): the thin head dim
@@ -80,12 +82,6 @@ def group_flash_attention(q, k, v, pair_bias, mask, dropout, deterministic,
         allow_tune=True,
     )
     if tune_dec == "eager" and get_kernel_backend() != "pallas":
-        return None
-    if not fa.probe_ok(q.dtype, T, T, D,
-                       None if bias is None else bias.shape[2],
-                       None if bias is None else bias.dtype,
-                       mask is not None, False, dropout_on, heads=H,
-                       bias_heads=None if bias is None else bias.shape[1]):
         return None
     rng = make_rng("dropout") if dropout_on else None
     kpm = None

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from unicore_tpu.ops.backend import pallas_interpret, tpu_compiler_params
+from unicore_tpu.ops.backend import pallas_interpret
 from unicore_tpu.ops.pallas.prng import keep_mask
 
 NEG_INF = -1e30
@@ -561,98 +561,6 @@ def _pick_blocks(tq, tk, bias_itemsize=0):
     return bq, bk
 
 
-def probe_ok(dtype, tq, tk, d, bias_q, bias_dtype, has_pad, causal,
-             dropout_on, heads=1, bias_heads=None):
-    """FAIL-OPEN compile probe for one flash config (round-2 lesson: a
-    kernel that doesn't lower must fall back to the einsum path, not kill
-    training).  Keyed on everything that affects Mosaic lowering — q/kv
-    dtype, seq lens (they fix the block sizes), head dim, bias kind
-    (``bias_q`` is None / 1 / tq — the bQ==1 sublane-1 block is its own
-    spec), bias dtype AND bias head count (``bias_heads`` is 1 for a
-    head-broadcast bias, else the head count: ``_hb_specs`` lowers a
-    (1, 1, bQ, bk) block for bH == 1 vs (1, hb, bQ, bk) otherwise, so a
-    heads-dim probe would not cover a broadcastable attn_mask), pad mask
-    presence, causal, dropout.  The probe shrinks the batch to 1 (grid
-    size does not affect lowering) but keeps the REAL head count: in the
-    single-block regime the kernels batch ``_pick_hb(heads, ...)`` heads
-    per grid step with hb-times larger blocks, so a heads=1 probe would
-    compile a different (hb=1) variant than production runs and the
-    fail-open guarantee would be void exactly where VMEM pressure is
-    highest."""
-    from unicore_tpu.ops.backend import kernel_probe_ok
-
-    dtype = jnp.dtype(dtype)
-    bias_dtype = None if bias_q is None else jnp.dtype(bias_dtype)
-    # the block pair the production call will ACTUALLY lower — tuner
-    # decisions included (picked_blocks consults the autotune cache and
-    # memoizes per process), and threaded into the probe key below: a
-    # probe verdict for heuristic blocks must not vouch for tuned blocks
-    # recorded under a different cache state
-    bq_, bk_ = picked_blocks(
-        tq, tk,
-        None if bias_q is None else (
-            1, 1 if (bias_heads is None or bias_heads == 1) else 2,
-            bias_q, tk,
-        ),
-        bias_dtype,
-        dtype=dtype, d=d, has_pad=has_pad, causal=causal,
-        dropout_on=dropout_on,
-    )
-    heads = heads if (tq == bq_ and tk == bk_) else 1  # hb only single-block
-    if bias_q is None:
-        bias_heads = None
-    else:
-        # normalize the same way heads is: the only spec distinction is
-        # broadcast (bH == 1) vs per-head (bH == heads), and after the
-        # multi-block heads->1 collapse both coincide at 1
-        bias_heads = 1 if (bias_heads is None or bias_heads == 1) else heads
-    key = ("flash", dtype.name, tq, tk, d, bias_q,
-           None if bias_dtype is None else bias_dtype.name,
-           has_pad, causal, dropout_on, heads, bias_heads, bq_, bk_)
-
-    def build():
-        q = jnp.zeros((1, tq, heads, d), dtype)
-        kv = jnp.zeros((1, tk, heads, d), dtype)
-        pad = jnp.zeros((1, tk), jnp.int32) if has_pad else None
-        rng = jax.random.PRNGKey(0) if dropout_on else None
-        dp = 0.1 if dropout_on else 0.0
-        kw = dict(key_padding_mask=pad, causal=causal, dropout_prob=dp,
-                  rng=rng, is_training=dropout_on)
-        if bias_q is None:
-            def f(q, kv):
-                o = flash_attention(q, kv, kv, **kw)
-                return jnp.sum(o.astype(jnp.float32))
-
-            jax.jit(jax.grad(f, argnums=(0, 1))).lower(q, kv).compile()
-        else:
-            bias = jnp.zeros((1, bias_heads, bias_q, tk), bias_dtype)
-
-            def f(q, kv, bias):
-                o = flash_attention(q, kv, kv, bias=bias, **kw)
-                return jnp.sum(o.astype(jnp.float32))
-
-            jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, kv, bias).compile()
-
-    return kernel_probe_ok(key, build)
-
-
-def kernel_self_check():
-    """Compile-smoke the production-critical spec variants (used by
-    ``tools/tpu_smoke.py`` and available for startup checks): BERT-like
-    bf16 per-head bias+pad+dropout, the head-broadcast (bH==1) bias
-    block, the bQ==1 broadcast-bias block, and causal."""
-    return (
-        probe_ok(jnp.bfloat16, 512, 512, 64, 512, jnp.bfloat16, True, False,
-                 True, heads=8, bias_heads=8)
-        and probe_ok(jnp.bfloat16, 512, 512, 64, 512, jnp.bfloat16, True,
-                     False, True, heads=8, bias_heads=1)
-        and probe_ok(jnp.float32, 256, 256, 64, 1, jnp.float32, False, False,
-                     False)
-        and probe_ok(jnp.float32, 256, 256, 64, None, None, False, True,
-                     False)
-    )
-
-
 def eligible(q_shape, k_shape, bias_shape):
     """Whether the flash kernel supports these shapes ([B,H,T,D] layout)."""
     _, _, tq, d = q_shape
@@ -798,7 +706,7 @@ def _flash_fwd_impl(q, k, v, bias, pad, dropout_prob, seed, causal, scale):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*args)
@@ -837,7 +745,7 @@ def _flash_fwd_hb(q, k, v, bias, pad, dropout_prob, seed, causal, scale,
             jax.ShapeDtypeStruct((bsz, heads, tq, 1), jnp.float32),
         ],
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024,  # see the backward's note
         ),
@@ -919,7 +827,7 @@ def _flash_bwd(dropout_prob, causal, scale, residuals, g):
                 pltpu.VMEM((tk, d), jnp.float32),
             ],
             interpret=pallas_interpret(),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary",
                                      "arbitrary"),
             ),
@@ -945,7 +853,7 @@ def _flash_bwd(dropout_prob, causal, scale, residuals, g):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*(common_args + extra_args))
@@ -991,7 +899,7 @@ def _flash_bwd(dropout_prob, causal, scale, residuals, g):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*(common_args + extra_args))
@@ -1058,7 +966,7 @@ def _dbias_pass(q, k, v, bias, pad, seed, lse, delta, g, dropout_prob,
         out_shape=jax.ShapeDtypeStruct((heads, tq, tk), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -1158,7 +1066,7 @@ def _flash_bwd_fused(q, k, v, bias, pad, seed, lse, delta, g, dropout_prob,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             # the hb-batched working set legitimately exceeds the 16MB
             # default scoped-vmem (v5e has 128MB physical); measured

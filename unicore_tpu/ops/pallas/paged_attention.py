@@ -13,6 +13,17 @@ online-softmax accumulator per (head, query) — the gathered
 exists, and per-row ``lengths`` make the work RAGGED: a row holding 3
 pages stops after 3 DMAs regardless of the table width.
 
+Layout: the pool is ``[num_slots, H*D]`` — heads folded into the lane
+dimension, so a page is a ``[page_size, H*D]`` tile-aligned slab and one
+DMA moves it (a ``[.., H, 64]`` pool has a 64-wide minor dim, which the
+chip's compiler refuses to DMA and which wastes half of every HBM tile).
+Heads are read back as 128-lane SLABS: with ``D == 64`` a slab holds
+two heads, and each head's scores are the slab-wide contraction of the
+keys with the query masked to that head's lanes (the other head's lanes
+contribute exact zeros).  That costs the MXU ``128 // D`` times the
+FLOPs of a per-head contraction and needs no unaligned lane slice; the
+step is bound by the KV bytes it streams, not by those FLOPs.
+
 Causality is one compare: gathered column ``j`` of a row's view IS
 position ``j`` (the pool layout invariant), so column ``c`` is admitted
 for query ``t`` iff ``c <= positions[b, t]`` — which also excludes
@@ -20,10 +31,10 @@ unwritten/stale slots, since every real query position is below the
 row's length.  Inactive query columns (position -1) mask everything and
 come out finite (garbage by contract, discarded by the caller).
 
-Dispatch (serve/attention.py) gates on ``use_pallas`` + the autotuner
-verdict (op ``"ragged_paged_attention"``) and compile-probes fail-open,
-so this kernel can only ever replace the eager path where it lowers and
-measures faster.
+Dispatch (serve/attention.py) gates on ``use_pallas``, the autotuner
+verdict (op ``"ragged_paged_attention"``) and ``supported`` (a static
+shape rule).  There is no compile probe: a shape ``supported`` admits
+and the chip's compiler refuses fails the serve step's compile, loudly.
 """
 
 import functools
@@ -33,16 +44,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from unicore_tpu.ops.backend import (
-    kernel_probe_ok,
-    pallas_interpret,
-    tpu_compiler_params,
-)
+from unicore_tpu.ops.backend import pallas_interpret
 
-# scoped-VMEM budget for the two KV scratch buffers (the rest of the
-# stack — q, out, accumulators — is KBs); same conservatism as the
-# softmax_dropout block heuristic
+_LANES = 128
+# scoped-VMEM budget for the two KV scratch buffers (q, out and the
+# accumulators are counted by the compiler on top of it)
 _SCRATCH_BUDGET_BYTES = 8 << 20
+
+
+def slab_heads(heads, head_dim):
+    """Heads per lane slab: as many as fit 128 lanes (1 when a head
+    fills a slab on its own), kept a divisor of the head count."""
+    g = max(1, min(heads, _LANES // head_dim))
+    while heads % g:
+        g -= 1
+    return g
+
+
+def supported(heads, head_dim, page_size, itemsize):
+    """Static shape rule for the COMPILED kernel (interpret mode takes
+    any shape): slabs are whole 128-lane tiles and a page is whole
+    sublane tiles of the pool dtype (8 rows of 32 bits)."""
+    slab = slab_heads(heads, head_dim) * head_dim
+    return slab % _LANES == 0 and page_size % (8 * 4 // itemsize) == 0
 
 
 def pick_pages_per_block(num_table_pages, page_size, head_dim, tuned=None,
@@ -63,20 +87,27 @@ def pick_pages_per_block(num_table_pages, page_size, head_dim, tuned=None,
 
 
 def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
-            k_scr, v_scr, sems, *, page_size, pages_per_block, scale):
+            k_scr, v_scr, m_scr, l_scr, acc_scr, sems, *, page_size,
+            pages_per_block, scale, heads, head_dim):
     b = pl.program_id(0)
     length = len_ref[b]
     n_table = pt_ref.shape[1]
     blk_slots = pages_per_block * page_size
     n_blocks = pl.cdiv(length, blk_slots)
+    group = slab_heads(heads, head_dim)
+    slab = group * head_dim
+    t = q_ref.shape[1]
 
-    q = q_ref[0].astype(jnp.float32) * scale  # [T, H, D]
-    t, heads, d = q.shape
-    # query positions [1, T, 1]: -1 marks an inactive column (mask all)
-    pos_q = pos_ref[0][None, :, None]
+    # query positions [T, 1]: -1 marks an inactive column (mask all)
+    pos_q = pos_ref[0]
+    # which head of its slab each lane belongs to
+    lane_head = jax.lax.broadcasted_iota(
+        jnp.int32, (1, slab), 1) // head_dim
+    m_scr[...] = jnp.full(m_scr.shape, -1e30, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def body(i, carry):
-        m, l, acc = carry
         # issue all this block's page DMAs, then wait: table rows are
         # padded with the trash page 0, so a clamped out-of-range read
         # fetches page 0 — always a valid pool page, masked below
@@ -84,97 +115,119 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
         for j in range(pages_per_block):
             page = pt_ref[b, jnp.minimum(i * pages_per_block + j,
                                          n_table - 1)]
+            rows = pl.ds(j * page_size, page_size)
             for src, dst, s in ((kp_hbm, k_scr, 0), (vp_hbm, v_scr, 1)):
                 cp = pltpu.make_async_copy(
-                    src.at[page], dst.at[j], sems.at[s, j]
+                    src.at[page], dst.at[rows], sems.at[s, j]
                 )
                 cp.start()
                 copies.append(cp)
         for cp in copies:
             cp.wait()
-        k = k_scr[...].astype(jnp.float32).reshape(blk_slots, heads, d)
-        v = v_scr[...].astype(jnp.float32).reshape(blk_slots, heads, d)
-        # [H, T, S]: batch over heads, contract head_dim
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
         cols = i * blk_slots + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, blk_slots), 2
+            jnp.int32, (1, blk_slots), 1
         )
         # bottom-right causal + unwritten-slot exclusion in one compare
         # (every real query position is < length by construction)
-        valid = cols <= pos_q
-        s = jnp.where(valid, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # a query whose positions precede this whole block has m_new ==
-        # -1e30 == s; exp(0) would admit every masked column, so the
-        # probability is zeroed explicitly rather than through the
-        # subtraction
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(  # [H, T, D]
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc * alpha + pv
+        valid = cols <= pos_q  # [T, S]
+        for sl in range(heads // group):
+            lanes = pl.ds(sl * slab, slab)
+            q = q_ref[0, :, lanes] * scale  # [T, slab]
+            k = k_scr[:, lanes]             # [S, slab]
+            v = v_scr[:, lanes]
+            acc = acc_scr[:, lanes]
+            for g in range(group):
+                h = sl * group + g
+                mine = lane_head == g
+                qh = q if group == 1 else jnp.where(
+                    mine, q, jnp.zeros_like(q))
+                s = jax.lax.dot_general(  # [T, S]
+                    qh, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                s = jnp.where(valid, s, -1e30)
+                m = m_scr[h]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                # a query whose positions precede this whole block has
+                # m_new == -1e30 == s; exp(0) would admit every masked
+                # column, so the probability is zeroed explicitly
+                # rather than through the subtraction
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                alpha = jnp.exp(m - m_new)
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                m_scr[h] = m_new
+                pv = jax.lax.dot_general(  # [T, slab]
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                # only this head's lanes of pv are its p @ v
+                acc = jnp.where(mine, acc * alpha + pv, acc)
+            acc_scr[:, lanes] = acc
+        return carry
 
-    init = (
-        jnp.full((heads, t, 1), -1e30, jnp.float32),
-        jnp.zeros((heads, t, 1), jnp.float32),
-        jnp.zeros((heads, t, d), jnp.float32),
-    )
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
-    # inactive rows/columns never accumulate; keep them finite instead
-    # of 0/0
-    out = acc / jnp.maximum(l, 1e-30)          # [H, T, D]
-    o_ref[0] = out.transpose(1, 0, 2).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+    for sl in range(heads // group):
+        lanes = pl.ds(sl * slab, slab)
+        denom = jnp.zeros((t, slab), jnp.float32)
+        for g in range(group):
+            denom = jnp.where(lane_head == g, l_scr[sl * group + g], denom)
+        # inactive rows/columns never accumulate; keep them finite
+        # instead of 0/0
+        o_ref[0, :, lanes] = (
+            acc_scr[:, lanes] / jnp.maximum(denom, 1e-30)
+        ).astype(o_ref.dtype)
 
 
-def _call(q3, k_pages4, v_pages4, page_table, lengths, positions, *,
-          page_size, pages_per_block, scale):
-    bsz, t, heads, d = q3.shape
-    qo_spec = pl.BlockSpec((1, t, heads, d),
-                           lambda b, pt, ln: (b, 0, 0, 0))
-    pos_spec = pl.BlockSpec((1, t), lambda b, pt, ln: (b, 0))
+def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
+          page_size, pages_per_block, scale, heads, head_dim):
+    bsz, t, hd = q3.shape
+    qo_spec = pl.BlockSpec((1, t, hd), lambda b, pt, ln: (b, 0, 0))
+    # [B, T, 1]: the block's last two dims are the array's own, which
+    # the TPU lowering takes at any T (a (1, T) block of [B, T] is not)
+    pos_spec = pl.BlockSpec((1, t, 1), lambda b, pt, ln: (b, 0, 0))
+    blk_slots = pages_per_block * page_size
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bsz,),
         in_specs=[
             pos_spec,
             qo_spec,
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),  # k pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((pages_per_block, page_size, heads, d), q3.dtype),
-            pltpu.VMEM((pages_per_block, page_size, heads, d), q3.dtype),
+            pltpu.VMEM((blk_slots, hd), k_pages3.dtype),
+            pltpu.VMEM((blk_slots, hd), v_pages3.dtype),
+            pltpu.VMEM((heads, t, 1), jnp.float32),   # running max
+            pltpu.VMEM((heads, t, 1), jnp.float32),   # running sum
+            pltpu.VMEM((t, hd), jnp.float32),         # accumulator
             pltpu.SemaphoreType.DMA((2, pages_per_block)),
         ],
     )
     return pl.pallas_call(
         functools.partial(
             _kernel, page_size=page_size, pages_per_block=pages_per_block,
-            scale=float(scale),
+            scale=float(scale), heads=heads, head_dim=head_dim,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, t, heads, d), q3.dtype),
+        out_shape=jax.ShapeDtypeStruct((bsz, t, hd), q3.dtype),
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        name="ragged_paged_attention",
+        compiler_params=pltpu.CompilerParams(
             # the scratch/DMA pattern serializes programs on-core anyway
             dimension_semantics=("arbitrary",),
         ),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      positions.astype(jnp.int32), q3, k_pages4, v_pages4)
+      positions.astype(jnp.int32)[:, :, None], q3, k_pages3, v_pages3)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, positions,
                            lengths, *, page_size, scale,
                            pages_per_block=None):
     """Mixed prefill+decode paged attention: q [B, T, H, D], flat pools
-    [num_slots, H, D], page_table [B, P] (pad rows with page 0),
+    [num_slots, H*D], page_table [B, P] (pad rows with page 0),
     positions [B, T] per-token global positions (-1 = inactive),
     lengths [B] valid token count incl. this step's (0 = inactive row).
     Returns [B, T, H, D]."""
@@ -183,15 +236,17 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, positions,
     if pages_per_block is None:
         pages_per_block = pick_pages_per_block(
             page_table.shape[1], page_size, d, num_heads=heads,
-            itemsize=q.dtype.itemsize,
+            itemsize=k_pages.dtype.itemsize,
         )
-    return _call(
-        q,
-        k_pages.reshape(num_pages, page_size, heads, d),
-        v_pages.reshape(num_pages, page_size, heads, d),
+    out = _call(
+        q.reshape(bsz, t, heads * d),
+        k_pages.reshape(num_pages, page_size, heads * d),
+        v_pages.reshape(num_pages, page_size, heads * d),
         page_table, lengths, positions,
         page_size=page_size, pages_per_block=pages_per_block, scale=scale,
+        heads=heads, head_dim=d,
     )
+    return out.reshape(bsz, t, heads, d)
 
 
 def ragged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
@@ -204,32 +259,3 @@ def ragged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         q, k_pages, v_pages, page_table, positions, lengths,
         page_size=page_size, scale=scale, pages_per_block=pages_per_block,
     )
-
-
-def probe_ok(dtype, bsz, width, heads, d, num_pages, page_size,
-             table_pages, pages_per_block):
-    """Fail-open compile probe (see ``backend.kernel_probe_ok``): lower
-    a single-sequence config with the production width/page_size/heads/
-    head-dim and block shape — the dims that pick the DMA/layout
-    lowering; grid size (batch) and pool page count shrink to minimum."""
-    del bsz, num_pages, table_pages  # grid/pool/table size never
-    # changes the lowering; only the block shape and dtypes do
-    key = ("ragged_paged_attention", str(dtype), int(width), heads, d,
-           int(page_size), int(pages_per_block))
-
-    def build():
-        pp = int(pages_per_block)
-        w = int(width)
-        kp = jnp.zeros(((pp + 1) * page_size, heads, d), dtype)
-        q = jnp.zeros((1, w, heads, d), dtype)
-        pt = jnp.zeros((1, max(pp, 1)), jnp.int32)
-        ln = jnp.full((1,), page_size, jnp.int32)
-        pos = jnp.minimum(jnp.arange(w, dtype=jnp.int32),
-                          page_size - 1)[None]
-        fn = functools.partial(
-            ragged_paged_attention, page_size=int(page_size),
-            scale=1.0, pages_per_block=pp,
-        )
-        jax.jit(fn).lower(q, kp, kp, pt, pos, ln).compile()
-
-    return kernel_probe_ok(key, build)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from unicore_tpu.ops.backend import pallas_interpret, tpu_compiler_params
+from unicore_tpu.ops.backend import pallas_interpret
 from unicore_tpu.ops.pallas.prng import keep_mask
 
 
@@ -188,7 +188,7 @@ def _softmax_dropout_fwd_impl(x, mask, bias, dropout_prob, q_blk, seed,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # every softmax row block is independent
             dimension_semantics=("parallel",) * len(grid),
         ),
@@ -229,7 +229,7 @@ def _bwd(dropout_prob, q_blk, residuals, g):
         out_specs=[xs],
         out_shape=[jax.ShapeDtypeStruct(x_shape, sm.dtype)],
         interpret=pallas_interpret(),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * len(grid),
         ),
     )(jnp.atleast_1d(jnp.asarray(seed, dtype=jnp.int32)), g, sm)[0]

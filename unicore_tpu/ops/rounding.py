@@ -15,7 +15,7 @@ selects between them.
 import jax
 import jax.numpy as jnp
 
-from .backend import use_pallas
+from .backend import needs_shard_map, note_dispatch, use_pallas
 
 
 def fp32_to_bf16_sr_reference(x, rng):
@@ -41,21 +41,13 @@ def fp32_to_bf16_sr(x, rng):
     from unicore_tpu.ops import tuning
 
     decision = tuning.sr_cast_decision(x.size, str(x.dtype))
-    if decision == "eager":
-        return fp32_to_bf16_sr_reference(x, rng)
-    if use_pallas() or isinstance(decision, dict):
-        from .backend import kernel_probe_ok
+    # under a multi-device mesh GSPMD partitions the reference and cannot
+    # partition a Mosaic kernel
+    take_kernel = decision != "eager" and not needs_shard_map() and (
+        use_pallas() or isinstance(decision, dict)
+    )
+    if note_dispatch("fp32_to_bf16_sr", "n%d" % x.size, take_kernel):
         from .pallas import rounding as pl_impl
 
-        _, r_blk = pl_impl.pick_layout(x.size)
-
-        def build():
-            # rows = r_blk re-picks the same block → identical BlockSpec
-            px = jnp.zeros((r_blk * pl_impl._LANE,), jnp.float32)
-            jax.jit(pl_impl.fp32_to_bf16_sr).lower(
-                px, jax.random.PRNGKey(0)
-            ).compile()
-
-        if kernel_probe_ok(("fp32_to_bf16_sr", r_blk), build):
-            return pl_impl.fp32_to_bf16_sr(x, rng)
+        return pl_impl.fp32_to_bf16_sr(x, rng)
     return fp32_to_bf16_sr_reference(x, rng)

@@ -32,7 +32,7 @@ grid-dependent), so a cache write can never flip a decision mid-trace.
 
 Multi-host runs read ONLY the committed repo cache and never tune:
 per-host overlays could disagree and trace different programs into one
-SPMD step (the ``kernel_timed_winner`` multi-host rule).
+SPMD step.
 """
 
 import contextlib
@@ -57,7 +57,7 @@ def _static_verdict_keys():
     entries these are fingerprint-independent: they encode a structural
     result, not a device timing.
 
-    The one entry today: the BENCH_r05 evoformer softmax_dropout shape
+    The one entry today: the r5-era evoformer softmax_dropout shape
     ([1,128,4,128,128] bf16, 5-D broadcast mask/bias) measured
     0.985-0.994x eager across rounds — the kernel's 128x128 row blocks
     leave only 16K elements per grid program, under the fixed-cost

@@ -9,7 +9,7 @@ Each tunable op registers an :class:`OpSpec` naming
 - ``candidates(workload)`` — the bounded config set.  ``"eager"`` is
   ALWAYS a candidate: when the plain-XLA composition beats every kernel
   config for a bucket, the cache records it and dispatch skips the
-  kernel (the BENCH_r05 evoformer case, 0.985x, becomes an automatic
+  kernel (the r5-era evoformer case, 0.985x, becomes an automatic
   win instead of a silent regression).
 - ``build_runner(workload, config)`` — an AOT-compiled zero-arg step of
   the op (fwd+bwd, the training cost) under that config.
@@ -180,9 +180,7 @@ def _flash_bias_class(wl):
     # dtype + q-broadcastness only: both drive the block-size budget (a
     # bQ==1 bias streams ~KBs; a full bias doubles the score-block
     # stream).  Head-broadcastness is deliberately NOT bucketed — block
-    # choice is independent of it, and probe_ok's multi-block heads
-    # collapse must resolve the SAME bucket inside and outside its build
-    # or the probed blocks could diverge from the production blocks.
+    # choice is independent of it.
     if wl["bias"] is None:
         return None
     shape, dt = wl["bias"]
@@ -357,7 +355,7 @@ def _ragged_args(wl, width):
     pages, ps = wl["table_pages"], wl["page_size"]
     num_pages = bsz * pages + 1  # page 0 reserved (trash)
     q = _zeros((bsz, width, heads, d), wl["dtype"])
-    pool = _zeros((num_pages * ps, heads, d), wl["dtype"])
+    pool = _zeros((num_pages * ps, heads * d), wl["dtype"])
     table = (1 + jnp.arange(bsz * pages, dtype=jnp.int32).reshape(
         bsz, pages))
     lengths = jnp.full((bsz,), pages * ps, jnp.int32)
@@ -621,7 +619,7 @@ OPS = {
 
 
 # Preset workloads for the CLI: the shapes the bench and the flagship
-# configs actually run (BENCH_r05 micro set).
+# configs actually run (the r5-era micro set).
 PRESETS = {
     "sd_bert": sd_workload(
         (32, 12, 512, 512), "bfloat16",

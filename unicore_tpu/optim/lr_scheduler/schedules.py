@@ -21,12 +21,9 @@ import math
 
 
 def _traced(*xs):
-    try:
-        import jax.core
+    import jax.core
 
-        return any(isinstance(x, jax.core.Tracer) for x in xs)
-    except Exception:  # pragma: no cover - jax always present in practice
-        return False
+    return any(isinstance(x, jax.core.Tracer) for x in xs)
 
 
 def _where(cond, a, b):

@@ -13,9 +13,7 @@ def batch_shard_index(batch_axes):
     shard_map."""
     lin = 0
     for ax in (batch_axes or ()):
-        from ._compat import axis_size
-
-        lin = lin * axis_size(ax) + jax.lax.axis_index(ax)
+        lin = lin * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return lin
 
 

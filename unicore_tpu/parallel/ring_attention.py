@@ -75,9 +75,7 @@ def ring_attention(q, k, v, axis_name, bias=None, key_padding_mask=None,
     carry must be typed device-varying over all of them, not just the
     ring axis).  Returns [B, T_local, H, D].
     """
-    from ._compat import axis_size
-
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, t_local, h, d = q.shape
     if scale is None:
@@ -135,13 +133,10 @@ def ring_attention(q, k, v, axis_name, bias=None, key_padding_mask=None,
     body = jax.checkpoint(body)
 
     # scan carries must be typed device-varying over every shard_map axis
-    # (a no-op on jax versions without varying-type checking — _compat)
     axes = tuple(varying_axes) if varying_axes else (axis_name,)
 
     def vary(x):
-        from ._compat import vary as _vary
-
-        return _vary(x, axes)
+        return jax.lax.pcast(x, axes, to="varying")
 
     m0 = vary(jnp.full((b, h, t_local, 1), NEG_INF, dtype=jnp.float32))
     l0 = vary(jnp.zeros((b, h, t_local, 1), dtype=jnp.float32))
@@ -205,9 +200,7 @@ def ring_self_attention(mesh, q, k, v, bias=None, key_padding_mask=None,
     def call(q_, k_, v_, *extras):
         return fn(q_, k_, v_, **dict(zip(kw_order, extras)))
 
-    from ._compat import shard_map
-
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         call, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_spec
     )
     return wrapped(*operands)

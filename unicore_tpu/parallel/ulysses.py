@@ -62,7 +62,7 @@ def _local_attention(q, k, v, bias, key_padding_mask, causal, scale,
 def _flash_local_ok(q_shape, k_shape, bias_shape, bias_dtype, has_pad,
                     causal, dropout_on, dtype):
     """Can the flash kernel take the LOCAL (post-all-to-all) attention?
-    Checked with the local shapes; fail-open to the materialized path."""
+    Checked with the local shapes."""
     from unicore_tpu.ops.backend import use_pallas
     from unicore_tpu.ops.pallas import flash_attention as fa
 
@@ -88,12 +88,7 @@ def _flash_local_ok(q_shape, k_shape, bias_shape, bias_dtype, has_pad,
     )
     if tune_dec == "eager" and get_kernel_backend() != "pallas":
         return False
-    return fa.probe_ok(
-        dtype, t, k_shape[1], d,
-        None if bias_shape is None else bias_shape[2],
-        bias_dtype, has_pad, causal, dropout_on, heads=h_local,
-        bias_heads=None if bias_shape is None else bias_shape[1],
-    )
+    return True
 
 
 def ulysses_attention(q, k, v, axis_name, bias=None, key_padding_mask=None,
@@ -105,9 +100,7 @@ def ulysses_attention(q, k, v, axis_name, bias=None, key_padding_mask=None,
     ``key_padding_mask``: [B, T] bool (True = pad), full key axis.
     ``dropout_p``/``base_seed``: attention dropout — ``base_seed`` is a
     replicated int32 scalar; per-device decorrelation happens here."""
-    from ._compat import axis_size
-
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, t_local, h, d = q.shape
     assert h % n == 0, f"heads ({h}) must divide seq-parallel size ({n})"
     if scale is None:
@@ -216,9 +209,7 @@ def ulysses_self_attention(mesh, q, k, v, bias=None, key_padding_mask=None,
     def call(q_, k_, v_, *extras):
         return fn(q_, k_, v_, **dict(zip(kw_order, extras)))
 
-    from ._compat import shard_map
-
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         call, mesh=mesh, in_specs=tuple(in_specs), out_specs=qkv_spec
     )
     return wrapped(*operands)

@@ -1,7 +1,9 @@
 """Paged attention over page tables: the serve tier's attention core.
 
 Layouts: queries keep the module convention ``[B, T, H, D]``; the pool
-is FLAT — ``k_pages``/``v_pages`` are ``[num_slots, H, D]`` where slot
+is FLAT — ``k_pages``/``v_pages`` are ``[num_slots, H*D]`` (heads folded
+into the minor dim, so a page is a tile-aligned slab the kernel can DMA
+and no HBM tile is half empty at ``D == 64``) where slot
 ``page * page_size + offset`` holds the token at ``position`` such that
 ``page == position // page_size`` in that sequence's table.  Gathering a
 sequence's pages in table order therefore reproduces its keys in
@@ -20,10 +22,12 @@ Two implementations:
   single decode token or a prefill chunk, both in the SAME program —
   DMAs that row's pages HBM -> VMEM and accumulates an online softmax
   per (head, query); the gathered ``[B, S, H, D]`` key tensor never
-  materializes.  Gated through ``ops/backend.py`` (``use_pallas`` +
-  fail-open compile probe) and the PR-2 autotuner (op
+  materializes.  Gated through ``ops/backend.py`` (``use_pallas``),
+  the kernel's static shape rule and the PR-2 autotuner (op
   ``"ragged_paged_attention"``): an ``"eager"`` verdict for the bucket
-  routes around the kernel, a config dict picks its page block.
+  routes around the kernel, a config dict picks its page block.  The
+  path each compiled width took is in ``backend.dispatch_report()``;
+  a kernel the chip's compiler refuses fails the step's compile.
 """
 
 import dataclasses
@@ -53,7 +57,7 @@ class PagedMeta:
 
 
 def gather_slots(pages, page_table, page_size):
-    """[num_slots, H, D] pool + [B, P] tables -> [B, P*page_size, H, D]
+    """[num_slots, H*D] pool + [B, P] tables -> [B, P*page_size, H*D]
     position-ordered per-sequence views (XLA lowers this to one gather)."""
     bsz, npages = page_table.shape
     flat = (page_table[:, :, None] * page_size
@@ -69,8 +73,11 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, positions,
     inactive row -> fully masked; output rows for those are garbage by
     contract and discarded by the caller)."""
     del lengths  # the position compare subsumes the length mask
-    k = gather_slots(k_pages, page_table, page_size)  # [B, S, H, D]
-    v = gather_slots(v_pages, page_table, page_size)
+    bsz, _, heads, d = q.shape
+    k = gather_slots(k_pages, page_table, page_size).reshape(
+        bsz, -1, heads, d)  # [B, S, H, D]
+    v = gather_slots(v_pages, page_table, page_size).reshape(
+        bsz, -1, heads, d)
     s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
     cols = jnp.arange(k.shape[1], dtype=jnp.int32)
     # column j of the gathered view IS position j; bottom-right causal
@@ -84,43 +91,46 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, positions,
 
 
 def _kernel_ok(q, k_pages, page_table, page_size):
-    """Whether the Pallas ragged kernel should take this call: TPU
-    backend, tuner verdict not "eager", and the config compile-probes
-    (fail-open).  Both serve dispatch widths (the pure-decode T=1 and
-    the prefill-chunk T=C program) go through the same gate — the
-    bucket key carries the width."""
-    from unicore_tpu.ops.backend import get_kernel_backend, use_pallas
+    """Pages per block when the Pallas ragged kernel takes this call,
+    else None: TPU backend, a shape the compiled kernel supports, and a
+    tuner verdict that is not "eager".  Both serve dispatch widths (the
+    pure-decode T=1 and the prefill-chunk T=C program) go through the
+    same gate — the bucket key carries the width."""
+    from unicore_tpu.ops.backend import (
+        get_kernel_backend, pallas_interpret, use_pallas,
+    )
 
     if not use_pallas():
         return None
     from unicore_tpu.ops import tuning
     from unicore_tpu.ops.pallas import paged_attention as pl_pa
 
+    if not pallas_interpret() and not pl_pa.supported(
+        q.shape[2], q.shape[3], page_size, k_pages.dtype.itemsize
+    ):
+        return None
     decision = tuning.ragged_paged_decision(
         q.shape, page_table.shape[1], page_size, q.dtype.name,
         allow_tune=True,
     )
     if decision == "eager" and get_kernel_backend() != "pallas":
         return None
-    pages_per_block = pl_pa.pick_pages_per_block(
+    return pl_pa.pick_pages_per_block(
         page_table.shape[1], page_size, q.shape[3],
         tuned=tuning.tuned_pages_per_block(page_table.shape[1], decision),
-        num_heads=q.shape[2], itemsize=q.dtype.itemsize,
+        num_heads=q.shape[2], itemsize=k_pages.dtype.itemsize,
     )
-    if not pl_pa.probe_ok(
-        q.dtype, q.shape[0], q.shape[1], q.shape[2], q.shape[3],
-        k_pages.shape[0] // page_size, page_size, page_table.shape[1],
-        pages_per_block,
-    ):
-        return None
-    return pages_per_block
 
 
 def paged_attention(q, k_pages, v_pages, page_table, positions, lengths,
                     page_size, scale):
     """Dispatching paged attention (see module docstring)."""
+    from unicore_tpu.ops.backend import note_dispatch
+
     pages_per_block = _kernel_ok(q, k_pages, page_table, page_size)
-    if pages_per_block is not None:
+    desc = "b%d w%d h%d d%d page%d %s" % (*q.shape, page_size, q.dtype.name)
+    if note_dispatch("ragged_paged_attention", desc,
+                     pages_per_block is not None):
         from unicore_tpu.ops.pallas import paged_attention as pl_pa
 
         return pl_pa.ragged_paged_attention(

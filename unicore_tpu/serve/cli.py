@@ -388,8 +388,11 @@ def main(argv=None):
             f"{args.max_replicas}: the autoscale envelope is empty"
         )
 
+    from unicore_tpu.ops.backend import dispatch_report
     from unicore_tpu.serve.engine import ServeEngine
+    from unicore_tpu.utils import configure_compile_cache
 
+    configure_compile_cache()
     if args.demo:
         model, params = _demo_model(args.seed)
         rng = np.random.default_rng(args.seed)
@@ -462,6 +465,8 @@ def main(argv=None):
                   for k, v in engine.stats.items()},
         "drain": engine.drain_report,
         "pool_clean": pool_clean,
+        # which path each compiled width's attention took
+        "kernel_dispatch": dispatch_report(),
     }
     if shutdown.requested and engine.drain_report is None:
         # the signal landed after the last step boundary: nothing was

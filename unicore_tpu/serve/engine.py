@@ -107,6 +107,15 @@ class WeightSwapError(RuntimeError):
     any other bad-manifest fault: quarantine and roll back."""
 
 
+class StepCompileError(RuntimeError):
+    """The first call of a serve step at some (width, sampling) failed:
+    tracing, lowering or compiling it raised (nothing is donated before
+    that).  This is a fault of the PROGRAM, not of the requests in
+    flight — failing them and carrying on would end a run with exit 0
+    and no token served — so ``serve_step`` lets it propagate past the
+    per-request fault isolation."""
+
+
 DEFAULT_PREFILL_CHUNK = 32
 
 
@@ -167,6 +176,9 @@ class ServeEngine:
         # never produces a lowering outside serve_step_widths()
         self.width_fn = self._width_for
         self._step_fns = {}
+        # (width, sampling) keys whose step has run once: a failure
+        # before that is a StepCompileError, not a host fault
+        self._step_ran = set()
         # Pass-5 determinism harness hook: when set, called with
         # ((width, sampling), args) BEFORE the jitted call consumes
         # (donates) the pages — tools/unicore_determinism.py captures
@@ -256,9 +268,10 @@ class ServeEngine:
             from unicore_tpu.ops import tuning
 
             leaf = jax.tree_util.tree_leaves(self.pages)[0]
+            heads = int(self.model.decoder_attention_heads)
             return tuning.tuned_prefill_chunk(tuning.ragged_paged_decision(
                 (self.max_batch, default_chunk,
-                 leaf.shape[1], leaf.shape[2]),
+                 heads, leaf.shape[1] // heads),
                 self.table_width, self.page_size, leaf.dtype.name,
             ), default_chunk)
         except Exception as e:  # noqa: BLE001 - fail open to the default
@@ -566,8 +579,18 @@ class ServeEngine:
             # the moment it is issued
             self._input_capture((w, sampling), args)
         t0 = time.perf_counter()
+        step_fn = self._ragged_step_fn(w, sampling)
         with self._armed(f"serve/ragged-w{w}"):
-            toks, ok, self.pages = self._ragged_step_fn(w, sampling)(*args)
+            try:
+                toks, ok, self.pages = step_fn(*args)
+            except Exception as exc:
+                if (w, sampling) in self._step_ran:
+                    raise
+                raise StepCompileError(
+                    f"serve step ragged-w{w}/{sampling} failed on its "
+                    f"first call (trace, lowering or compile): {exc}"
+                ) from exc
+            self._step_ran.add((w, sampling))
             toks = np.asarray(toks)  # host sync: the scheduler needs them
             ok = np.asarray(ok)
         dt = time.perf_counter() - t0
@@ -911,6 +934,8 @@ class ServeEngine:
                 if todo:
                     try:
                         self._step_rows(todo)
+                    except StepCompileError:
+                        raise  # the program is broken, not a request
                     except Exception as exc:  # host fault isolation
                         self._host_fault(todo, "ragged-step", exc)
                     did_dispatch = True

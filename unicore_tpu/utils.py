@@ -31,6 +31,22 @@ def _jax():
     return jax
 
 
+def configure_compile_cache():
+    """Point jax's persistent compilation cache at a place that stays
+    put, and return that place.  Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set jax reads it itself and nothing is set here; otherwise the cache
+    is ``<checkout>/.jax_cache`` (git-ignored) — a fixed path, because
+    the path is part of the cache key and a directory that moves never
+    hits.  Called by every entry point before its first compile."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(checkout, ".jax_cache")
+    _jax().config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # ---------------------------------------------------------------------------
 # Tree utilities (reference: apply_to_sample utils.py:38, tree_map :386,
 # tensor_tree_map :402)

@@ -514,12 +514,9 @@ def cli_main(modify_parser: Optional[argparse.ArgumentParser] = None) -> None:
     parser = options.get_training_parser()
     args = options.parse_args_and_arch(parser, modify_parser=modify_parser)
     if getattr(args, "cpu", False):
-        import jax
-
         jax.config.update("jax_platforms", "cpu")
+    utils.configure_compile_cache()
     if getattr(args, "profile", False):
-        import jax
-
         with jax.profiler.trace(
             os.path.join(args.save_dir, "jax_trace"),
             create_perfetto_link=False,
