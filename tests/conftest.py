@@ -56,6 +56,18 @@ def _no_mesh_left_by_an_earlier_trainer():
     backend.set_spmd_mesh(None)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_dispatch_record_left_by_an_earlier_file():
+    """``ops.backend`` records process-wide which path every kernel shape
+    took, and a benchmark cell's run checks the WHOLE record against the
+    path its file names: a test file must not inherit the record of a
+    file that forced the other path (an xdist worker runs several files,
+    in an order that shifts whenever a file is added)."""
+    from unicore_tpu.ops import backend
+
+    backend._DISPATCH.clear()
+
+
 def pytest_configure(config):
     # "slow": excluded from the tier-1 gate (pytest -m 'not slow') but
     # run by the CI workflow's full `pytest tests/` step — for tests

@@ -706,6 +706,7 @@ def _flash_fwd_impl(q, k, v, bias, pad, dropout_prob, seed, causal, scale):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=pallas_interpret(),
+        name="flash_attention_fwd",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
@@ -745,6 +746,7 @@ def _flash_fwd_hb(q, k, v, bias, pad, dropout_prob, seed, causal, scale,
             jax.ShapeDtypeStruct((bsz, heads, tq, 1), jnp.float32),
         ],
         interpret=pallas_interpret(),
+        name="flash_attention_fwd_hb",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024,  # see the backward's note
@@ -827,6 +829,7 @@ def _flash_bwd(dropout_prob, causal, scale, residuals, g):
                 pltpu.VMEM((tk, d), jnp.float32),
             ],
             interpret=pallas_interpret(),
+            name="flash_attention_bwd_joint",
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary",
                                      "arbitrary"),
@@ -853,6 +856,7 @@ def _flash_bwd(dropout_prob, causal, scale, residuals, g):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_dq",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
@@ -899,6 +903,7 @@ def _flash_bwd(dropout_prob, causal, scale, residuals, g):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_dkv",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
@@ -966,6 +971,7 @@ def _dbias_pass(q, k, v, bias, pad, seed, lse, delta, g, dropout_prob,
         out_shape=jax.ShapeDtypeStruct((heads, tq, tk), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_dbias",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -1066,6 +1072,7 @@ def _flash_bwd_fused(q, k, v, bias, pad, seed, lse, delta, g, dropout_prob,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=pallas_interpret(),
+        name="flash_attention_bwd_hb",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             # the hb-batched working set legitimately exceeds the 16MB

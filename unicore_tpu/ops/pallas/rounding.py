@@ -63,5 +63,6 @@ def fp32_to_bf16_sr(x, rng):
         out_specs=pl.BlockSpec((r_blk, _LANE), lambda i: (i, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, _LANE), jnp.bfloat16),
         interpret=pallas_interpret(),
+        name="fp32_to_bf16_sr",
     )(seed, x2d)
     return out.ravel()[:n].reshape(shape)

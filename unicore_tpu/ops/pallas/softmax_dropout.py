@@ -188,6 +188,7 @@ def _softmax_dropout_fwd_impl(x, mask, bias, dropout_prob, q_blk, seed,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=pallas_interpret(),
+        name="softmax_dropout_fwd",
         compiler_params=pltpu.CompilerParams(
             # every softmax row block is independent
             dimension_semantics=("parallel",) * len(grid),
@@ -229,6 +230,7 @@ def _bwd(dropout_prob, q_blk, residuals, g):
         out_specs=[xs],
         out_shape=[jax.ShapeDtypeStruct(x_shape, sm.dtype)],
         interpret=pallas_interpret(),
+        name="softmax_dropout_bwd",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * len(grid),
         ),

@@ -29,6 +29,7 @@ every pool.
 import argparse
 import json
 import logging
+import os
 import sys
 
 import numpy as np
@@ -173,6 +174,11 @@ def make_parser():
                           "harness's mid-stream SIGTERM trigger)")
     p.add_argument("--json", dest="json_out",
                    help="write the report here instead of stdout")
+    p.add_argument("--profile", metavar="DIR", default=None,
+                   help="capture a jax profiler trace of the run (xplane "
+                        "format) under DIR/jax_trace: the engine's "
+                        "serve/* spans on the device operations' clock "
+                        "(docs/serving.md#tracing-a-serving-process)")
     return p
 
 
@@ -248,6 +254,7 @@ def _result_record(r):
         "prompt": r.prompt,
         "tokens": r.tokens,
         "finish_reason": r.finish_reason,
+        "queue_ms": None if r.queue_ms is None else round(r.queue_ms, 2),
         "ttft_ms": None if r.ttft_ms is None else round(r.ttft_ms, 2),
         "evictions": r.evictions,
     }
@@ -388,11 +395,29 @@ def main(argv=None):
             f"{args.max_replicas}: the autoscale envelope is empty"
         )
 
-    from unicore_tpu.ops.backend import dispatch_report
-    from unicore_tpu.serve.engine import ServeEngine
     from unicore_tpu.utils import configure_compile_cache
 
     configure_compile_cache()
+    if not args.profile:
+        return _serve(args)
+    import jax
+
+    # host spans on, the Python tracer off: it would record every
+    # Python call and slow the host path the trace is taken to time
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    with jax.profiler.trace(os.path.join(args.profile, "jax_trace"),
+                            profiler_options=options):
+        return _serve(args)
+
+
+def _serve(args):
+    """Build the model, the requests and the engine (or the fleet),
+    run to completion, write the report."""
+    from unicore_tpu.ops.backend import dispatch_report
+    from unicore_tpu.serve.engine import ServeEngine
+
     if args.demo:
         model, params = _demo_model(args.seed)
         rng = np.random.default_rng(args.seed)
@@ -449,18 +474,7 @@ def main(argv=None):
     pool_clean = engine.pool.is_idle()
     engine.pool.check_invariants()
     report = {
-        "results": [
-            {
-                "request_id": r.request_id,
-                "prompt": r.prompt,
-                "tokens": r.tokens,
-                "finish_reason": r.finish_reason,
-                "ttft_ms": (None if r.ttft_ms is None
-                            else round(r.ttft_ms, 2)),
-                "evictions": r.evictions,
-            }
-            for r in results
-        ],
+        "results": [_result_record(r) for r in results],
         "stats": {k: (round(v, 4) if isinstance(v, float) else v)
                   for k, v in engine.stats.items()},
         "drain": engine.drain_report,

@@ -22,9 +22,31 @@ lookup instead of a prefill (``prefix_cache=True``), with the partial
 tail page always privately owned (copy-on-write by recompute), so one
 session's decode never mutates another's shared page.
 
-Metrics: per-request TTFT, aggregate decode tokens/sec, pool occupancy
-and prefix-cache hit stats (peak + per-step into
-``unicore_tpu.metrics`` when an aggregation context is active).
+Metrics: per-request queue wait and TTFT, and the counters of
+:attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
+occupancy, prefix-cache hits), which the JSON report and the fleet
+router read.
+
+Tracing: a ``serve_step`` that has work records the span tree below as
+``jax.profiler.TraceAnnotation``s, so the spans land in the profiler's
+trace on the clock of the device operations (``unicore-serve --profile``,
+or the benchmark's traced window) and an idle gap of the device can be
+put down to the phase the host was in.  With no trace running an
+annotation costs well under a microsecond::
+
+    serve/step                one scheduler iteration that had work
+      serve/schedule          expiry, drain, capacity fail-fast, admission,
+                              chaos preemption, prepare_decode
+        serve/admit           Scheduler.admit: prefix match, can_alloc, alloc
+      serve/plan              _plan_rows
+      serve/assemble          the numpy rows of one dispatch
+      serve/transfer          every per-step argument onto the device
+      serve/dispatch-w<n>     the compiled step at width n, until the
+                              sampled tokens are on the host
+        serve/launch          the compiled call returning
+        serve/fetch           the sampled tokens and row flags to the host
+      serve/emit              counters, quarantine, prefill watermark,
+                              register_prefix, _emit
 
 Robustness (ISSUE 7), layered on the ``resilience/`` machinery:
 
@@ -64,6 +86,7 @@ same pieces, so solo-engine and fleet behavior cannot diverge.
 
 import contextlib
 import dataclasses
+import functools
 import logging
 import os
 import time
@@ -75,14 +98,32 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from unicore_tpu.logging import metrics
-
 from .attention import PagedMeta
 from .kv_pool import PagedKVPool, PoolExhausted
 from .sampling import finite_rows, sample_tokens, step_keys
 from .scheduler import DEFAULT_REQUEST_RETRIES, Scheduler
 
 logger = logging.getLogger(__name__)
+
+# the span tree of one serve_step (module docstring); the benchmark's
+# readers (benchmarks/lib/span_readers.py) know the spans by these names
+_span = jax.profiler.TraceAnnotation
+SPAN_STEP = "serve/step"
+SPAN_SCHEDULE = "serve/schedule"
+SPAN_ADMIT = "serve/admit"
+SPAN_PLAN = "serve/plan"
+SPAN_ASSEMBLE = "serve/assemble"
+SPAN_TRANSFER = "serve/transfer"
+SPAN_DISPATCH = "serve/dispatch-w{width}"
+SPAN_LAUNCH = "serve/launch"
+SPAN_FETCH = "serve/fetch"
+SPAN_EMIT = "serve/emit"
+
+
+@functools.lru_cache(maxsize=None)
+def _dispatch_span(width):
+    """The dispatch span's name, formatted once per compiled width."""
+    return SPAN_DISPATCH.format(width=width)
 
 
 @dataclasses.dataclass
@@ -96,6 +137,9 @@ class ServeResult:
     finish_reason: str
     ttft_ms: Optional[float]   # None when no token was ever emitted
     evictions: int
+    # enqueue to first admission: the scheduler's part of ttft_ms (the
+    # rest is prefill); None when the request was never admitted
+    queue_ms: Optional[float] = None
 
 
 class WeightSwapError(RuntimeError):
@@ -443,7 +487,6 @@ class ServeEngine:
         )
         self.scheduler.finish(seq, "failed")
         self.stats["quarantined"] += 1
-        metrics.log_scalar("serve/quarantined", self.stats["quarantined"])
 
     @staticmethod
     def _is_decode_ready(seq):
@@ -500,78 +543,80 @@ class ServeEngine:
         request's blast radius from 1 to ``max_batch`` (the per-seq
         isolation the old split prefill path had).  Only a fault in the
         compiled call itself still fails the whole in-flight batch."""
-        B = self.max_batch
-        w = self.width_fn(max(m for _, _, m, _, _ in rows))
-        assert all(m <= w for _, _, m, _, _ in rows), (rows, w)
-        tokens = np.zeros((B, w), np.int32)
-        positions = np.full((B, w), -1, np.int32)
-        tables = np.zeros((B, self.table_width), np.int32)
-        slot_mapping = np.zeros((B * w,), np.int32)  # 0 = trash slot
-        lengths = np.zeros((B,), np.int32)
-        last_col = np.zeros((B,), np.int32)
-        temperature = np.zeros((B,), np.float32)
-        top_k = np.zeros((B,), np.int32)
-        seeds = np.zeros((B,), np.int32)
-        steps = np.zeros((B,), np.int32)
-        packed = []
-        for seq, start, m, emit, dec in rows:
-            if seq.done:
-                continue  # failed through an earlier row this step
-            b = len(packed)
-            try:
-                prefix = seq.prefix()
-                ptable = np.asarray(self.pool.page_table(seq.sid),
-                                    np.int32)
-                pos = np.arange(start, start + m)
-                page_idx = pos // self.page_size
-                if page_idx[-1] >= len(ptable):
-                    raise IndexError(
-                        f"position {start + m - 1} beyond the "
-                        f"{len(ptable)} page(s) of sequence {seq.sid!r}"
+        with _span(SPAN_ASSEMBLE):
+            B = self.max_batch
+            w = self.width_fn(max(m for _, _, m, _, _ in rows))
+            assert all(m <= w for _, _, m, _, _ in rows), (rows, w)
+            tokens = np.zeros((B, w), np.int32)
+            positions = np.full((B, w), -1, np.int32)
+            tables = np.zeros((B, self.table_width), np.int32)
+            slot_mapping = np.zeros((B * w,), np.int32)  # 0 = trash slot
+            lengths = np.zeros((B,), np.int32)
+            last_col = np.zeros((B,), np.int32)
+            temperature = np.zeros((B,), np.float32)
+            top_k = np.zeros((B,), np.int32)
+            seeds = np.zeros((B,), np.int32)
+            steps = np.zeros((B,), np.int32)
+            packed = []
+            for seq, start, m, emit, dec in rows:
+                if seq.done:
+                    continue  # failed through an earlier row this step
+                b = len(packed)
+                try:
+                    prefix = seq.prefix()
+                    ptable = np.asarray(self.pool.page_table(seq.sid),
+                                        np.int32)
+                    pos = np.arange(start, start + m)
+                    page_idx = pos // self.page_size
+                    if page_idx[-1] >= len(ptable):
+                        raise IndexError(
+                            f"position {start + m - 1} beyond the "
+                            f"{len(ptable)} page(s) of sequence {seq.sid!r}"
+                        )
+                    tokens[b, :m] = prefix[start:start + m]
+                    positions[b, :m] = pos
+                    tables[b, :len(ptable)] = ptable
+                    # a chunk's write slots, vectorized: one table fetch per
+                    # row instead of a per-token pool.slot() call
+                    slot_mapping[b * w:b * w + m] = (
+                        ptable[page_idx] * self.page_size
+                        + pos % self.page_size
                     )
-                tokens[b, :m] = prefix[start:start + m]
-                positions[b, :m] = pos
-                tables[b, :len(ptable)] = ptable
-                # a chunk's write slots, vectorized: one table fetch per
-                # row instead of a per-token pool.slot() call
-                slot_mapping[b * w:b * w + m] = (
-                    ptable[page_idx] * self.page_size
-                    + pos % self.page_size
-                )
-                lengths[b] = start + m
-                last_col[b] = m - 1
-                temperature[b] = seq.req.temperature
-                top_k[b] = seq.req.top_k
-                seeds[b] = seq.req.seed
-                steps[b] = len(seq.generated)
-            except Exception as exc:  # noqa: BLE001 - per-row isolation
-                # scrub the half-written row (trash-slot defaults) and
-                # fail ONLY this sequence
-                tokens[b] = 0
-                positions[b] = -1
-                tables[b] = 0
-                slot_mapping[b * w:(b + 1) * w] = 0
-                lengths[b] = 0
-                self._host_fault([seq], "row-assembly", exc)
-                continue
-            packed.append((seq, start, m, emit, dec))
-        rows = packed
+                    lengths[b] = start + m
+                    last_col[b] = m - 1
+                    temperature[b] = seq.req.temperature
+                    top_k[b] = seq.req.top_k
+                    seeds[b] = seq.req.seed
+                    steps[b] = len(seq.generated)
+                except Exception as exc:  # noqa: BLE001 - per-row isolation
+                    # scrub the half-written row (trash-slot defaults) and
+                    # fail ONLY this sequence
+                    tokens[b] = 0
+                    positions[b] = -1
+                    tables[b] = 0
+                    slot_mapping[b * w:(b + 1) * w] = 0
+                    lengths[b] = 0
+                    self._host_fault([seq], "row-assembly", exc)
+                    continue
+                packed.append((seq, start, m, emit, dec))
+            rows = packed
         if not rows:
             return
         sampling = self._sampling_mode([r[0] for r in rows])
-        args = [
-            self.params, self.pages,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(slot_mapping),
-            jnp.asarray(lengths), jnp.asarray(last_col),
-            jnp.asarray(seeds), jnp.asarray(steps),
-            jnp.asarray(temperature), jnp.asarray(top_k),
-        ]
-        if self._chaos_poison:
-            poison = np.zeros((B,), bool)
-            for b, (seq, *_rest) in enumerate(rows):
-                poison[b] = self._poison_row(seq)
-            args.append(jnp.asarray(poison))
+        with _span(SPAN_TRANSFER):
+            args = [
+                self.params, self.pages,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(tables), jnp.asarray(slot_mapping),
+                jnp.asarray(lengths), jnp.asarray(last_col),
+                jnp.asarray(seeds), jnp.asarray(steps),
+                jnp.asarray(temperature), jnp.asarray(top_k),
+            ]
+            if self._chaos_poison:
+                poison = np.zeros((B,), bool)
+                for b, (seq, *_rest) in enumerate(rows):
+                    poison[b] = self._poison_row(seq)
+                args.append(jnp.asarray(poison))
         any_decode = any(r[4] for r in rows)
         if self._input_capture is not None:
             # determinism-harness capture: before the call — the jit
@@ -580,44 +625,48 @@ class ServeEngine:
             self._input_capture((w, sampling), args)
         t0 = time.perf_counter()
         step_fn = self._ragged_step_fn(w, sampling)
-        with self._armed(f"serve/ragged-w{w}"):
-            try:
-                toks, ok, self.pages = step_fn(*args)
-            except Exception as exc:
-                if (w, sampling) in self._step_ran:
-                    raise
-                raise StepCompileError(
-                    f"serve step ragged-w{w}/{sampling} failed on its "
-                    f"first call (trace, lowering or compile): {exc}"
-                ) from exc
-            self._step_ran.add((w, sampling))
-            toks = np.asarray(toks)  # host sync: the scheduler needs them
-            ok = np.asarray(ok)
+        with _span(_dispatch_span(w)), self._armed(f"serve/ragged-w{w}"):
+            with _span(SPAN_LAUNCH):
+                try:
+                    toks, ok, self.pages = step_fn(*args)
+                except Exception as exc:
+                    if (w, sampling) in self._step_ran:
+                        raise
+                    raise StepCompileError(
+                        f"serve step ragged-w{w}/{sampling} failed on its "
+                        f"first call (trace, lowering or compile): {exc}"
+                    ) from exc
+                self._step_ran.add((w, sampling))
+            with _span(SPAN_FETCH):
+                # host sync: the scheduler needs the tokens
+                toks = np.asarray(toks)
+                ok = np.asarray(ok)
         dt = time.perf_counter() - t0
-        self.stats["prefills"] += sum(1 for r in rows if not r[4])
-        if any_decode:
-            self.stats["decode_time_s"] += dt
-            self.decode_ms.append(dt * 1e3)
-            self.stats["decode_steps"] += 1
-            self.stats["decode_tokens"] += sum(1 for r in rows if r[4])
-            if self.progress_path:
-                with open(self.progress_path, "a") as fh:
-                    fh.write(f"{self.stats['decode_steps']}\n")
-        for b, (seq, start, m, emit, _) in enumerate(rows):
-            if seq.done:
-                continue  # quarantined through an earlier row this step
-            if not bool(ok[b]):
-                self._quarantine(seq, f"ragged-w{w}")
-                continue
-            seq.prefilled = start + m  # rows per seq are ascending
-            if (not seq.prefix_registered
-                    and seq.prefilled >= len(seq.req.prompt)):
-                # the prompt's KV is fully written: index its full
-                # pages so later shared-prefix requests dedup
-                self.pool.register_prefix(seq.sid, seq.req.prompt)
-                seq.prefix_registered = True
-            if emit:
-                self._emit(seq, int(toks[b]))
+        with _span(SPAN_EMIT):
+            self.stats["prefills"] += sum(1 for r in rows if not r[4])
+            if any_decode:
+                self.stats["decode_time_s"] += dt
+                self.decode_ms.append(dt * 1e3)
+                self.stats["decode_steps"] += 1
+                self.stats["decode_tokens"] += sum(1 for r in rows if r[4])
+                if self.progress_path:
+                    with open(self.progress_path, "a") as fh:
+                        fh.write(f"{self.stats['decode_steps']}\n")
+            for b, (seq, start, m, emit, _) in enumerate(rows):
+                if seq.done:
+                    continue  # quarantined through an earlier row this step
+                if not bool(ok[b]):
+                    self._quarantine(seq, f"ragged-w{w}")
+                    continue
+                seq.prefilled = start + m  # rows per seq are ascending
+                if (not seq.prefix_registered
+                        and seq.prefilled >= len(seq.req.prompt)):
+                    # the prompt's KV is fully written: index its full
+                    # pages so later shared-prefix requests dedup
+                    self.pool.register_prefix(seq.sid, seq.req.prompt)
+                    seq.prefix_registered = True
+                if emit:
+                    self._emit(seq, int(toks[b]))
 
     def _emit(self, seq, token):
         """Append one sampled token and settle termination."""
@@ -625,10 +674,6 @@ class ServeEngine:
         self.stats["generated_tokens"] += 1
         if seq.first_token_at is None:
             seq.first_token_at = self._clock()  # same clock as enqueued_at
-            metrics.log_scalar(
-                "serve/ttft_ms",
-                (seq.first_token_at - seq.enqueued_at) * 1e3,
-            )
         req = seq.req
         if req.eos_id is not None and token == req.eos_id:
             self.scheduler.finish(seq, "eos")
@@ -651,7 +696,6 @@ class ServeEngine:
         seqs = [self._enqueue(req) for req in requests]
         if self.scheduler.num_shed:
             self._sync_lifecycle_stats()
-            metrics.log_scalar("serve/shed", self.scheduler.num_shed)
         return seqs
 
     def _enqueue(self, req, generated=None):
@@ -708,7 +752,6 @@ class ServeEngine:
         seq = self._enqueue(request, generated=generated)
         if self.scheduler.num_shed:
             self._sync_lifecycle_stats()
-            metrics.log_scalar("serve/shed", self.scheduler.num_shed)
         return seq
 
     def generate(self, requests) -> List[ServeResult]:
@@ -762,6 +805,10 @@ class ServeEngine:
                 else (seq.first_token_at - seq.enqueued_at) * 1e3
             ),
             evictions=seq.evictions,
+            queue_ms=(
+                None if seq.admitted_at is None
+                else (seq.admitted_at - seq.enqueued_at) * 1e3
+            ),
         )
 
     def collect_finished(self) -> List[ServeResult]:
@@ -807,9 +854,6 @@ class ServeEngine:
         )
         self.scheduler.finish(seq, "capacity")
         self.stats["capacity_failfast"] += 1
-        metrics.log_scalar(
-            "serve/capacity_failfast", self.stats["capacity_failfast"]
-        )
 
     def _host_fault(self, seqs, phase, exc):
         """A host-side step fault (sampler bug, bad batch assembly)
@@ -834,7 +878,6 @@ class ServeEngine:
         for seq in failed:
             self.scheduler.finish(seq, "failed")
         self.stats["host_faults"] += 1
-        metrics.log_scalar("serve/host_faults", self.stats["host_faults"])
 
     def _run_to_completion(self, sched):
         del sched  # serve_step reads self.scheduler
@@ -851,7 +894,8 @@ class ServeEngine:
         separate programs — the old two-program shape, expressed
         through the same machinery so the comparison isolates the
         unification."""
-        rows = self._plan_rows(todo)
+        with _span(SPAN_PLAN):
+            rows = self._plan_rows(todo)
         if not rows:
             return
         if self.unified:
@@ -870,75 +914,83 @@ class ServeEngine:
         ragged dispatch (mixed prefill-chunk + decode rows).  Returns
         True while work remains queued — the fleet router's
         interleaving unit (and what ``generate()`` loops on).  An idle
-        call is cheap and finalizes a pending drain report."""
-        sched = self.scheduler
-        if not sched.has_work():
-            self._sync_lifecycle_stats()
-            self._maybe_finalize_drain()
-            self._stalled = 0
-            return False
-        now = self._clock()
-        # deadline expiry at the ADMISSION boundary: a blown
-        # request must not take (or keep) pool pages
-        expired = bool(sched.expire(now))
-        if not self._draining and self._drain_requested():
-            self._draining = True
-            self._drain_started = now
-            # report what the DRAIN cut, not lifetime counters —
-            # pre-drain overload sheds are not the drain's doing
-            self._drain_shed0 = sched.num_shed
-            self._drain_expired0 = sched.num_expired
-            logger.warning(
-                "drain requested: admission closed; shedding %d "
-                "waiting request(s), %d running get %.1fs to finish",
-                len(sched.waiting), len(sched.running),
-                self.drain_timeout,
-            )
-        shed_now = 0
-        if self._draining:
-            # admission is closed: what waits now can never run
-            for seq in list(sched.waiting):
-                sched.finish(seq, "shed")
-                shed_now += 1
-            if (now - self._drain_started) > self.drain_timeout:
-                for seq in list(sched.running):
-                    sched.finish(seq, "shed")
-                    shed_now += 1
+        call is cheap, records no span and finalizes a pending drain
+        report."""
+        if not self.scheduler.has_work():
+            return self._settle_idle()
+        with _span(SPAN_STEP):
+            return self._step_with_work()
+
+    def _settle_idle(self):
         self._sync_lifecycle_stats()
-        if not sched.has_work():
-            self._maybe_finalize_drain()
-            self._stalled = 0
-            return False
-        failed_fast = 0
+        self._maybe_finalize_drain()
+        self._stalled = 0
+        return False
+
+    def _step_with_work(self):
+        sched = self.scheduler
+        failed_fast = shed_now = 0
         admitted, did_dispatch = [], False
         try:
-            # capacity fail-fast BEFORE admission: a head request
-            # that can never fit would otherwise stall the queue
-            while (sched.waiting
-                   and self.pool.pages_for(
-                       len(sched.waiting[0].prefix()))
-                   > self.pool.num_usable_pages):
-                self._fail_capacity(sched.waiting[0])
-                failed_fast += 1
-            if not self._draining:
-                # admit() hands back fresh AND resumed sequences —
-                # their ragged prefill starts past any shared-prefix
-                # pages the pool matched (a resumed one re-creates
-                # exactly the KV its eviction dropped)
-                admitted = sched.admit(
-                    bucket=lambda n: min(n, self.prefill_chunk))
-            if not self._draining:
-                sched.chaos_preempt()
-            if sched.running:
-                todo = sched.prepare_decode()
-                if todo:
-                    try:
-                        self._step_rows(todo)
-                    except StepCompileError:
-                        raise  # the program is broken, not a request
-                    except Exception as exc:  # host fault isolation
-                        self._host_fault(todo, "ragged-step", exc)
-                    did_dispatch = True
+            with _span(SPAN_SCHEDULE):
+                now = self._clock()
+                # deadline expiry at the ADMISSION boundary: a blown
+                # request must not take (or keep) pool pages
+                expired = bool(sched.expire(now))
+                if not self._draining and self._drain_requested():
+                    self._draining = True
+                    self._drain_started = now
+                    # report what the DRAIN cut, not lifetime counters —
+                    # pre-drain overload sheds are not the drain's doing
+                    self._drain_shed0 = sched.num_shed
+                    self._drain_expired0 = sched.num_expired
+                    logger.warning(
+                        "drain requested: admission closed; shedding %d "
+                        "waiting request(s), %d running get %.1fs to "
+                        "finish", len(sched.waiting), len(sched.running),
+                        self.drain_timeout,
+                    )
+                if self._draining:
+                    # admission is closed: what waits now can never run
+                    for seq in list(sched.waiting):
+                        sched.finish(seq, "shed")
+                        shed_now += 1
+                    if (now - self._drain_started) > self.drain_timeout:
+                        for seq in list(sched.running):
+                            sched.finish(seq, "shed")
+                            shed_now += 1
+                self._sync_lifecycle_stats()
+                if not sched.has_work():
+                    return self._settle_idle()
+                # capacity fail-fast BEFORE admission: a head request
+                # that can never fit would otherwise stall the queue
+                while (sched.waiting
+                       and self.pool.pages_for(
+                           len(sched.waiting[0].prefix()))
+                       > self.pool.num_usable_pages):
+                    self._fail_capacity(sched.waiting[0])
+                    failed_fast += 1
+                if not self._draining:
+                    # admit() hands back fresh AND resumed sequences —
+                    # their ragged prefill starts past any shared-prefix
+                    # pages the pool matched (a resumed one re-creates
+                    # exactly the KV its eviction dropped)
+                    with _span(SPAN_ADMIT):
+                        admitted = sched.admit(
+                            bucket=lambda n: min(n, self.prefill_chunk))
+                    for seq in admitted:
+                        if seq.admitted_at is None:  # a resumed one keeps it
+                            seq.admitted_at = now
+                    sched.chaos_preempt()
+                todo = sched.prepare_decode() if sched.running else []
+            if todo:
+                try:
+                    self._step_rows(todo)
+                except StepCompileError:
+                    raise  # the program is broken, not a request
+                except Exception as exc:  # host fault isolation
+                    self._host_fault(todo, "ragged-step", exc)
+                did_dispatch = True
             # deadline expiry at the DECODE boundary: pages free
             # the moment the deadline blows, not a decode tail later
             expired = bool(sched.expire(self._clock())) or expired
@@ -960,10 +1012,6 @@ class ServeEngine:
                 raise  # pages missing with nothing running: a bug
             sched.preempt(sched._pick_victim())
             self.stats["pool_exhausted_recoveries"] += 1
-            metrics.log_scalar(
-                "serve/pool_exhausted_recoveries",
-                self.stats["pool_exhausted_recoveries"],
-            )
             self._stalled = 0  # freed pages guarantee the retry runs
             return True
         self.stats["peak_pool_occupancy"] = max(
@@ -971,9 +1019,6 @@ class ServeEngine:
         )
         self.stats["peak_waiting"] = max(
             self.stats["peak_waiting"], len(sched.waiting)
-        )
-        metrics.log_scalar(
-            "serve/pool_occupancy", self.pool.occupancy()
         )
         # an iteration may legitimately emit nothing when its only
         # event was an eviction (chaos, or an exhaustion cascade
@@ -990,10 +1035,7 @@ class ServeEngine:
                 "inevitable)"
             )
         if not sched.has_work():
-            self._sync_lifecycle_stats()
-            self._maybe_finalize_drain()
-            self._stalled = 0
-            return False
+            return self._settle_idle()
         return True
 
     def _maybe_finalize_drain(self):
@@ -1021,7 +1063,6 @@ class ServeEngine:
             "pool_idle": self.pool.is_idle(),
         }
         self._draining = False
-        metrics.log_scalar("serve/drain_ms", drain_ms)
         logger.warning("drain complete: %s", self.drain_report)
 
     # -- fleet-facing surface ------------------------------------------
@@ -1212,7 +1253,6 @@ class ServeEngine:
         self._owns_params = True
         self.weight_swaps += 1
         stall = self._clock() - t0
-        metrics.log_scalar("serve/weight_swap_stall_ms", stall * 1e3)
         logger.info(
             "weight swap #%d installed (%d leaves, %.2f ms host stall)",
             self.weight_swaps, len(old_leaves), stall * 1e3,

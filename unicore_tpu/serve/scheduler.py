@@ -94,6 +94,10 @@ class Sequence:
         self.generated: List[int] = []
         self.evictions = 0
         self.enqueued_at = None  # host clocks are the engine's job
+        # first admission to `running`, stamped by the engine like
+        # enqueued_at (a resumed sequence keeps it): queue wait =
+        # admitted_at - enqueued_at, the rest of ttft is prefill
+        self.admitted_at = None
         self.first_token_at = None
         self.finish_reason = None
         # tokens whose KV is already written to pool pages (set to the
