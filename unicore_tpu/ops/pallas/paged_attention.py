@@ -179,8 +179,33 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
         ).astype(o_ref.dtype)
 
 
+# One trace and one lowering per signature, shared by every layer of a
+# step program.  ``pl.pallas_call`` traces the kernel's Python body
+# (~600 equations) and lowers it to a kernel module EVERY time it is
+# called, and a 24-layer decoder calls it 24 times per compiled width
+# with identical shapes and parameters: on the chip's host that was
+# ~45 s a width, two thirds of a serve process's set-up.  Under this
+# inner ``jit`` the first layer traces and the others hit jit's trace
+# cache; the enclosing step lowers ONE private function holding one
+# ``tpu_custom_call`` and a call to it per layer, which XLA inlines
+# before it schedules anything, so the compiled step holds the same
+# kernels in the same order.  The key is jit's own: operand shapes and
+# dtypes and the static keywords, so another geometry and the tuner's
+# other ``pages_per_block`` get entries of their own (a static argument
+# must hash: ``scale`` arrives as a Python float).  ``interpret`` is
+# read by the caller and is part of the key, not read in here once per
+# cached trace: it is constant in a process (``backend._on_tpu()``),
+# but ``tests/test_chip_compile.py`` steers it in process and must not
+# be served a trace taken under the other setting.  Nested under a
+# step's trace the inner jit keeps no executable, so
+# ``_call._cache_size()`` counts eager calls only.
+@functools.partial(
+    jax.jit,
+    static_argnames=("page_size", "pages_per_block", "scale", "heads",
+                     "head_dim", "interpret"),
+)
 def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
-          page_size, pages_per_block, scale, heads, head_dim):
+          page_size, pages_per_block, scale, heads, head_dim, interpret):
     bsz, t, hd = q3.shape
     qo_spec = pl.BlockSpec((1, t, hd), lambda b, pt, ln: (b, 0, 0))
     # [B, T, 1]: the block's last two dims are the array's own, which
@@ -209,11 +234,11 @@ def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
     return pl.pallas_call(
         functools.partial(
             _kernel, page_size=page_size, pages_per_block=pages_per_block,
-            scale=float(scale), heads=heads, head_dim=head_dim,
+            scale=scale, heads=heads, head_dim=head_dim,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, t, hd), q3.dtype),
-        interpret=pallas_interpret(),
+        interpret=interpret,
         name="ragged_paged_attention",
         compiler_params=pltpu.CompilerParams(
             # the scratch/DMA pattern serializes programs on-core anyway
@@ -243,8 +268,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, positions,
         k_pages.reshape(num_pages, page_size, heads * d),
         v_pages.reshape(num_pages, page_size, heads * d),
         page_table, lengths, positions,
-        page_size=page_size, pages_per_block=pages_per_block, scale=scale,
-        heads=heads, head_dim=d,
+        page_size=page_size, pages_per_block=pages_per_block,
+        scale=float(scale), heads=heads, head_dim=d,
+        interpret=pallas_interpret(),
     )
     return out.reshape(bsz, t, heads, d)
 
