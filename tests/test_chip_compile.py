@@ -155,3 +155,21 @@ def test_ragged_paged_attention_compiles(width, page_size, heads, dtype,
     _compile(fn, one_chip, ((bsz, width, heads, d), dtype), pool, pool,
              ((bsz, context // page_size), I32), ((bsz, width), I32),
              ((bsz,), I32))
+
+
+# a serve cell with 30 heads of 128 (a one-head slab: 128 lanes hold one
+# head), 12 rows, an 8,192-token table and a float32 pool of 800 pages:
+# the shapes the full-attention layers of a hybrid decoder hand the kernel
+@pytest.mark.parametrize("width", [1, 64])
+def test_ragged_paged_attention_compiles_at_30_heads_of_128(
+        width, one_chip, compiled_kernels):
+    from unicore_tpu.ops.pallas import paged_attention as pa
+
+    bsz, heads, d, page_size, context, num_pages = 12, 30, 128, 64, 8192, 800
+    assert pa.supported(heads, d, page_size, 4)
+    fn = functools.partial(
+        pa.ragged_paged_attention, page_size=page_size, scale=d ** -0.5)
+    pool = ((num_pages * page_size, heads * d), F32)
+    _compile(fn, one_chip, ((bsz, width, heads, d), F32), pool, pool,
+             ((bsz, context // page_size), I32), ((bsz, width), I32),
+             ((bsz,), I32))

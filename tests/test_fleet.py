@@ -191,6 +191,9 @@ def test_load_snapshot_is_stable_typed_dict(lm):
         "draining": bool, "step_ms": float,
         "prefix_hits": int, "prefix_tokens_saved": int,
         "prefix_hit_rate": float,
+        # ISSUE 27: the cache was asked for and refused (a model with
+        # recurrent layers), so the hit surface above stays at zero
+        "prefix_cache_refused": bool,
         # ISSUE 14 health surface: the retired-token watermark the
         # router's wedge detector differences, and the host-fault
         # counter its fault-rate threshold windows

@@ -23,7 +23,14 @@ from .transformer_encoder import (  # noqa: F401
 from .transformer_decoder import (  # noqa: F401
     TransformerDecoder,
     TransformerDecoderLayer,
-    future_mask,
+)
+from .pattern_decoder import (  # noqa: F401
+    FullAttentionMixer,
+    GatedFFN,
+    LinearAttentionMixer,
+    PatternDecoder,
+    PatternDecoderLayer,
+    RMSNorm,
 )
 from .triangle_attention import (  # noqa: F401
     EvoformerPairBlock,

@@ -574,18 +574,10 @@ class SelfMultiheadAttention(nn.Module):
             s = s + causal_iota_mask(q.shape[1], k.shape[1])[None, None]
             p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
             return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        from unicore_tpu.serve.attention import paged_attention
+        from unicore_tpu.serve.attention import write_and_attend
 
-        flat_k = k.astype(k_pages.value.dtype).reshape(-1, self.embed_dim)
-        flat_v = v.astype(v_pages.value.dtype).reshape(-1, self.embed_dim)
-        k_pages.value = k_pages.value.at[paged.slot_mapping].set(flat_k)
-        v_pages.value = v_pages.value.at[paged.slot_mapping].set(flat_v)
-        return paged_attention(
-            q, k_pages.value, v_pages.value,
-            page_table=paged.page_table, positions=positions,
-            lengths=paged.lengths, page_size=paged.page_size,
-            scale=scaling,
-        )
+        return write_and_attend(q, k, v, k_pages, v_pages, paged, positions,
+                                scaling)
 
 
 def _decode_mask(idx, tgt_len, cache_len):

@@ -7,9 +7,13 @@ materialized future mask into the additive attention mask
 (``transformer_decoder.py:19-22,106-121``); here ``auto_regressive``
 flows to the attention core as a flag so the flash kernel masks
 in-block and the materialized path builds the mask from fused iota
-compares — no [T, T] tensor in HBM (``future_mask`` below is kept for
-API parity only — nothing in the stack materializes it anymore; the
-sequence-parallel path takes ``causal=`` natively too).
+compares — no [T, T] tensor in HBM (the sequence-parallel path takes
+``causal=`` natively too).
+
+This is the LayerNorm / one-activation / multi-head block with a K/V
+page per token in every layer; a decoder whose layers differ in kind
+(linear-attention layers beside full attention, RMSNorm, a gated FFN)
+is ``pattern_decoder.py``.
 """
 
 from typing import Optional
@@ -24,14 +28,6 @@ from .multihead_attention import _BATCH_AXES, CrossMultiheadAttention, SelfMulti
 from .transformer_encoder import RelativePositionBias
 from unicore_tpu.parallel import tp_constraint
 from unicore_tpu.utils import get_activation_fn
-
-
-def future_mask(seq_len, dtype=jnp.float32):
-    """[T, T] additive causal mask: 0 on/below diagonal, -inf above
-    (reference: transformer_decoder.py:19-22)."""
-    return jnp.triu(
-        jnp.full((seq_len, seq_len), float("-inf"), dtype=dtype), k=1
-    )
 
 
 class TransformerDecoderLayer(nn.Module):

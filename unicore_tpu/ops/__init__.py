@@ -20,5 +20,6 @@ from .dropout import dropout  # noqa: F401
 from .fused_cross_entropy import (  # noqa: F401
     fused_linear_cross_entropy, linear_nll_reference,
 )
+from .gated_delta_rule import gated_delta_rule, short_conv  # noqa: F401
 from .rounding import fp32_to_bf16_sr, fp32_to_bf16_sr_reference  # noqa: F401
 from .multi_tensor import l2_norm  # noqa: F401

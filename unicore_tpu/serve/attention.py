@@ -47,13 +47,20 @@ class PagedMeta:
     (trash slots for inactive rows); ``lengths`` [B] int32 valid token
     counts INCLUDING the current tokens; ``page_size``/``num_slots`` are
     static Python ints (``num_slots`` sizes the pool variables at flax
-    init and is ignored afterwards)."""
+    init and is ignored afterwards).
+
+    A model with recurrent layers also gets ``state_slots`` [B] int32:
+    the state-store slot of each row's SEQUENCE (a row is assigned anew
+    every step, the state is not), out of range for an empty row so that
+    its write is dropped; ``num_state_slots`` sizes the store at init."""
 
     page_table: Any
     slot_mapping: Any
     lengths: Any
     page_size: int
     num_slots: int = 0
+    state_slots: Any = None
+    num_state_slots: int = 0
 
 
 def gather_slots(pages, page_table, page_size):
@@ -141,4 +148,23 @@ def paged_attention(q, k_pages, v_pages, page_table, positions, lengths,
     return paged_attention_reference(
         q, k_pages, v_pages, page_table, positions, lengths, page_size,
         scale,
+    )
+
+
+def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
+    """One layer's paged step: this step's ``k``/``v`` [B, T, H, D]
+    scatter into the pool variables ``k_pages``/``v_pages`` (flax
+    variables of collection ``"pagedkv"``, ``[num_slots, H*D]``) at
+    ``paged.slot_mapping``, then every row attends the pages its table
+    names.  The scatter lands before the gather, so a row sees the keys
+    the same program wrote."""
+    width = k_pages.value.shape[-1]
+    k_pages.value = k_pages.value.at[paged.slot_mapping].set(
+        k.astype(k_pages.value.dtype).reshape(-1, width))
+    v_pages.value = v_pages.value.at[paged.slot_mapping].set(
+        v.astype(v_pages.value.dtype).reshape(-1, width))
+    return paged_attention(
+        q, k_pages.value, v_pages.value,
+        page_table=paged.page_table, positions=positions,
+        lengths=paged.lengths, page_size=paged.page_size, scale=scale,
     )

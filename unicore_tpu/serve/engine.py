@@ -22,6 +22,19 @@ lookup instead of a prefill (``prefix_cache=True``), with the partial
 tail page always privately owned (copy-on-write by recompute), so one
 session's decode never mutates another's shared page.
 
+A model with RECURRENT layers (``model.has_recurrent_state``: linear
+attention beside full attention) holds a second kind of cache: one
+fixed-size state per sequence, in the same donated ``pagedkv`` tree as
+the pages.  The pool hands each resident sequence a state slot with its
+pages and takes it back with them; a step gathers the slots of its rows,
+runs, and scatters them back inside the one jitted program, zeroing a
+state whose row starts at position 0.  Two rules follow from the state
+being a chain: such a model gets ONE row per sequence per dispatch
+(chunk k needs the state chunk k-1 leaves, so consecutive chunks of one
+prompt cannot share a program the way K/V scatters do), and prefix hits
+are refused (a hit starts a prompt past its shared pages, where no state
+exists).
+
 Metrics: per-request queue wait and TTFT, and the counters of
 :attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
 occupancy, prefix-cache hits), which the JSON report and the fleet
@@ -40,6 +53,8 @@ annotation costs well under a microsecond::
         serve/admit           Scheduler.admit: prefix match, can_alloc, alloc
       serve/plan              _plan_rows
       serve/assemble          the numpy rows of one dispatch
+        serve/state           a recurrent model's rows: each row's state slot
+                              looked up, rows starting from zero counted
       serve/transfer          every per-step argument onto the device
       serve/dispatch-w<n>     the compiled step at width n, until the
                               sampled tokens are on the host
@@ -111,6 +126,7 @@ _span = jax.profiler.TraceAnnotation
 SPAN_STEP = "serve/step"
 SPAN_SCHEDULE = "serve/schedule"
 SPAN_ADMIT = "serve/admit"
+SPAN_STATE = "serve/state"
 SPAN_PLAN = "serve/plan"
 SPAN_ASSEMBLE = "serve/assemble"
 SPAN_TRANSFER = "serve/transfer"
@@ -189,8 +205,21 @@ class ServeEngine:
             int(max_context or model.max_seq_len), model.max_seq_len, cap
         )
         self.num_slots = self.num_pages * self.page_size
-        self.pool = PagedKVPool(self.num_pages, self.page_size,
-                                prefix_cache=prefix_cache)
+        # a model with recurrent layers keeps one state per sequence
+        # beside its pages (module docstring): a slot per batch row, and
+        # no prefix hits, which would start a prompt where no state is
+        self.recurrent = bool(getattr(model, "has_recurrent_state", False))
+        self.prefix_cache_refused = bool(self.recurrent and prefix_cache)
+        if self.prefix_cache_refused:
+            logger.warning(
+                "prefix cache REFUSED: the model has recurrent layers, and "
+                "a prefix hit would start a prompt past its shared pages "
+                "with no recurrent state for that boundary; every prompt "
+                "prefills from position 0")
+        self.pool = PagedKVPool(
+            self.num_pages, self.page_size,
+            prefix_cache=prefix_cache and not self.recurrent,
+            state_slots=self.max_batch if self.recurrent else 0)
         self.table_width = self.pool.pages_for(self.max_context)
         # unified=False is the bench A/B baseline: prefill rows and
         # decode rows dispatch as two separate programs per step (the
@@ -270,6 +299,7 @@ class ServeEngine:
             "shed": 0, "expired": 0, "quarantined": 0, "host_faults": 0,
             "capacity_failfast": 0, "peak_waiting": 0,
             "prefix_hits": 0, "prefix_tokens_saved": 0,
+            "state_resets": 0, "state_slots_peak": 0,
         }
         # live weight swaps installed via swap_weights (ISSUE 18);
         # _owns_params flips on the first swap — boot params may be
@@ -290,6 +320,7 @@ class ServeEngine:
             lengths=jnp.ones((1,), jnp.int32),
             page_size=self.page_size,
             num_slots=self.num_slots,
+            num_state_slots=self.pool.num_state_slots,
         )
         shapes = jax.eval_shape(
             lambda key, p: self.model.init(
@@ -381,10 +412,11 @@ class ServeEngine:
 
             def step(params, pages, tokens, positions, page_table,
                      slot_mapping, lengths, last_col, seeds, steps,
-                     temperature, top_k, poison=None):
+                     temperature, top_k, poison=None, state_slots=None):
                 meta = PagedMeta(
                     page_table=page_table, slot_mapping=slot_mapping,
                     lengths=lengths, page_size=page_size,
+                    state_slots=state_slots,
                 )
                 logits, mutated = model.apply(
                     {"params": params, "pagedkv": pages}, tokens,
@@ -441,17 +473,24 @@ class ServeEngine:
         arts = {}
         widths = self.serve_step_widths() if widths is None else widths
         for w in widths:
-            extra = ((s(B, dtype=jnp.bool_),) if self._chaos_poison
-                     else ())
             traced = self._ragged_step_fn(w, sampling).trace(
                 params, pages, s(B, w), s(B, w), s(B, W), s(B * w),
                 s(B), s(B), s(B), s(B), s(B, dtype=jnp.float32), s(B),
-                *extra,
+                *self._extra_step_args(s(B, dtype=jnp.bool_), s(B)),
             )
             arts[f"ragged-w{w}"] = {
                 "jaxpr": traced.jaxpr, "lowered": traced.lower(),
             }
         return arts
+
+    def _extra_step_args(self, poison, state_slots):
+        """The step's trailing positional arguments ``(poison,
+        state_slots)``, as far as this engine passes them: none for a
+        model without recurrent layers and no chaos injection (the
+        program such an engine compiles is the one it always did)."""
+        if self.recurrent:
+            return (poison if self._chaos_poison else None, state_slots)
+        return (poison,) if self._chaos_poison else ()
 
     # -- host-side step assembly ---------------------------------------
 
@@ -509,7 +548,13 @@ class ServeEngine:
         see chunk j<k's keys written in the same program, exactly as a
         single full-length prefill would — so a cold solo prompt fills
         the whole ``max_batch x prefill_chunk`` token budget instead
-        of paying for one ragged row and B-1 padded ones."""
+        of paying for one ragged row and B-1 padded ones.
+
+        NOT for a model with recurrent layers: chunk k starts from the
+        state chunk k-1 leaves, and the rows of one dispatch all start
+        from the state the store held BEFORE it.  Such a model gets one
+        row per sequence per dispatch; its prompt advances one chunk a
+        step, as every other prefilling sequence's does beside it."""
         rows = []
         prefilling = []
         for seq in seqs:
@@ -528,6 +573,8 @@ class ServeEngine:
                 entry[1] = start + m
                 if entry[1] >= total:
                     prefilling.remove(entry)
+            if self.recurrent:
+                break  # one row per sequence: the state is a chain
         return rows
 
     def _dispatch(self, rows):
@@ -557,6 +604,9 @@ class ServeEngine:
             top_k = np.zeros((B,), np.int32)
             seeds = np.zeros((B,), np.int32)
             steps = np.zeros((B,), np.int32)
+            # an empty row's state slot is out of range (and unlike any
+            # other row's): its gather clips, its write is dropped
+            state_slots = self.max_batch + np.arange(B, dtype=np.int32)
             packed = []
             for seq, start, m, emit, dec in rows:
                 if seq.done:
@@ -600,6 +650,12 @@ class ServeEngine:
                     continue
                 packed.append((seq, start, m, emit, dec))
             rows = packed
+            if self.recurrent:
+                with _span(SPAN_STATE):
+                    for b, (seq, start, *_rest) in enumerate(rows):
+                        state_slots[b] = self.pool.state_slot(seq.sid)
+                    self.stats["state_resets"] += sum(
+                        1 for r in rows if r[1] == 0)
         if not rows:
             return
         sampling = self._sampling_mode([r[0] for r in rows])
@@ -612,11 +668,14 @@ class ServeEngine:
                 jnp.asarray(seeds), jnp.asarray(steps),
                 jnp.asarray(temperature), jnp.asarray(top_k),
             ]
+            poison = None
             if self._chaos_poison:
                 poison = np.zeros((B,), bool)
                 for b, (seq, *_rest) in enumerate(rows):
                     poison[b] = self._poison_row(seq)
-                args.append(jnp.asarray(poison))
+                poison = jnp.asarray(poison)
+            args.extend(self._extra_step_args(
+                poison, jnp.asarray(state_slots) if self.recurrent else None))
         any_decode = any(r[4] for r in rows)
         if self._input_capture is not None:
             # determinism-harness capture: before the call — the jit
@@ -839,6 +898,7 @@ class ServeEngine:
         self.stats["prefix_hits"] = self.pool.prefix_stats["hits"]
         self.stats["prefix_tokens_saved"] = (
             self.pool.prefix_stats["tokens_saved"])
+        self.stats["state_slots_peak"] = self.pool.state_stats["peak"]
 
     def _fail_capacity(self, seq):
         """Satellite fix: a request whose prefix can never fit even an
@@ -1084,7 +1144,10 @@ class ServeEngine:
         ``prefix_hits`` (int), ``prefix_tokens_saved`` (int),
         ``prefix_hit_rate`` (float, hits/lookups, 0.0 before the first
         lookup) — how much the router's session affinity is paying
-        off on this replica.
+        off on this replica; ``prefix_cache_refused`` (bool) says the
+        hit surface will stay at zero because the model has recurrent
+        layers and the engine refused the cache it was asked for, so a
+        router should not spend affinity on this replica's behalf.
 
         Health surface (ISSUE 14): ``last_progress`` (int) is the
         retired-token watermark — the monotonic count of tokens this
@@ -1112,6 +1175,7 @@ class ServeEngine:
             "prefix_hits": int(ps["hits"]),
             "prefix_tokens_saved": int(ps["tokens_saved"]),
             "prefix_hit_rate": round(float(hit_rate), 4),
+            "prefix_cache_refused": bool(self.prefix_cache_refused),
             "last_progress": int(self.stats["generated_tokens"]),
             "host_faults": int(self.stats["host_faults"]),
         }

@@ -50,6 +50,21 @@ Shared-prefix dedup (multi-tenant pool):
   capacity).  Allocation takes the free list first, then evicts cached
   pages oldest-first — deterministic, so a replayed trace makes the
   same eviction (and therefore the same hit/miss) decisions every run.
+
+Recurrent state slots (a model with linear-attention layers):
+
+- Such a model keeps, beside its pages, ONE fixed-size state per
+  sequence (``state_slots > 0``: as many slots as the engine has batch
+  rows).  A slot is taken in :meth:`alloc` and released in :meth:`free`,
+  so it lives and dies with the sequence's pages through every path
+  that ends a sequence's residency (finish, preemption, expiry, drain,
+  failover salvage): nothing else hands slots out.  The device side
+  zeroes a state when a row starts at position 0, so a released slot
+  needs no clearing and a re-admitted sequence prefills from zero.
+- A prefix hit would start a prompt past its shared pages, where a
+  recurrent layer has no state: the engine builds this pool with
+  ``prefix_cache=False`` for such a model (state snapshots at page
+  boundaries are what a hit would need).
 """
 
 import hashlib
@@ -74,7 +89,8 @@ class PagedKVPool:
     """Fixed-capacity refcounted page allocator with per-sequence page
     tables and an optional shared-prefix page index."""
 
-    def __init__(self, num_pages, page_size, prefix_cache=True):
+    def __init__(self, num_pages, page_size, prefix_cache=True,
+                 state_slots=0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "reserved trash page)")
@@ -95,6 +111,11 @@ class PagedKVPool:
         self._page_digests = {}  # page -> digest (registered pages)
         self._cached = OrderedDict()  # page -> digest, LRU order
         self._shared_tokens = {}  # seq_id -> tokens satisfied by dedup
+        # recurrent state slots: one per resident sequence (docstring)
+        self.num_state_slots = int(state_slots)
+        self._state_free = list(range(self.num_state_slots - 1, -1, -1))
+        self._state_of = {}  # seq_id -> slot
+        self.state_stats = {"taken": 0, "peak": 0}
         # last _match_chain result, keyed by (tokens, cap) + an index
         # generation counter: admission calls can_alloc then alloc with
         # the same prompt back to back, and the blake2b chain walk is
@@ -169,6 +190,8 @@ class PagedKVPool:
         """Whether a new sequence of ``num_tokens`` tokens fits —
         with ``tokens`` the check credits shared-prefix pages the
         allocation would not actually consume."""
+        if self.num_state_slots and not self._state_free:
+            return False
         need = self.pages_for(num_tokens)
         shared, shared_pages = self._match_chain(tokens, num_tokens)
         return need - shared <= self._new_page_budget(shared_pages)
@@ -237,6 +260,7 @@ class PagedKVPool:
                 f"({shared} shared), "
                 f"{self._new_page_budget(shared_pages)} free"
             )
+        self._take_state_slot(seq_id)
         self._acquire_shared(shared_pages)
         table = list(shared_pages)
         for _ in range(need):
@@ -289,9 +313,29 @@ class PagedKVPool:
         pages = self._tables.pop(seq_id)
         del self._lens[seq_id]
         self._shared_tokens.pop(seq_id, None)
+        if self.num_state_slots:
+            self._state_free.append(self._state_of.pop(seq_id))
         for p in reversed(pages):
             self._release(p)
         return pages
+
+    # -- recurrent state slots -----------------------------------------
+
+    def _take_state_slot(self, seq_id):
+        if not self.num_state_slots:
+            return
+        if not self._state_free:
+            raise PoolExhausted(
+                f"no free state slot for sequence {seq_id!r} "
+                f"({self.num_state_slots} held)")
+        self._state_of[seq_id] = self._state_free.pop()
+        self.state_stats["taken"] += 1
+        self.state_stats["peak"] = max(self.state_stats["peak"],
+                                       len(self._state_of))
+
+    def state_slot(self, seq_id):
+        """The state-store slot of a resident sequence."""
+        return self._state_of[seq_id]
 
     # -- prefix registration -------------------------------------------
 
@@ -393,3 +437,13 @@ class PagedKVPool:
         seen = free | cached | set(counted)
         assert 0 not in seen, "trash page 0 was handed out"
         assert seen == set(range(1, self.num_pages)), "pages leaked"
+        if self.num_state_slots:
+            held = list(self._state_of.values())
+            assert len(set(held)) == len(held), "state slot held twice"
+            assert not set(held) & set(self._state_free), (
+                "state slot both held and free")
+            assert (set(held) | set(self._state_free)
+                    == set(range(self.num_state_slots))), "state slot leaked"
+            assert len(self._state_free) + len(held) == self.num_state_slots
+            assert set(self._state_of) == set(self._tables), (
+                "a state slot must live and die with its sequence's pages")
