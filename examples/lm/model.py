@@ -156,6 +156,9 @@ class TransformerLMModel(BaseUnicoreModel):
         # enforces; a 2-D positions array carries the per-sequence
         # offsets); the decoder drops the key-padding mask on the decode
         # path itself.
+        # ``paged`` (serve/attention.py PagedMeta): the serve engine's
+        # step.  Its tokens are a flat list, [1, N] with their positions;
+        # only attention sorts them into batch rows.
         # ``segment_ids`` [B, T] routes packed rows (data/packing.py)
         # through segment-causal attention; ``positions`` then carries
         # the per-segment reset offsets (-1 at pad slots)
@@ -202,6 +205,11 @@ class TransformerLMModel(BaseUnicoreModel):
           decode=decode, positions=positions, paged=paged,
           segment_ids=segment_ids)
 
+        if paged is not None and paged.last_token is not None:
+            # a serve step reads one token's logits per batch row: the
+            # head runs on those [1, max_batch] tokens, not on every
+            # token the step carries
+            x = jnp.take(x, paged.last_token, axis=1)
         # tied projection + final LN'd features -> logits
         x = LayerNorm(self.decoder_embed_dim, name="out_layer_norm")(x)
         x = get_activation_fn(self.activation_fn)(x)

@@ -198,6 +198,10 @@ def test_load_snapshot_is_stable_typed_dict(lm):
         # router's wedge detector differences, and the host-fault
         # counter its fault-rate threshold windows
         "last_progress": int, "host_faults": int,
+        # ISSUE 28: the fill of the mixed step program (dispatches at
+        # the prefill width, tokens carried, tokens compiled for)
+        "mixed_steps": int, "mixed_tokens_carried": int,
+        "mixed_tokens_capacity": int,
     }
     assert set(snap) == set(want_types), snap
     for k, t in want_types.items():

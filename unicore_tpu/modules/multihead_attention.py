@@ -379,7 +379,10 @@ class SelfMultiheadAttention(nn.Module):
         ``decode=True``) switches from the per-call dense cache to the
         serve tier's shared paged KV pool: k/v write into pool pages at
         ``paged.slot_mapping`` and attention gathers each sequence's
-        pages through its page table (collection ``"pagedkv"``)."""
+        pages through its page table (collection ``"pagedkv"``).  The
+        tokens of such a call are the serve step's flat list, ``[1, N]``
+        with ``positions`` [1, N], or its ``[B, T]`` rectangle: only
+        the attention core sorts them into batch rows."""
         bsz, tgt_len, embed_dim = query.shape
         assert embed_dim == self.embed_dim
         head_dim = self.embed_dim // self.num_heads
@@ -435,7 +438,7 @@ class SelfMultiheadAttention(nn.Module):
             if paged is not None:
                 if positions is None and not self.is_initializing():
                     raise ValueError(
-                        "paged decode requires positions= ([B, T] global "
+                        "paged decode requires positions= (the global "
                         "positions of the current tokens; they drive both "
                         "the causal mask and the page-slot bookkeeping)"
                     )
@@ -555,8 +558,9 @@ class SelfMultiheadAttention(nn.Module):
         """Serve-tier attention over the shared paged KV pool: this
         step's k/v scatter into pool pages at ``paged.slot_mapping`` and
         each sequence attends the pages its table names, masked to its
-        own positions (``unicore_tpu/serve/attention.py`` owns the math
-        and the eager/Pallas dispatch).  Pool buffers live in collection
+        own positions (``unicore_tpu/serve/attention.py`` owns the math,
+        the way from the step's token list to the kernel's batch rows and
+        back, and the eager/Pallas dispatch).  Pool buffers live in collection
         ``"pagedkv"`` — one [num_slots, H*Dh] pair per layer, allocated
         once at engine init and donated through every jitted step."""
         is_initialized = self.has_variable("pagedkv", "k_pages")

@@ -1,6 +1,8 @@
 """Paged attention over page tables: the serve tier's attention core.
 
-Layouts: queries keep the module convention ``[B, T, H, D]``; the pool
+Layouts: the attention core takes queries in the module convention
+``[B, T, H, D]`` (a serve step whose tokens are a flat list sorts them
+into that rectangle here, in ``write_and_attend``); the pool
 is FLAT — ``k_pages``/``v_pages`` are ``[num_slots, H*D]`` (heads folded
 into the minor dim, so a page is a tile-aligned slab the kernel can DMA
 and no HBM tile is half empty at ``D == 64``) where slot
@@ -43,11 +45,23 @@ class PagedMeta:
     is not a pytree; only its array fields are traced).
 
     ``page_table`` [B, P] int32 (rows padded with the trash page 0);
-    ``slot_mapping`` [B*T] int32 flat write slots for the current tokens
-    (trash slots for inactive rows); ``lengths`` [B] int32 valid token
-    counts INCLUDING the current tokens; ``page_size``/``num_slots`` are
-    static Python ints (``num_slots`` sizes the pool variables at flax
-    init and is ignored afterwards).
+    ``slot_mapping`` [N] int32 flat write slots for the step's tokens
+    (the trash slot for a token nobody carries); ``lengths`` [B] int32
+    valid token counts INCLUDING the current tokens;
+    ``page_size``/``num_slots`` are static Python ints (``num_slots``
+    sizes the pool variables at flax init and is ignored afterwards).
+
+    The step's tokens may come as the kernel's ``[B, width]`` rectangle
+    itself (N = B * width, row-major: the decode step, and every step of
+    a recurrent model) or as a FLAT list of N < B * width tokens, each
+    row's tokens consecutive.  The flat list brings its map both ways:
+    ``rect_token`` [B, width] int32, the flat token in each cell of the
+    rectangle (N for a cell no token fills), ``rect_positions``
+    [B, width] int32, that token's position (-1 for such a cell), and
+    ``token_cell`` [N] int32, the cell ``row * width + column`` of each
+    flat token.  ``last_token`` [B] int32, where given, names the flat
+    token each row samples from: the model then returns logits for
+    those B tokens only.
 
     A model with recurrent layers also gets ``state_slots`` [B] int32:
     the state-store slot of each row's SEQUENCE (a row is assigned anew
@@ -61,6 +75,10 @@ class PagedMeta:
     num_slots: int = 0
     state_slots: Any = None
     num_state_slots: int = 0
+    rect_token: Any = None
+    rect_positions: Any = None
+    token_cell: Any = None
+    last_token: Any = None
 
 
 def gather_slots(pages, page_table, page_size):
@@ -152,19 +170,38 @@ def paged_attention(q, k_pages, v_pages, page_table, positions, lengths,
 
 
 def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
-    """One layer's paged step: this step's ``k``/``v`` [B, T, H, D]
-    scatter into the pool variables ``k_pages``/``v_pages`` (flax
-    variables of collection ``"pagedkv"``, ``[num_slots, H*D]``) at
-    ``paged.slot_mapping``, then every row attends the pages its table
-    names.  The scatter lands before the gather, so a row sees the keys
-    the same program wrote."""
+    """One layer's paged step: this step's ``k``/``v`` (token-major,
+    ``[..., H, D]`` over N tokens) scatter into the pool variables
+    ``k_pages``/``v_pages`` (flax variables of collection ``"pagedkv"``,
+    ``[num_slots, H*D]``) at ``paged.slot_mapping``, then every row
+    attends the pages its table names.  The scatter lands before the
+    gather, so a row sees the keys the same program wrote.
+
+    Attention is the one place that needs rows: ``q`` goes into the
+    ``[B, width]`` rectangle the ragged kernel takes (a gather by
+    ``paged.rect_token`` for a flat list, a reshape when the tokens are
+    the rectangle already) and the output comes back in ``q``'s own
+    layout."""
     width = k_pages.value.shape[-1]
     k_pages.value = k_pages.value.at[paged.slot_mapping].set(
         k.astype(k_pages.value.dtype).reshape(-1, width))
     v_pages.value = v_pages.value.at[paged.slot_mapping].set(
         v.astype(v_pages.value.dtype).reshape(-1, width))
-    return paged_attention(
+    lead, rows = q.shape[:-2], paged.page_table.shape[0]
+    if paged.rect_token is None:
+        q = q.reshape((rows, -1) + q.shape[-2:])
+        positions = positions.reshape(rows, -1)
+    else:
+        # a cell no token fills reads some token's q: position -1 masks it
+        q = jnp.take(q.reshape((-1,) + q.shape[-2:]), paged.rect_token,
+                     axis=0, mode="clip")
+        positions = paged.rect_positions
+    o = paged_attention(
         q, k_pages.value, v_pages.value,
         page_table=paged.page_table, positions=positions,
         lengths=paged.lengths, page_size=paged.page_size, scale=scale,
     )
+    if paged.rect_token is not None:
+        o = jnp.take(o.reshape((-1,) + o.shape[-2:]), paged.token_cell,
+                     axis=0, mode="clip")
+    return o.reshape(lead + o.shape[-2:])

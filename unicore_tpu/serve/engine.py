@@ -11,10 +11,19 @@ the ``width=1`` pure-decode dispatch (steady-state traffic pays no
 chunk padding) and the ``width=prefill_chunk`` mixed dispatch, so a
 long prompt is admitted in bounded-TTFT slices WHILE the running batch
 keeps decoding in the same dispatch.  UL205 audits that the program
-count stays constant over every prompt length.  Pool buffers are
-DONATED through every step — after warmup nothing reallocates — and
-sampling (greedy/temperature/top-k, seeded per request) runs inside the
-step, so only the [B] sampled token ids cross the host boundary.
+count stays constant over every prompt length.
+
+The step's tokens are a FLAT list of one fixed size per program:
+``max_batch`` tokens at width 1 (the ``[max_batch, 1]`` rectangle is the
+list) and :attr:`ServeEngine.mixed_tokens` at the prefill width, 512
+tokens whatever ``max_batch`` is (a small engine: its rectangle).  Embedding, LayerNorms,
+projections and FFNs run on the tokens the step carries; only attention
+sorts them into the ``[max_batch, width]`` rectangle the ragged kernel
+takes, and the head runs on each row's last token, ``max_batch`` rows.
+Pool buffers are DONATED through every step — after warmup nothing
+reallocates — and sampling (greedy/temperature/top-k, seeded per
+request) runs inside the step, so only the [B] sampled token ids cross
+the host boundary.
 
 The pool itself is MULTI-TENANT: ``kv_pool.py`` dedups shared prefixes
 by chain-hash — a repeat of a warm system prompt becomes a page-table
@@ -28,12 +37,14 @@ fixed-size state per sequence, in the same donated ``pagedkv`` tree as
 the pages.  The pool hands each resident sequence a state slot with its
 pages and takes it back with them; a step gathers the slots of its rows,
 runs, and scatters them back inside the one jitted program, zeroing a
-state whose row starts at position 0.  Two rules follow from the state
+state whose row starts at position 0.  Three rules follow from the state
 being a chain: such a model gets ONE row per sequence per dispatch
 (chunk k needs the state chunk k-1 leaves, so consecutive chunks of one
-prompt cannot share a program the way K/V scatters do), and prefix hits
-are refused (a hit starts a prompt past its shared pages, where no state
-exists).
+prompt cannot share a program the way K/V scatters do), its recurrence
+walks the ``[max_batch, width]`` rectangle itself, so its step keeps the
+rectangle as the layout of its tokens and returns logits for every
+column, and prefix hits are refused (a hit starts a prompt past its
+shared pages, where no state exists).
 
 Metrics: per-request queue wait and TTFT, and the counters of
 :attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
@@ -55,11 +66,14 @@ annotation costs well under a microsecond::
       serve/assemble          the numpy rows of one dispatch
         serve/state           a recurrent model's rows: each row's state slot
                               looked up, rows starting from zero counted
-      serve/transfer          every per-step argument onto the device
+      serve/transfer          the step's operands onto the device: one
+                              packed vector (a recurrent model: one
+                              transfer an operand)
       serve/dispatch-w<n>     the compiled step at width n, until the
                               sampled tokens are on the host
         serve/launch          the compiled call returning
-        serve/fetch           the sampled tokens and row flags to the host
+        serve/fetch           the sampled tokens (and a recurrent model's
+                              row flags) to the host
       serve/emit              counters, quarantine, prefill watermark,
                               register_prefix, _emit
 
@@ -103,6 +117,7 @@ import contextlib
 import dataclasses
 import functools
 import logging
+import math
 import os
 import time
 from collections import deque
@@ -177,6 +192,13 @@ class StepCompileError(RuntimeError):
 
 
 DEFAULT_PREFILL_CHUNK = 32
+# the mixed step's token list, in TOKENS whatever the chunk: the best of
+# 256 / 512 / 1024 on the chip in both opt_1.3b cells (float32 weights,
+# chunk 128, one v5e: PERF.md, PR 28), near where such weights turn from
+# weight-bound to compute-bound (197e12 / 819e9 FLOP a byte at 2 FLOP a
+# weight a token and 4 bytes a weight: ~480).  Not read for another
+# dtype or chip
+MIXED_STEP_TOKENS = 512
 
 
 class ServeEngine:
@@ -244,6 +266,15 @@ class ServeEngine:
             if tuned:
                 chunk = tuned
         self.prefill_chunk = max(1, min(chunk, self.max_context))
+        # tokens the mixed step carries: derived, never configured.  A
+        # recurrent model's step is the rectangle; any other gets
+        # MIXED_STEP_TOKENS, at least a chunk beside a token for every
+        # row (so more tokens than rows: the step tells a model's
+        # all-token logits from its last-token ones by that), at most
+        # the rectangle
+        rect = self.max_batch * self.prefill_chunk
+        self.mixed_tokens = rect if self.recurrent else min(rect, max(
+            MIXED_STEP_TOKENS, self.max_batch + self.prefill_chunk))
         # the chunk-size -> compiled-width map, overridable so the
         # static audit (analysis/hlo_audit.py UL205) can check that it
         # never produces a lowering outside serve_step_widths()
@@ -300,6 +331,10 @@ class ServeEngine:
             "capacity_failfast": 0, "peak_waiting": 0,
             "prefix_hits": 0, "prefix_tokens_saved": 0,
             "state_resets": 0, "state_slots_peak": 0,
+            # the fill of the mixed program: dispatches at the prefill
+            # width, the tokens they carried, mixed_tokens x dispatches
+            "mixed_steps": 0, "mixed_tokens_carried": 0,
+            "mixed_tokens_capacity": 0,
         }
         # live weight swaps installed via swap_weights (ISSUE 18);
         # _owns_params flips on the first swap — boot params may be
@@ -398,47 +433,143 @@ class ServeEngine:
             return (1,)
         return (1, self.prefill_chunk)
 
+    def _step_tokens(self, width):
+        """Tokens in the list of the step program at ``width``."""
+        return self.max_batch if width == 1 else self.mixed_tokens
+
+    def _step_operands(self, width):
+        """``[(name, shape), ...]`` of the per-dispatch operands of the
+        step program at ``width``, in the order a recurrent model's step
+        takes them as arguments and every other step finds them in its
+        one packed vector.  All are 32 bits wide: ``temperature`` is
+        float32, ``poison`` a flag, the rest int32."""
+        B, n = self.max_batch, self._step_tokens(width)
+        lead = (B, width) if self.recurrent else (1, n)
+        ops = [("tokens", lead), ("positions", lead),
+               ("page_table", (B, self.table_width)),
+               ("slot_mapping", (n,)), ("lengths", (B,)), ("last", (B,)),
+               ("seeds", (B,)), ("steps", (B,)), ("temperature", (B,)),
+               ("top_k", (B,))]
+        if self._chaos_poison:
+            ops.append(("poison", (B,)))
+        if self.recurrent:
+            ops.append(("state_slots", (B,)))
+        elif width > 1:
+            ops += [("rect_token", (B, width)), ("token_cell", (n,))]
+        return ops
+
+    @staticmethod
+    def _packed_size(operands):
+        return sum(math.prod(shape) for _, shape in operands)
+
+    @staticmethod
+    def _cut(packed, operands):
+        """``{name: view}`` of the operands laid end to end in one
+        vector: numpy views on the host, slices of the traced argument
+        in the step."""
+        o, end = {}, 0
+        for name, shape in operands:
+            o[name] = packed[end:end + math.prod(shape)].reshape(shape)
+            end += math.prod(shape)
+        return o
+
+    def _last_token_rows(self, logits, last, width):
+        """``[max_batch, vocab]`` logits of the tokens ``last`` out of
+        what a model made of the flat list (traced).  A model that
+        honours ``PagedMeta.last_token`` ran its head on those tokens
+        only and returns ``[1, max_batch, vocab]``; one that ignores it
+        returns ``[1, N, vocab]`` and is picked from here, correct if
+        slower.  Shapes tell the two apart, since ``mixed_tokens >
+        max_batch``, and at width 1 they are the same thing: row b's
+        one token is token b.  Anything else fails at trace time."""
+        B, n = self.max_batch, self._step_tokens(width)
+        if logits.ndim != 3 or logits.shape[0] != 1 \
+                or logits.shape[1] not in (B, n):
+            raise ValueError(
+                f"the serve step at width {width} handed "
+                f"{type(self.model).__name__} tokens [1, {n}] and got "
+                f"logits {logits.shape}: expected [1, {B}, vocab] (each "
+                f"row's PagedMeta.last_token) or [1, {n}, vocab]")
+        rows = logits[0]
+        return rows if rows.shape[0] == B else jnp.take(rows, last, axis=0)
+
     def _ragged_step_fn(self, width, sampling):
-        """The unified serve step at one static width: rows carry
-        (tokens, positions, slot_mapping, lengths) per-sequence ragged
-        metadata — a decode row has one real token, a prefill row a
-        chunk; padded columns sit at position -1 writing the trash
-        slot.  Each row samples from its LAST real column's logits."""
+        """The unified serve step at one static width.  Its tokens are
+        a flat list (module docstring): ``tokens`` / ``positions``
+        [1, N] and ``slot_mapping`` [N], with N = ``max_batch`` at width
+        1 (the rectangle is the list) and ``mixed_tokens`` otherwise,
+        where ``rect_token`` [max_batch, width] and ``token_cell`` [N]
+        map list to rectangle and back; a token nobody carries sits at
+        position -1 writing the trash slot.  Each row samples from the
+        logits of its LAST token, ``last`` [max_batch] (a decode row:
+        its single token; a prefill tail chunk: the final prompt token),
+        the only tokens the head runs on.  The operands arrive as ONE
+        int32 vector, ``_step_operands`` end to end (one transfer a
+        step, not one an operand), and are cut apart here; the sampled
+        tokens go back as one too, -1 where a row's logits were not
+        finite.
+
+        A recurrent model's step keeps the rectangle, its operands
+        apart as arguments and the finite-row flags apart from the
+        tokens: ``tokens`` / ``positions`` [max_batch, width], logits
+        for every column, ``last`` the column each row samples from."""
         key = (width, sampling)
         fn = self._step_fns.get(key)
         if fn is None:
             model, page_size = self.model, self.page_size
-            poison_gate = self._chaos_poison
+            operands = self._step_operands(width)
 
-            def step(params, pages, tokens, positions, page_table,
-                     slot_mapping, lengths, last_col, seeds, steps,
-                     temperature, top_k, poison=None, state_slots=None):
+            def forward(params, pages, o, last_token):
+                rect_token = o.get("rect_token")
                 meta = PagedMeta(
-                    page_table=page_table, slot_mapping=slot_mapping,
-                    lengths=lengths, page_size=page_size,
-                    state_slots=state_slots,
+                    page_table=o["page_table"],
+                    slot_mapping=o["slot_mapping"], lengths=o["lengths"],
+                    page_size=page_size, state_slots=o.get("state_slots"),
+                    rect_token=rect_token,
+                    rect_positions=None if rect_token is None else jnp.take(
+                        o["positions"][0], rect_token, mode="fill",
+                        fill_value=-1),
+                    token_cell=o.get("token_cell"), last_token=last_token,
                 )
                 logits, mutated = model.apply(
-                    {"params": params, "pagedkv": pages}, tokens,
-                    decode=True, positions=positions, paged=meta,
+                    {"params": params, "pagedkv": pages}, o["tokens"],
+                    decode=True, positions=o["positions"], paged=meta,
                     mutable=["pagedkv"],
                 )
-                # each row's sampled-from logits: the last REAL column
-                # of its chunk (a decode row: its single token; a
-                # prefill tail chunk: the final prompt token)
-                rows = jnp.take_along_axis(
-                    logits, last_col[:, None, None], axis=1
-                )[:, 0]
-                if poison_gate:  # chaos injection, gated at trace time
+                return logits, mutated["pagedkv"]
+
+            def sample(rows, o):
+                if "poison" in o:  # chaos injection, gated at trace time
                     rows = jnp.where(
-                        poison[:, None], jnp.asarray(jnp.nan, rows.dtype),
-                        rows,
+                        o["poison"][:, None],
+                        jnp.asarray(jnp.nan, rows.dtype), rows,
                     )
                 ok = finite_rows(rows)
-                toks = self._pick_tokens(
-                    rows, seeds, steps, temperature, top_k, sampling
-                )
-                return toks, ok, mutated["pagedkv"]
+                return self._pick_tokens(
+                    rows, o["seeds"], o["steps"], o["temperature"],
+                    o["top_k"], sampling
+                ), ok
+
+            if self.recurrent:
+                def step(params, pages, *args):
+                    o = {name: x for (name, _), x in zip(operands, args)}
+                    logits, pages = forward(params, pages, o, None)
+                    toks, ok = sample(jnp.take_along_axis(
+                        logits, o["last"][:, None, None], axis=1
+                    )[:, 0], o)
+                    return toks, ok, pages
+            else:
+                def step(params, pages, packed):
+                    o = self._cut(packed, operands)
+                    o["temperature"] = jax.lax.bitcast_convert_type(
+                        o["temperature"], jnp.float32)
+                    if "poison" in o:
+                        o["poison"] = o["poison"] != 0
+                    logits, pages = forward(params, pages, o, o["last"])
+                    toks, ok = sample(self._last_token_rows(
+                        logits, o["last"], width), o)
+                    # one array to fetch: -1 for a row of nonfinite logits
+                    return jnp.where(ok, toks, -1), pages
 
             fn = self._step_fns[key] = jax.jit(
                 step, donate_argnums=(1,)
@@ -465,32 +596,25 @@ class ServeEngine:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree
             )
 
-        def s(*shape, dtype=jnp.int32):
-            return jax.ShapeDtypeStruct(shape, dtype)
-
+        dtypes = {"temperature": jnp.float32, "poison": jnp.bool_}
         params, pages = sds(self.params), sds(self.pages)
-        B, W = self.max_batch, self.table_width
         arts = {}
         widths = self.serve_step_widths() if widths is None else widths
         for w in widths:
+            operands = self._step_operands(w)
+            if self.recurrent:
+                args = [jax.ShapeDtypeStruct(
+                    shape, dtypes.get(name, jnp.int32))
+                    for name, shape in operands]
+            else:
+                args = [jax.ShapeDtypeStruct(
+                    (self._packed_size(operands),), jnp.int32)]
             traced = self._ragged_step_fn(w, sampling).trace(
-                params, pages, s(B, w), s(B, w), s(B, W), s(B * w),
-                s(B), s(B), s(B), s(B), s(B, dtype=jnp.float32), s(B),
-                *self._extra_step_args(s(B, dtype=jnp.bool_), s(B)),
-            )
+                params, pages, *args)
             arts[f"ragged-w{w}"] = {
                 "jaxpr": traced.jaxpr, "lowered": traced.lower(),
             }
         return arts
-
-    def _extra_step_args(self, poison, state_slots):
-        """The step's trailing positional arguments ``(poison,
-        state_slots)``, as far as this engine passes them: none for a
-        model without recurrent layers and no chaos injection (the
-        program such an engine compiles is the one it always did)."""
-        if self.recurrent:
-            return (poison if self._chaos_poison else None, state_slots)
-        return (poison,) if self._chaos_poison else ()
 
     # -- host-side step assembly ---------------------------------------
 
@@ -542,13 +666,17 @@ class ServeEngine:
         running decode is never delayed by admission), then LEFTOVER
         row capacity soaks prompt chunks — one span per prefilling
         sequence in admission order, then EXTRA spans of the same
-        prompts.  Packing several consecutive chunks of ONE prompt
-        into several rows of one dispatch is sound because every
-        layer's KV scatter lands before its gather: chunk k's queries
-        see chunk j<k's keys written in the same program, exactly as a
-        single full-length prefill would — so a cold solo prompt fills
-        the whole ``max_batch x prefill_chunk`` token budget instead
-        of paying for one ragged row and B-1 padded ones.
+        prompts — until the step's ``mixed_tokens`` TOKENS are spent.
+        The budget may cut the last chunk of a step short; a prompt
+        that does not fit continues next step from its watermark, as it
+        does when the rows run out.  Packing several consecutive chunks
+        of ONE prompt into several rows of one dispatch is sound
+        because every layer's KV scatter lands before its gather:
+        chunk k's queries see chunk j<k's keys written in the same
+        program, exactly as a single full-length prefill would — so a
+        cold solo prompt fills the step's token list instead of paying
+        for one ragged row and B-1 padded ones.  The rows of one
+        sequence are consecutive chunks in ascending order.
 
         NOT for a model with recurrent layers: chunk k starts from the
         state chunk k-1 leaves, and the rows of one dispatch all start
@@ -562,14 +690,16 @@ class ServeEngine:
                 rows.append((seq, seq.prefilled, 1, True, True))
             else:
                 prefilling.append([seq, seq.prefilled])
-        while prefilling and len(rows) < self.max_batch:
+        budget = self.mixed_tokens - len(rows)
+        while prefilling and len(rows) < self.max_batch and budget > 0:
             for entry in list(prefilling):
-                if len(rows) >= self.max_batch:
+                if len(rows) >= self.max_batch or budget <= 0:
                     break
                 seq, start = entry
                 total = len(seq.prefix())
-                m = min(self.prefill_chunk, total - start)
+                m = min(self.prefill_chunk, total - start, budget)
                 rows.append((seq, start, m, start + m == total, False))
+                budget -= m
                 entry[1] = start + m
                 if entry[1] >= total:
                     prefilling.remove(entry)
@@ -594,24 +724,35 @@ class ServeEngine:
             B = self.max_batch
             w = self.width_fn(max(m for _, _, m, _, _ in rows))
             assert all(m <= w for _, _, m, _, _ in rows), (rows, w)
-            tokens = np.zeros((B, w), np.int32)
-            positions = np.full((B, w), -1, np.int32)
-            tables = np.zeros((B, self.table_width), np.int32)
-            slot_mapping = np.zeros((B * w,), np.int32)  # 0 = trash slot
-            lengths = np.zeros((B,), np.int32)
-            last_col = np.zeros((B,), np.int32)
-            temperature = np.zeros((B,), np.float32)
-            top_k = np.zeros((B,), np.int32)
-            seeds = np.zeros((B,), np.int32)
-            steps = np.zeros((B,), np.int32)
-            # an empty row's state slot is out of range (and unlike any
-            # other row's): its gather clips, its write is dropped
-            state_slots = self.max_batch + np.arange(B, dtype=np.int32)
-            packed = []
+            # the step's token list: the [B, w] rectangle itself (row b
+            # at b * w) for the decode step and a recurrent model, else
+            # the rows' tokens end to end with the map to the rectangle
+            N = self._step_tokens(w)
+            # every operand is a view of one buffer: what a step without
+            # recurrent layers is handed whole
+            operands = self._step_operands(w)
+            packed = np.zeros(self._packed_size(operands), np.int32)
+            o = self._cut(packed, operands)
+            flat = "rect_token" in o
+            o["temperature"] = o["temperature"].view(np.float32)
+            tokens = o["tokens"].reshape(-1)
+            positions = o["positions"].reshape(-1)
+            positions[:] = -1
+            slot_mapping = o["slot_mapping"]  # 0 = trash slot
+            if flat:
+                o["rect_token"][:] = N
+            if self.recurrent:
+                # an empty row's state slot is out of range (and unlike
+                # any other row's): its gather clips, its write is dropped
+                o["state_slots"][:] = B + np.arange(B, dtype=np.int32)
+            live = []
+            carried = 0
             for seq, start, m, emit, dec in rows:
                 if seq.done:
                     continue  # failed through an earlier row this step
-                b = len(packed)
+                b = len(live)
+                at = carried if flat else b * w
+                mine = slice(at, at + m)
                 try:
                     prefix = seq.prefix()
                     ptable = np.asarray(self.pool.page_table(seq.sid),
@@ -623,59 +764,61 @@ class ServeEngine:
                             f"position {start + m - 1} beyond the "
                             f"{len(ptable)} page(s) of sequence {seq.sid!r}"
                         )
-                    tokens[b, :m] = prefix[start:start + m]
-                    positions[b, :m] = pos
-                    tables[b, :len(ptable)] = ptable
+                    tokens[mine] = prefix[start:start + m]
+                    positions[mine] = pos
+                    o["page_table"][b, :len(ptable)] = ptable
                     # a chunk's write slots, vectorized: one table fetch per
                     # row instead of a per-token pool.slot() call
-                    slot_mapping[b * w:b * w + m] = (
+                    slot_mapping[mine] = (
                         ptable[page_idx] * self.page_size
                         + pos % self.page_size
                     )
-                    lengths[b] = start + m
-                    last_col[b] = m - 1
-                    temperature[b] = seq.req.temperature
-                    top_k[b] = seq.req.top_k
-                    seeds[b] = seq.req.seed
-                    steps[b] = len(seq.generated)
+                    if flat:
+                        o["rect_token"][b, :m] = np.arange(at, at + m)
+                        o["token_cell"][mine] = b * w + np.arange(m)
+                    o["lengths"][b] = start + m
+                    # the token the row samples from: a column of the
+                    # rectangle's logits, or a token of the flat list
+                    o["last"][b] = m - 1 if self.recurrent else at + m - 1
+                    o["temperature"][b] = seq.req.temperature
+                    o["top_k"][b] = seq.req.top_k
+                    o["seeds"][b] = seq.req.seed
+                    o["steps"][b] = len(seq.generated)
+                    if self._chaos_poison:
+                        o["poison"][b] = self._poison_row(seq)
                 except Exception as exc:  # noqa: BLE001 - per-row isolation
                     # scrub the half-written row (trash-slot defaults) and
                     # fail ONLY this sequence
-                    tokens[b] = 0
-                    positions[b] = -1
-                    tables[b] = 0
-                    slot_mapping[b * w:(b + 1) * w] = 0
-                    lengths[b] = 0
+                    tokens[mine] = 0
+                    positions[mine] = -1
+                    slot_mapping[mine] = 0
+                    if flat:
+                        o["token_cell"][mine] = 0
+                        o["rect_token"][b] = N
+                    o["page_table"][b] = 0
+                    o["lengths"][b] = 0
                     self._host_fault([seq], "row-assembly", exc)
                     continue
-                packed.append((seq, start, m, emit, dec))
-            rows = packed
+                live.append((seq, start, m, emit, dec))
+                carried += m
+            rows = live
             if self.recurrent:
                 with _span(SPAN_STATE):
                     for b, (seq, start, *_rest) in enumerate(rows):
-                        state_slots[b] = self.pool.state_slot(seq.sid)
+                        o["state_slots"][b] = self.pool.state_slot(seq.sid)
                     self.stats["state_resets"] += sum(
                         1 for r in rows if r[1] == 0)
         if not rows:
             return
         sampling = self._sampling_mode([r[0] for r in rows])
         with _span(SPAN_TRANSFER):
-            args = [
-                self.params, self.pages,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(tables), jnp.asarray(slot_mapping),
-                jnp.asarray(lengths), jnp.asarray(last_col),
-                jnp.asarray(seeds), jnp.asarray(steps),
-                jnp.asarray(temperature), jnp.asarray(top_k),
-            ]
-            poison = None
-            if self._chaos_poison:
-                poison = np.zeros((B,), bool)
-                for b, (seq, *_rest) in enumerate(rows):
-                    poison[b] = self._poison_row(seq)
-                poison = jnp.asarray(poison)
-            args.extend(self._extra_step_args(
-                poison, jnp.asarray(state_slots) if self.recurrent else None))
+            if self.recurrent:
+                if self._chaos_poison:
+                    o["poison"] = o["poison"].astype(bool)
+                args = [self.params, self.pages,
+                        *(jnp.asarray(o[name]) for name, _ in operands)]
+            else:
+                args = [self.params, self.pages, jnp.asarray(packed)]
         any_decode = any(r[4] for r in rows)
         if self._input_capture is not None:
             # determinism-harness capture: before the call — the jit
@@ -687,7 +830,7 @@ class ServeEngine:
         with _span(_dispatch_span(w)), self._armed(f"serve/ragged-w{w}"):
             with _span(SPAN_LAUNCH):
                 try:
-                    toks, ok, self.pages = step_fn(*args)
+                    *out, self.pages = step_fn(*args)
                 except Exception as exc:
                     if (w, sampling) in self._step_ran:
                         raise
@@ -697,12 +840,18 @@ class ServeEngine:
                     ) from exc
                 self._step_ran.add((w, sampling))
             with _span(SPAN_FETCH):
-                # host sync: the scheduler needs the tokens
-                toks = np.asarray(toks)
-                ok = np.asarray(ok)
+                # host sync: the scheduler needs the tokens, and which
+                # rows sampled from finite logits (a recurrent model's
+                # step says so apart, any other by a token of -1)
+                toks = np.asarray(out[0])
+                ok = np.asarray(out[1]) if self.recurrent else toks >= 0
         dt = time.perf_counter() - t0
         with _span(SPAN_EMIT):
             self.stats["prefills"] += sum(1 for r in rows if not r[4])
+            if w > 1:
+                self.stats["mixed_steps"] += 1
+                self.stats["mixed_tokens_carried"] += carried
+                self.stats["mixed_tokens_capacity"] += N
             if any_decode:
                 self.stats["decode_time_s"] += dt
                 self.decode_ms.append(dt * 1e3)
@@ -1156,7 +1305,14 @@ class ServeEngine:
         WEDGED, whatever its queues claim.  ``host_faults`` (int) is
         the monotonic host-fault counter; the router differences it
         per fleet step, and a burst over its fault window marks the
-        replica dead before a wedge would."""
+        replica dead before a wedge would.
+
+        The fill of the mixed step (monotonic, for a reader to
+        difference): ``mixed_steps`` (int) dispatches at the prefill
+        width, ``mixed_tokens_carried`` (int) the tokens they carried,
+        ``mixed_tokens_capacity`` (int) the tokens their programs were
+        compiled for; carried over capacity is how much of a mixed
+        step's dense work was for tokens somebody sent."""
         sched = self.scheduler
         recent = list(self.decode_ms)[-33:]
         step_ms = float(sorted(recent)[len(recent) // 2]) if recent else 0.0
@@ -1178,6 +1334,10 @@ class ServeEngine:
             "prefix_cache_refused": bool(self.prefix_cache_refused),
             "last_progress": int(self.stats["generated_tokens"]),
             "host_faults": int(self.stats["host_faults"]),
+            "mixed_steps": int(self.stats["mixed_steps"]),
+            "mixed_tokens_carried": int(self.stats["mixed_tokens_carried"]),
+            "mixed_tokens_capacity": int(
+                self.stats["mixed_tokens_capacity"]),
         }
 
     def reclaim_waiting(self, *, include_running=False):
