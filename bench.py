@@ -1259,7 +1259,7 @@ def _zero1_micros(out):
     leaves), ``zero1_step_overhead_ratio`` (reduce-scatter + update
     all-gather cost vs plain dp all-reduce on the 8-device CPU mesh),
     and ``optim_sr_cast_speedup`` (the dispatched fp32->bf16 SR cast vs
-    the jnp reference at the tuner-preset moment size)."""
+    the jnp reference at a BERT-base moment's size)."""
     import subprocess
 
     env = dict(os.environ)
@@ -1292,13 +1292,12 @@ def _zero1_micros(out):
             out[k] = child[k]
 
     # SR cast A/B in THIS process (no mesh dependency): reference jnp
-    # composition vs the dispatched op (autotune verdict / use_pallas
-    # gate) at the committed tuner-preset moment size
+    # composition vs the dispatched op (the use_pallas gate) at a
+    # BERT-base moment's size
     import jax
     import jax.numpy as jnp
 
     from unicore_tpu.ops import rounding as _rnd
-    from unicore_tpu.ops import tuning as _tuning
 
     n = 768 * 768
     x = jnp.zeros((n,), jnp.float32)
@@ -1306,9 +1305,6 @@ def _zero1_micros(out):
     t_ref = _timed(jax.jit(_rnd.fp32_to_bf16_sr_reference), x, key)
     t_disp = _timed(jax.jit(_rnd.fp32_to_bf16_sr), x, key)
     out["optim_sr_cast_speedup"] = round(t_ref / t_disp, 3)
-    out["optim_sr_cast_decision"] = _tuning.describe_decision(
-        "optim_sr_cast", _tuning.sr_cast_workload(n)
-    )
     return out["zero1_step_overhead_ratio"]
 
 
@@ -1564,9 +1560,9 @@ def _microbench(out):
     """Kernel-tier speedups on the chip (the analogue of the reference's
     fused-vs-eager CUDA kernel comparison, BASELINE.md).
 
-    Two families: ``*_speedup`` = the AUTO dispatch (measured per-shape
-    routing) vs the all-jnp reference — the tier's DELIVERED value, >= ~1
-    by construction since auto falls back wherever the kernel loses; and
+    Two families: ``*_speedup`` = the AUTO dispatch (per-shape routing
+    by the ops' rules) vs the all-jnp reference — the tier's DELIVERED
+    value; and
     ``*_kernel_speedup`` = the forced Pallas kernel vs reference — the
     kernel itself, at the shapes it exists for (long-k rows, 5-D
     Evoformer broadcasts).  Fills ``out`` INCREMENTALLY so a late
@@ -1576,33 +1572,15 @@ def _microbench(out):
     import numpy as np
 
     from unicore_tpu import ops
-    from unicore_tpu.ops import tuning
     from unicore_tpu.ops.backend import kernel_backend
     from unicore_tpu.ops.pallas.flash_attention import flash_attention
 
     rng = np.random.RandomState(0)
 
-    def _note_decision(name, workload):
-        """Record which autotuner decision the AUTO dispatch used for a
-        micro ("heuristic" when nothing is cached for the bucket)."""
-        try:
-            out[name + "_tuned_config_used"] = tuning.describe_decision(
-                workload["op"], workload
-            )
-        except Exception as e:  # noqa: BLE001 - reporting must not kill micros
-            out[name + "_tuned_config_used"] = _clean(e, 120)
-
-    def _sd_wl(x, mask, bias):
-        op = lambda a: None if a is None else (a.shape, a.dtype.name)
-        return tuning.sd_workload(
-            x.shape, x.dtype.name, mask=op(mask), bias=op(bias),
-            dropout_on=True,
-        )
-
     def compare(make_fn, *args, fast="pallas"):
         """Backend speedup via the shared interleave protocol; separate
         jits so each traces under its own backend ("auto" traces the
-        measured dispatch)."""
+        shape rules' dispatch)."""
         fp = jax.jit(make_fn())
         fr = jax.jit(make_fn())
 
@@ -1630,14 +1608,12 @@ def _microbench(out):
 
         return loss
 
-    # BERT shape: auto dispatch (the heuristic and the tune cache pick
-    # the side)
+    # BERT shape: auto dispatch (the shape rule picks the side)
     x = jnp.asarray(rng.randn(32, 12, 512, 512), jnp.bfloat16)
     bias = jnp.asarray(rng.randn(1, 12, 512, 512), jnp.bfloat16)
     _record_micro(out, "softmax_dropout_speedup", lambda: compare(
         lambda: jax.grad(sd_loss_of(x, bias)), x, bias, fast="auto"
     ))
-    _note_decision("softmax_dropout_speedup", _sd_wl(x, None, bias))
 
     # long-k rows (k=2048): the regime the reference's block kernel
     # existed for (softmax_fast.h:495-508)
@@ -1646,8 +1622,6 @@ def _microbench(out):
     _record_micro(out, "softmax_dropout_k2048_kernel_speedup", lambda: compare(
         lambda: jax.grad(sd_loss_of(xk, bk)), xk, bk
     ))
-    _note_decision("softmax_dropout_k2048_kernel_speedup",
-                   _sd_wl(xk, None, bk))
 
     # 5-D Evoformer broadcast shape (mask [B,G,1,1,K], bias [1,1,H,Q,K] —
     # reference tests/test_softmax.py:81-119 contract)
@@ -1663,40 +1637,6 @@ def _microbench(out):
     _record_micro(out, "softmax_dropout_evoformer_speedup", lambda: compare(
         lambda: jax.grad(sd_loss_of(xe, be, mask=me)), xe, be, fast="auto"
     ))
-    evo_wl = _sd_wl(xe, me, be)
-    _note_decision("softmax_dropout_evoformer_speedup", evo_wl)
-
-    # the crossover win, made visible (ISSUE 2): tune the evoformer
-    # bucket ON DEVICE (a warm cache reuses the entry — zero re-timings)
-    # and re-measure the auto dispatch, which now follows the measured
-    # verdict — "eager" turns the 0.985x silent regression into a >= 1.0
-    # tie by skipping the kernel; a winning q_blk config beats both
-    def _tuned_evoformer():
-        import os
-        import tempfile
-
-        from unicore_tpu.ops.tuning import TuneCache
-        from unicore_tpu.ops.tuning.tuner import tune_workloads
-
-        # tune into a SCRATCH cache and dispatch from it for this micro
-        # only: writing the persistent overlay would make the next bench
-        # run's "untuned" auto micro read this verdict, collapsing the
-        # heuristic-vs-tuned distinction the metric pair exists to show
-        scratch = TuneCache(paths=[os.path.join(
-            tempfile.mkdtemp(prefix="bench_tune_"), "cache.json"
-        )])
-        tune_workloads([evo_wl], scratch)
-        with tuning.use_cache(scratch):
-            _note_decision("softmax_dropout_evoformer_tuned_speedup",
-                           evo_wl)
-            return compare(
-                lambda: jax.grad(sd_loss_of(xe, be, mask=me)), xe, be,
-                fast="auto",
-            )
-
-    _record_micro(out, "softmax_dropout_evoformer_tuned_speedup",
-                 _tuned_evoformer)
-
     # LayerNorm has NO kernel micro anymore: the Pallas kernel was
     # deleted in r5 after the honest re-measurement (real-bytes sync)
     # read 0.671x vs XLA's own fusion at [32*512, 768] bf16 — XLA is the
@@ -1725,9 +1665,6 @@ def _microbench(out):
         return round(r, 3), s
 
     _record_micro(out, "flash_attention_t2048_speedup", _flash_ratio)
-    _note_decision("flash_attention_t2048_speedup", tuning.flash_workload(
-        q.shape, q.shape[1], q.dtype.name,
-    ))
 
     # fused vs eager AdamW (BASELINE.md "fused-vs-eager speedup"): the
     # framework's one-jit whole-tree update (the analogue of the

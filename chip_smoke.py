@@ -268,12 +268,10 @@ def train_phase(work, seed, size):
     import jax
 
     from unicore_tpu.distributed import utils as dist_utils
-    from unicore_tpu.ops import backend, tuning
-    from unicore_tpu.ops.tuning import cache as tune_cache
+    from unicore_tpu.ops import backend
 
     _import_example("bert")
     dist_utils.reset_mesh()
-    overlay = tune_cache.TuneCache(paths=[tune_cache.overlay_cache_path()])
     data = write_corpus(
         os.path.join(work, "bert_data"), seed, size["symbols"],
         size["seq"], n_train=size["batch"] * (size["updates"] + 2),
@@ -331,10 +329,6 @@ def train_phase(work, seed, size):
         "cache_dir": cache_dir,
         "cache_entries": (len(os.listdir(cache_dir))
                           if cache_dir and os.path.isdir(cache_dir) else 0),
-        "tuner_mode": tuning.autotune_mode(),
-        # state outside git that steers which program is compiled
-        "tune_overlay_entries": sum(
-            len(es) for es in overlay.all_entries().values()),
         "kernel_dispatch": backend.dispatch_report(),
     })
     shutil.rmtree(save_dir)  # gigabytes at the real width
@@ -744,9 +738,6 @@ def main(argv=None):
             rep = train_phase(WORK, args.seed, BERT)
             emit("train", **rep)
             _chip_checks_step(rep, "bert")
-            check(rep["tune_overlay_entries"] == 0,
-                  f"{rep['tune_overlay_entries']} kernel-tune overlay "
-                  "entries steer this run from outside the checkout")
             check(rep["cache_entries"] > 0,
                   f"nothing was written to {rep['cache_dir']}")
             # a hit is the cache's own event; that it was the train step

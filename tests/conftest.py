@@ -10,17 +10,7 @@ before any backend is initialized: conftest import time is early enough
 (pytest imports conftest before test modules).
 """
 
-import atexit
 import os
-import shutil
-import tempfile
-
-# hermetic kernel-autotune overlay: a developer machine's tune entries
-# (~/.cache or an exported UNICORE_TPU_CACHE_DIR) must not steer
-# dispatch (block choices) inside the suite — unconditional override
-_tune_dir = tempfile.mkdtemp(prefix="unicore_tune_test_")
-os.environ["UNICORE_TPU_CACHE_DIR"] = _tune_dir
-atexit.register(shutil.rmtree, _tune_dir, ignore_errors=True)
 
 # the suite neither reads nor writes the persistent compilation cache:
 # the entry points would otherwise place one in the checkout, shared by

@@ -9,7 +9,7 @@ parameters: the first layer traces the body, the others share that
 trace.  These tests hold the mechanism to what it promises: one body
 entry per compiled width whatever the depth, as many kernels in the
 program as there are layers, the served tokens of the eager path, and a
-cache entry of its own for every geometry and every tuned page block.
+cache entry of its own for every geometry and every page block.
 
 Nested under the step's trace the inner jit keeps no executable, so
 ``_call._cache_size()`` stays 0 there; what counts the traces is the
@@ -27,7 +27,7 @@ import pytest
 
 from examples.lm.model import TransformerLMModel
 from unicore_tpu.analysis.trace_audit import _iter_eqns
-from unicore_tpu.ops import backend, tuning
+from unicore_tpu.ops import backend
 from unicore_tpu.ops.pallas import paged_attention as pa
 from unicore_tpu.serve import Request
 from unicore_tpu.serve.engine import ServeEngine
@@ -197,26 +197,23 @@ def test_engines_of_other_geometry_get_their_own_trace(other,
                     for g in (a, b)}
 
 
-# -- (d) a tuned page block reaches the kernel -----------------------------
+# -- (d) the shape rule's page block reaches the kernel --------------------
 
 
-def test_tuned_pages_per_block_reaches_the_kernel(kernel_entries):
+def test_picked_pages_per_block_reaches_the_kernel(kernel_entries):
     model, params = _lm()
     with backend.kernel_backend("pallas"):
         engine = _engine(model, params)
-        heuristic = pa.pick_pages_per_block(
+        picked = pa.pick_pages_per_block(
             engine.table_width, engine.page_size, 8, num_heads=4,
             itemsize=4)
-        assert heuristic != 1
+        assert picked != 1
         engine.trace_step_fns(widths=(1,))
-        with tuning.forced_config("ragged_paged_attention",
-                                  {"pages_per_block": 1}):
-            _engine(model, params).trace_step_fns(widths=(1,))
-    assert [e["pages_per_block"] for e in kernel_entries] == [heuristic, 1]
+    assert [e["pages_per_block"] for e in kernel_entries] == [picked]
 
 
 def test_eager_calls_cache_one_entry_per_page_block(rng, kernel_entries):
-    """Called eagerly (the tuner's candidates, the kernel's own tests)
+    """Called eagerly (the kernel's own tests, a scratch sweep)
     the inner jit keeps its executables, so ``_cache_size()`` counts
     them: one per ``pages_per_block``, none for a repeated call."""
     bsz, pages, ps, heads, d = 2, 4, 4, 4, 8

@@ -1037,29 +1037,6 @@ def test_prefix_cache_on_off_and_eviction_deterministic(lm):
     assert e3.pool.prefix_stats == e2.pool.prefix_stats
 
 
-def test_auto_prefill_chunk_consults_tuner(lm):
-    """prefill_chunk=0 (auto) takes a measured chunked-admission
-    verdict for the engine's ragged bucket; an explicit chunk always
-    wins, and no verdict means the default."""
-    from unicore_tpu.ops import tuning
-    from unicore_tpu.serve.engine import DEFAULT_PREFILL_CHUNK
-
-    model, params = lm
-    base = ServeEngine(model, params, num_pages=16, page_size=4,
-                       max_batch=2)
-    assert base.prefill_chunk == DEFAULT_PREFILL_CHUNK
-    with tuning.forced_config(
-            "ragged_paged_attention",
-            {"pages_per_block": 1, "prefill_chunk": 8}):
-        tuned = ServeEngine(model, params, num_pages=16, page_size=4,
-                            max_batch=2)
-        explicit = ServeEngine(model, params, num_pages=16, page_size=4,
-                               max_batch=2, prefill_chunk=16)
-    assert tuned.prefill_chunk == 8
-    assert tuned.serve_step_widths() == (1, 8)
-    assert explicit.prefill_chunk == 16
-
-
 def test_quarantined_prefix_sharer_leaves_survivor_exact(lm):
     """A poisoned request whose pages are prefix-SHARED is quarantined
     while the survivor sharing the prefix stays token-identical — the

@@ -155,7 +155,7 @@ Rules (see docs/static_analysis.md for rationale and incidents):
 - UL117 wall-clock-in-decision-path: a wall-clock read
   (``time.time``/``perf_counter``/``monotonic``/``datetime.now``/…)
   inside a production DECISION module — scheduler/router/health/
-  rollout/tuning dispatch, and everything under ``fleet/`` and
+  rollout dispatch, and everything under ``fleet/`` and
   ``deploy/`` — outside the injectable-clock idiom those tiers
   standardize on (``clock=None`` parameter, ``self._clock = clock or
   time.monotonic``).  A decision keyed on the real clock cannot be
@@ -339,7 +339,7 @@ _UL117_TIMING_NAME_RE = re.compile(
 # UL117: basename fragments that mark a module as decision dispatch
 # (fleet/ and deploy/ are in scope wholesale — see _is_decision_file)
 _UL117_DECISION_FRAGS = ("scheduler", "engine", "router", "rollout",
-                         "health", "tuner", "tuning", "autoscaler")
+                         "health", "autoscaler")
 
 # UL118: method tails that grow a collection with the factory's result
 _UL118_GROW_TAILS = {"append", "appendleft", "add", "insert"}

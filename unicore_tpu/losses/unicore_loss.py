@@ -29,7 +29,7 @@ def fused_head_request(loss, model):
     ``supports_fused_head`` (the features+kernel+bias output contract) —
     models without the contract silently keep the materialized-logits
     path.  ``chunk_override`` is ``--fused-ce-chunk`` (0/None = auto:
-    tuned verdict, else the op's byte heuristics)."""
+    the op's byte rule)."""
     args = getattr(loss, "args", None)
     enabled = str(getattr(args, "fused_lm_head", None) or "on") != "off"
     if not (enabled and getattr(model, "supports_fused_head", False)):

@@ -55,12 +55,11 @@ def _flash_ok(q, k, bias, has_pad, dropout_on, causal=False):
         None if bias is None else tuple(bias.shape),
         has_pad, causal, dropout_on,
     )
-    return note_dispatch("flash_attention", desc, _flash_wins(
-        q, k, bias, has_pad, dropout_on, causal))
+    return note_dispatch("flash_attention", desc, _flash_wins(q, k, bias))
 
 
-def _flash_wins(q, k, bias, has_pad, dropout_on, causal):
-    from unicore_tpu.ops.backend import use_pallas
+def _flash_wins(q, k, bias):
+    from unicore_tpu.ops.backend import get_kernel_backend, use_pallas
     from unicore_tpu.ops.pallas import flash_attention as fa
 
     if not use_pallas():
@@ -91,21 +90,6 @@ def _flash_wins(q, k, bias, has_pad, dropout_on, causal):
             return False
         if bias is not None and bias.shape[0] not in (1, q.shape[0]):
             return False
-    # autotuner eager-crossover: a cache entry that says the measured
-    # winner for this bucket is the einsum composition routes around the
-    # kernel entirely (a forced "pallas" backend still takes flash — the
-    # parity/test override stays deterministic)
-    from unicore_tpu.ops import tuning
-    from unicore_tpu.ops.backend import get_kernel_backend
-
-    tune_dec = tuning.flash_decision(
-        q.shape, k.shape[1], q.dtype.name,
-        bias=None if bias is None else (bias.shape, bias.dtype.name),
-        has_pad=has_pad, causal=causal, dropout_on=dropout_on,
-        allow_tune=True,  # this workload carries the real batch/heads
-    )
-    if tune_dec == "eager" and get_kernel_backend() != "pallas":
-        return False
     # measured on v5e (BERT-base, T=512, trainable [1,H,T,T] bias,
     # dropout): in the SINGLE-BLOCK regime the fused backward computes
     # dq/dk/dv/dbias in one pass; isolated it is 1.6x faster than the
@@ -121,21 +105,10 @@ def _flash_wins(q, k, bias, has_pad, dropout_on, causal):
     # forced "pallas" backend always takes flash.
     if get_kernel_backend() != "pallas" and bias is not None:
         bq, bk = fa.picked_blocks(
-            q.shape[1], k.shape[1], bias.shape, bias.dtype,
-            dtype=q.dtype, d=q.shape[3], has_pad=has_pad, causal=causal,
-            dropout_on=dropout_on,
+            q.shape[1], k.shape[1], bias.shape, bias.dtype
         )
         single_block = q.shape[1] == bq and k.shape[1] == bk
-        # a tuned block pair is a measured verdict that flash wins at
-        # those blocks — the static multi-block/short-k crossover rule
-        # below only applies when the heuristic picked the blocks; the
-        # verdict must VALIDATE for the actual lengths (a pow2 bucket can
-        # cover lengths its blocks don't divide, in which case the blocks
-        # in use are heuristic ones the cache never vouched for)
-        tuned_applies = tuning.tuned_flash_blocks(
-            q.shape[1], k.shape[1], tune_dec
-        ) is not None
-        if not single_block and k.shape[1] < 1024 and not tuned_applies:
+        if not single_block and k.shape[1] < 1024:
             return False
     return True
 

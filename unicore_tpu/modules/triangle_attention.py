@@ -38,7 +38,7 @@ def group_flash_attention(q, k, v, pair_bias, mask, dropout, deterministic,
     materialized einsum path pays at realistic residue counts.  At
     T <= 512 the single-block fused backward computes dq/dk/dv/dbias in
     one sweep.  Returns ``[B, G, T, H, Dh]``, or None when the kernel
-    does not apply (non-128-multiple T, batched bias, tuner verdict) —
+    does not apply (non-128-multiple T, batched bias, short T) —
     callers fall back to the einsum + fused-softmax path."""
     from unicore_tpu.ops.backend import (
         get_kernel_backend, needs_shard_map, use_pallas,
@@ -69,20 +69,6 @@ def group_flash_attention(q, k, v, pair_bias, mask, dropout, deterministic,
     if not fa.eligible(qs, qs, None if bias is None else bias.shape):
         return None
     dropout_on = (not deterministic) and dropout > 0.0
-    # autotuner eager-crossover: a measured verdict that the einsum
-    # composition wins this bucket routes around the kernel (forced
-    # "pallas" backend stays on the kernel); the (B*G, T, H, D) workload
-    # carries the real grouped-batch extent, so tune mode may time it
-    from unicore_tpu.ops import tuning
-
-    tune_dec = tuning.flash_decision(
-        (B * G, T, H, D), T, q.dtype.name,
-        bias=None if bias is None else (bias.shape, bias.dtype.name),
-        has_pad=mask is not None, causal=False, dropout_on=dropout_on,
-        allow_tune=True,
-    )
-    if tune_dec == "eager" and get_kernel_backend() != "pallas":
-        return None
     rng = make_rng("dropout") if dropout_on else None
     kpm = None
     if mask is not None:

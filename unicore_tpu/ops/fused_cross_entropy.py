@@ -26,12 +26,10 @@ The per-row-cotangent contract (callers weight the nll themselves, e.g.
 full-sequence weighted-mask MLM loss, the static-slot ``[K, V]`` head,
 and plain cross-entropy.
 
-Dispatch mirrors the other tunable ops: an explicit ``chunk_size`` wins,
-then a tuned verdict from ``ops/tuning`` (``"eager"`` retires the fused
-path for buckets where the unfused matmul wins — small vocab*rows), then
-a static heuristic (fuse only when the logits tensor would exceed
-``FUSE_MIN_BYTES``; chunk sized so the per-chunk fp32 logits stay inside
-``CHUNK_TARGET_BYTES``).
+Dispatch: an explicit ``chunk_size`` wins, else a static byte rule (fuse
+only when the logits tensor would exceed ``FUSE_MIN_BYTES``; chunk sized
+so the per-chunk fp32 logits stay inside ``CHUNK_TARGET_BYTES``).
+Neither constant has been swept on this machine.
 """
 
 import functools
@@ -40,10 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# below this full-logits size the unfused matmul + logsumexp is both
-# faster (one big MXU call, no scan fixed costs) and irrelevant to peak
-# HBM; the autotuner's measured per-bucket verdict overrides in either
-# direction
+# below this full-logits size the unfused matmul + logsumexp is one big
+# MXU call with no scan fixed costs, and irrelevant to peak HBM
 FUSE_MIN_BYTES = 16 << 20
 # per-chunk fp32 logits budget the chunk heuristic targets: big enough
 # that the [chunk, V] matmul amortizes scan overhead (~256 rows at a 30k
@@ -65,8 +61,8 @@ def linear_nll_reference(features, kernel, targets, bias=None, *,
                          tied=False):
     """Unfused spec: materialized logits -> fp32 ``logsumexp - picked``.
     Bit-for-bit the path the losses took before this op existed (the
-    matmul runs in the compute dtype, the reduction in fp32), so an
-    ``"eager"`` verdict is a no-op relative to the legacy head."""
+    matmul runs in the compute dtype, the reduction in fp32), so the
+    unfused branch is a no-op relative to the legacy head."""
     kernel = kernel.astype(features.dtype)
     logits = features @ (kernel.T if tied else kernel)
     if bias is not None:
@@ -172,31 +168,16 @@ def _chunked_nll_bwd(chunk, tied, res, g):
 _chunked_nll.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
 
 
-def _resolve_chunk(rows, hidden, vocab, dtype, tied, has_bias):
-    """None -> eager (unfused), int -> fused chunk size.  Consults the
-    autotuner (a tuned ``"eager"`` or ``{"chunk": n}`` verdict wins),
-    then the static byte heuristics.  Never raises into the trace."""
-    try:
-        from unicore_tpu.ops import tuning
-
-        dec = tuning.fused_ce_decision(
-            rows, hidden, vocab, dtype, tied=tied, has_bias=has_bias,
-            allow_tune=True,
-        )
-        if dec == "eager":
-            return None
-        tuned = tuning.tuned_ce_chunk(rows, dec)
-        if tuned is not None:
-            return tuned
-    except Exception:  # noqa: BLE001 - tuner failure -> heuristics
-        pass
+def _resolve_chunk(rows, vocab):
+    """None -> eager (unfused), int -> fused chunk size: the static byte
+    rule, a function of the logits' shape alone."""
     if rows * vocab * 4 < FUSE_MIN_BYTES:
         return None
     chunk = pick_chunk(rows, vocab)
     if chunk >= rows:
         # a single chunk IS the full-logits program plus scan overhead —
         # nothing to save; let the one big MXU call win (an explicit
-        # chunk_size or tuned verdict can still force the chunked path)
+        # chunk_size can still force the chunked path)
         return None
     return chunk
 
@@ -210,9 +191,9 @@ def fused_linear_cross_entropy(features, kernel, targets, bias=None, *,
     - ``kernel``: ``[D, V]``, or the tied-embedding ``[V, D]`` ``attend``
       form with ``tied=True``.
     - ``targets``: ``[N]`` int labels; ``bias``: optional ``[V]``.
-    - ``chunk_size``: rows per scan step.  ``None``/0 = auto (tuned
-      verdict, else heuristic with an eager crossover for small
-      vocab*rows); an explicit value always takes the chunked path.
+    - ``chunk_size``: rows per scan step.  ``None``/0 = auto (the byte
+      rule, with an eager crossover for small vocab*rows); an explicit
+      value always takes the chunked path.
 
     Callers weight the returned nll themselves (``sum(nll * w)``): the
     per-row cotangent flows into the chunked backward, so masked/slot
@@ -225,8 +206,7 @@ def fused_linear_cross_entropy(features, kernel, targets, bias=None, *,
     else:
         # 0/negative/None all mean auto — a negative explicit chunk
         # would otherwise clamp to 1 and scan N single-row matvecs
-        chunk = _resolve_chunk(n, d, v, features.dtype.name, tied,
-                               bias is not None)
+        chunk = _resolve_chunk(n, v)
         if chunk is None:
             return linear_nll_reference(features, kernel, targets,
                                         bias=bias, tied=tied)

@@ -22,13 +22,6 @@ history.)
 
 import jax.numpy as jnp
 
-# The op's candidate set for the kernel autotuner (ops/tuning): eager
-# only — the r5 verdict above IS the tuned decision for every bucket,
-# and keeping the op registered means ``unicore_tune`` records the
-# measured eager cost per device kind (and any future kernel candidate
-# re-enters the race here instead of via a new dispatch path).
-TUNING_CANDIDATES = ("eager",)
-
 
 def layer_norm_reference(x, weight=None, bias=None, eps=1e-5):
     dtype = x.dtype

@@ -602,61 +602,38 @@ def _lse_spec(block_q):
 _SEED_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def picked_blocks(tq, tk, bias_shape=None, bias_dtype=None, *, dtype=None,
-                  d=None, has_pad=False, causal=False, dropout_on=False):
+def picked_blocks(tq, tk, bias_shape=None, bias_dtype=None):
     """The (block_q, block_k) the kernel will use for these shapes —
     THE block-choice authority, shared by `_common` and the module-level
     dispatch gate (`_flash_ok` predicts the single-block regime with it;
-    a drifted duplicate would silently misroute dispatch).  When the
-    caller supplies ``dtype``/``d`` (the full variant), a tuned block
-    pair from the autotuner cache takes precedence over the heuristic —
-    validated against the ACTUAL lengths, since a pow2 shape bucket can
-    cover lengths its blocks don't divide; tuner decisions are memoized
-    per process, so the forward and backward of one custom_vjp always
-    agree.  A bQ==1 broadcast bias streams only (1, block_k) per step
-    (~KBs) — shrinking the score block for it would multiply grid steps
-    for no VMEM relief; only a full (block_q, block_k) bias stream costs
-    budget."""
+    a drifted duplicate would silently misroute dispatch).  A function
+    of its arguments alone, so the forward and backward of one
+    custom_vjp always agree.  A bQ==1 broadcast bias streams only
+    (1, block_k) per step (~KBs) — shrinking the score block for it
+    would multiply grid steps for no VMEM relief; only a full
+    (block_q, block_k) bias stream costs budget."""
     bias_itemsize = (
         jnp.dtype(bias_dtype).itemsize
         if bias_shape is not None and bias_shape[2] != 1
         else 0
     )
-    if dtype is not None and d is not None:
-        from unicore_tpu.ops import tuning
-
-        dec = tuning.flash_decision(
-            (1, tq, 1, d), tk, jnp.dtype(dtype).name,
-            bias=None if bias_shape is None else (
-                bias_shape, jnp.dtype(bias_dtype).name
-            ),
-            has_pad=has_pad, causal=causal, dropout_on=dropout_on,
-        )
-        tuned = tuning.tuned_flash_blocks(tq, tk, dec)
-        if tuned is not None:
-            return tuned
     return _pick_blocks(tq, tk, bias_itemsize)
 
 
-def _common(q, k, causal, bias=None, has_pad=False, dropout_on=False):
+def _common(q, k, bias=None):
     bsz, heads, tq, d = q.shape
     tk = k.shape[2]
     block_q, block_k = picked_blocks(
         tq, tk,
         None if bias is None else bias.shape,
         None if bias is None else bias.dtype,
-        dtype=q.dtype, d=d, has_pad=has_pad, causal=causal,
-        dropout_on=dropout_on,
     )
     grid = (bsz, heads, tq // block_q, tk // block_k)
     return bsz, heads, tq, tk, d, block_q, block_k, grid
 
 
 def _flash_fwd_impl(q, k, v, bias, pad, dropout_prob, seed, causal, scale):
-    bsz, heads, tq, tk, d, block_q, block_k, grid = _common(
-        q, k, causal, bias, has_pad=pad is not None,
-        dropout_on=dropout_prob > 0.0,
-    )
+    bsz, heads, tq, tk, d, block_q, block_k, grid = _common(q, k, bias)
     if grid[2] == 1 and grid[3] == 1:
         return _flash_fwd_hb(
             q, k, v, bias, pad, dropout_prob, seed, causal, scale,
@@ -768,10 +745,7 @@ def _flash_fwd(q, k, v, bias, pad, dropout_prob, seed, causal, scale):
 
 def _flash_bwd(dropout_prob, causal, scale, residuals, g):
     q, k, v, bias, pad, seed, out, lse = residuals
-    bsz, heads, tq, tk, d, block_q, block_k, grid = _common(
-        q, k, causal, bias, has_pad=pad is not None,
-        dropout_on=dropout_prob > 0.0,
-    )
+    bsz, heads, tq, tk, d, block_q, block_k, grid = _common(q, k, bias)
     n_q, n_k = grid[2], grid[3]
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True

@@ -31,10 +31,10 @@ unwritten/stale slots, since every real query position is below the
 row's length.  Inactive query columns (position -1) mask everything and
 come out finite (garbage by contract, discarded by the caller).
 
-Dispatch (serve/attention.py) gates on ``use_pallas``, the autotuner
-verdict (op ``"ragged_paged_attention"``) and ``supported`` (a static
-shape rule).  There is no compile probe: a shape ``supported`` admits
-and the chip's compiler refuses fails the serve step's compile, loudly.
+Dispatch (serve/attention.py) gates on ``use_pallas`` and ``supported``
+(a static shape rule).  There is no compile probe: a shape ``supported``
+admits and the chip's compiler refuses fails the serve step's compile,
+loudly.
 """
 
 import functools
@@ -69,17 +69,15 @@ def supported(heads, head_dim, page_size, itemsize):
     return slab % _LANES == 0 and page_size % (8 * 4 // itemsize) == 0
 
 
-def pick_pages_per_block(num_table_pages, page_size, head_dim, tuned=None,
+def pick_pages_per_block(num_table_pages, page_size, head_dim,
                          num_heads=8, itemsize=2):
-    """Pages DMA'd per online-softmax block.  A tuned (validated) config
-    wins; the heuristic targets ~256 gathered slots per block — enough
-    rows to amortize the DMA issue latency without blowing VMEM."""
+    """Pages DMA'd per online-softmax block: ~256 gathered slots per
+    block — enough rows to amortize the DMA issue latency — held inside
+    the scratch budget.  The 256 has not been swept on this machine."""
     def fits(pp):
         return (2 * pp * page_size * num_heads * head_dim * itemsize
                 <= _SCRATCH_BUDGET_BYTES)
 
-    if tuned is not None and fits(tuned):
-        return int(tuned)
     pp = max(1, min(int(num_table_pages), -(-256 // int(page_size))))
     while pp > 1 and not fits(pp):
         pp -= 1
@@ -190,8 +188,8 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
 # ``tpu_custom_call`` and a call to it per layer, which XLA inlines
 # before it schedules anything, so the compiled step holds the same
 # kernels in the same order.  The key is jit's own: operand shapes and
-# dtypes and the static keywords, so another geometry and the tuner's
-# other ``pages_per_block`` get entries of their own (a static argument
+# dtypes and the static keywords, so another geometry and another
+# ``pages_per_block`` get entries of their own (a static argument
 # must hash: ``scale`` arrives as a Python float).  ``interpret`` is
 # read by the caller and is part of the key, not read in here once per
 # cached trace: it is constant in a process (``backend._on_tpu()``),

@@ -252,12 +252,8 @@ _softmax_dropout_p.defvjp(_fwd, _bwd)
 
 
 def softmax_dropout(x, dropout_prob, rng=None, is_training=True, mask=None,
-                    bias=None, q_blk=None):
-    """Entry point matching ``ops.softmax_dropout`` (minus return_softmax).
-
-    ``q_blk``: explicit row-block size (the autotuner's tuned config);
-    validated against the row count — an inapplicable value falls back
-    to the VMEM-budget heuristic rather than failing the lowering."""
+                    bias=None):
+    """Entry point matching ``ops.softmax_dropout`` (minus return_softmax)."""
     mask, bias = _canon(x, mask, bias)
     p = float(dropout_prob) if is_training else 0.0
     if p > 0.0:
@@ -266,6 +262,5 @@ def softmax_dropout(x, dropout_prob, rng=None, is_training=True, mask=None,
         seed = jax.random.randint(rng, (1,), 0, 2**31 - 1, dtype=jnp.int32)
     else:
         seed = jnp.zeros((1,), dtype=jnp.int32)
-    if q_blk is None or q_blk < 1 or q_blk > x.shape[-2] or x.shape[-2] % q_blk:
-        q_blk = _pick_q_blk_for(x, mask, bias)
-    return _softmax_dropout_p(x, mask, bias, p, int(q_blk), seed)
+    q_blk = _pick_q_blk_for(x, mask, bias)
+    return _softmax_dropout_p(x, mask, bias, p, q_blk, seed)

@@ -32,20 +32,11 @@ def fp32_to_bf16_sr_reference(x, rng):
 
 
 def fp32_to_bf16_sr(x, rng):
-    # autotuner consult (op "optim_sr_cast", docs/kernel_autotuning.md):
-    # a cached "eager" verdict retires the kernel for this size bucket,
-    # a config dict forces it; None falls through to the use_pallas
-    # heuristic.  Decisions are trace-time and memoized, so the chosen
-    # random stream (threefry reference vs counter-hash kernel) is
-    # stable for the whole process — the chaos bit-exactness contract.
-    from unicore_tpu.ops import tuning
-
-    decision = tuning.sr_cast_decision(x.size, str(x.dtype))
     # under a multi-device mesh GSPMD partitions the reference and cannot
-    # partition a Mosaic kernel
-    take_kernel = decision != "eager" and not needs_shard_map() and (
-        use_pallas() or isinstance(decision, dict)
-    )
+    # partition a Mosaic kernel.  The two impls draw different random
+    # streams; the choice reads only the mesh and the backend, so one
+    # process never mixes them.
+    take_kernel = not needs_shard_map() and use_pallas()
     if note_dispatch("fp32_to_bf16_sr", "n%d" % x.size, take_kernel):
         from .pallas import rounding as pl_impl
 

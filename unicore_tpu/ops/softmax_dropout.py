@@ -70,35 +70,18 @@ def softmax_dropout(
     Under a multi-device mesh it is always the reference: GSPMD partitions
     that, and cannot partition a Mosaic kernel.
 
-    Dispatch order under the auto backend: the autotuner cache first (a
-    recorded ``"eager"`` skips the kernel — the measured-crossover case;
-    a recorded ``{"q_blk": n}`` lowers that row block), then the static
-    rows-per-program crossover gate.  Nothing here reads a clock, so the
-    same shapes always trace the same program.  A forced ``"pallas"``
-    backend always takes the kernel (with a tuned row block when one is
-    cached) — the parity/test override stays deterministic."""
+    Under the auto backend the choice is :func:`_heuristic_kernel_win`'s
+    elements-per-program gate, a function of the shapes alone: the same
+    shapes always trace the same program.  A forced ``"pallas"`` backend
+    always takes the kernel (the parity/test override)."""
     if (use_pallas() and not needs_shard_map() and not return_softmax
             and _pallas_eligible(x, mask, bias)):
-        from . import tuning
         from .backend import get_kernel_backend, note_dispatch
         from .pallas import softmax_dropout as pl_impl
 
         dropout_on = is_training and float(dropout_prob) > 0.0
-        forced = get_kernel_backend() == "pallas"
-        opinfo = lambda op: (
-            None if op is None else (op.shape, op.dtype.name)
-        )
-        dec = tuning.softmax_dropout_decision(
-            x.shape, x.dtype.name, mask=opinfo(mask), bias=opinfo(bias),
-            dropout_on=dropout_on, allow_tune=True,
-        )
-        # a config whose q_blk doesn't validate for this row count (pow2
-        # buckets cover rows their block doesn't divide) was never
-        # measured as-lowered: the heuristic decides instead
-        q_blk = tuning.tuned_q_blk(x.shape[-2], dec)
-        take_kernel = forced or q_blk is not None or (
-            dec != "eager" and _heuristic_kernel_win(x, mask, bias)
-        )
+        take_kernel = (get_kernel_backend() == "pallas"
+                       or _heuristic_kernel_win(x, mask, bias))
         desc = "x%s %s mask=%s bias=%s dropout=%s" % (
             tuple(x.shape), x.dtype.name,
             None if mask is None else tuple(mask.shape),
@@ -107,7 +90,7 @@ def softmax_dropout(
         if note_dispatch("softmax_dropout", desc, take_kernel):
             return pl_impl.softmax_dropout(
                 x, dropout_prob, rng=rng, is_training=is_training,
-                mask=mask, bias=bias, q_blk=q_blk,
+                mask=mask, bias=bias,
             )
     return softmax_dropout_reference(
         x,
@@ -121,16 +104,14 @@ def softmax_dropout(
 
 
 def _heuristic_kernel_win(x, mask, bias):
-    """Static crossover gate for the out-of-the-box (no-cache) path: the
-    kernel pays ~2us of fixed cost per grid program plus its streaming
-    setup, so when each program's row block is small the eager XLA
-    fusion wins and the kernel must NOT lower.  The gate is elements per
-    program (row_block x k): the 5-D evoformer shape (batched mask/bias,
-    128x128 blocks, 512 programs, 16K elements each) sits below the 64K
-    threshold, the BERT and k=2048 shapes (131K elements per program)
-    above it.  Where the crossover really lies has not been measured on
-    this machine; the autotuner's measured per-bucket verdict overrides
-    this gate in either direction."""
+    """Static crossover gate: the kernel pays a fixed cost per grid
+    program plus its streaming setup, so when each program's row block
+    is small the eager XLA fusion wins and the kernel must NOT lower.
+    The gate is elements per program (row_block x k): the 5-D evoformer
+    shape (batched mask/bias, 128x128 blocks, 512 programs, 16K elements
+    each) sits below the 64K threshold, the BERT and k=2048 shapes (131K
+    elements per program) above it.  Where the crossover really lies has
+    not been measured on this machine."""
     from .pallas.softmax_dropout import _pick_q_blk_for
 
     return _pick_q_blk_for(x, mask, bias) * x.shape[-1] >= (1 << 16)

@@ -438,18 +438,6 @@ def add_optimization_args(parser):
                             'rbg is ~13%% faster per step on TPU (measured '
                             'BERT-base v5e); threefry is the jax default '
                             'with cross-backend stream stability')
-    group.add_argument('--kernel-autotune', default=None,
-                       choices=['off', 'cache', 'tune'],
-                       help='Pallas kernel config autotuning '
-                            '(docs/kernel_autotuning.md): "cache" dispatches '
-                            'from the persistent tune cache with the static '
-                            'heuristics as fallback; "tune" also times unseen '
-                            'shape buckets at first dispatch (single-host TPU '
-                            'only) and records the winners; "off" uses '
-                            'heuristics only.  Unset, the '
-                            'UNICORE_TPU_KERNEL_AUTOTUNE env var (default '
-                            '"cache") governs — an argparse default here '
-                            'would silently clobber it')
     group.add_argument('--fused-lm-head', default='on', choices=['on', 'off'],
                        help='fused chunked linear+cross-entropy head '
                             '(docs/performance.md): the loss runs the vocab '
@@ -461,9 +449,10 @@ def add_optimization_args(parser):
                             'always use it)')
     group.add_argument('--fused-ce-chunk', default=0, type=int, metavar='N',
                        help='rows per chunk for the fused LM/CE head; 0 = '
-                            'auto (kernel-autotune verdict when cached, else '
-                            'a byte-budget heuristic that falls back to the '
-                            'unfused matmul for small vocab*rows)')
+                            'auto: a rule on the logits\' bytes (unfused '
+                            'matmul under 16 MiB of fp32 logits, else the '
+                            'largest power-of-two chunk whose fp32 logits '
+                            'fit 32 MiB)')
     group.add_argument('--lr', '--learning-rate', default='0.25', type=eval_str_list_float,
                        metavar='LR_1,LR_2,...,LR_N',
                        help='per-epoch learning rates; the last entry persists past the list '

@@ -59,36 +59,16 @@ def _local_attention(q, k, v, bias, key_padding_mask, causal, scale,
     return o.astype(q.dtype)
 
 
-def _flash_local_ok(q_shape, k_shape, bias_shape, bias_dtype, has_pad,
-                    causal, dropout_on, dtype):
+def _flash_local_ok(q_shape, k_shape, bias_shape):
     """Can the flash kernel take the LOCAL (post-all-to-all) attention?
     Checked with the local shapes."""
     from unicore_tpu.ops.backend import use_pallas
     from unicore_tpu.ops.pallas import flash_attention as fa
 
-    if not use_pallas():
-        return False
     b, t, h_local, d = q_shape
     qs = (b, h_local, t, d)
     ks = (k_shape[0], h_local, k_shape[1], d)
-    if not fa.eligible(qs, ks, bias_shape):
-        return False
-    # autotuner eager-crossover on the LOCAL shapes (the per-device
-    # workload is what actually runs); forced "pallas" stays kernel
-    from unicore_tpu.ops import tuning
-    from unicore_tpu.ops.backend import get_kernel_backend
-
-    tune_dec = tuning.flash_decision(
-        (b, t, h_local, d), k_shape[1], jnp.dtype(dtype).name,
-        bias=None if bias_shape is None else (
-            bias_shape, jnp.dtype(bias_dtype).name
-        ),
-        has_pad=has_pad, causal=causal, dropout_on=dropout_on,
-        allow_tune=True,
-    )
-    if tune_dec == "eager" and get_kernel_backend() != "pallas":
-        return False
-    return True
+    return use_pallas() and fa.eligible(qs, ks, bias_shape)
 
 
 def ulysses_attention(q, k, v, axis_name, bias=None, key_padding_mask=None,
@@ -131,9 +111,7 @@ def ulysses_attention(q, k, v, axis_name, bias=None, key_padding_mask=None,
 
     dropout_on = dropout_p > 0.0 and base_seed is not None
     if _flash_local_ok(
-        qh.shape, kh.shape, None if bias is None else bias.shape,
-        None if bias is None else bias.dtype,
-        key_padding_mask is not None, causal, dropout_on, qh.dtype,
+        qh.shape, kh.shape, None if bias is None else bias.shape
     ):
         from unicore_tpu.ops.pallas.flash_attention import flash_attention
 

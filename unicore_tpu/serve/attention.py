@@ -24,12 +24,11 @@ Two implementations:
   single decode token or a prefill chunk, both in the SAME program —
   DMAs that row's pages HBM -> VMEM and accumulates an online softmax
   per (head, query); the gathered ``[B, S, H, D]`` key tensor never
-  materializes.  Gated through ``ops/backend.py`` (``use_pallas``),
-  the kernel's static shape rule and the PR-2 autotuner (op
-  ``"ragged_paged_attention"``): an ``"eager"`` verdict for the bucket
-  routes around the kernel, a config dict picks its page block.  The
-  path each compiled width took is in ``backend.dispatch_report()``;
-  a kernel the chip's compiler refuses fails the step's compile.
+  materializes.  Gated through ``ops/backend.py`` (``use_pallas``)
+  and the kernel's static shape rule (``supported``); its page block is
+  ``pick_pages_per_block``'s, from shape.  The path each compiled width
+  took is in ``backend.dispatch_report()``; a kernel the chip's
+  compiler refuses fails the step's compile.
 """
 
 import dataclasses
@@ -117,32 +116,21 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, positions,
 
 def _kernel_ok(q, k_pages, page_table, page_size):
     """Pages per block when the Pallas ragged kernel takes this call,
-    else None: TPU backend, a shape the compiled kernel supports, and a
-    tuner verdict that is not "eager".  Both serve dispatch widths (the
-    pure-decode T=1 and the prefill-chunk T=C program) go through the
-    same gate — the bucket key carries the width."""
-    from unicore_tpu.ops.backend import (
-        get_kernel_backend, pallas_interpret, use_pallas,
-    )
+    else None: TPU backend and a shape the compiled kernel supports.
+    Both serve dispatch widths (the pure-decode T=1 and the
+    prefill-chunk T=C program) go through the same gate."""
+    from unicore_tpu.ops.backend import pallas_interpret, use_pallas
 
     if not use_pallas():
         return None
-    from unicore_tpu.ops import tuning
     from unicore_tpu.ops.pallas import paged_attention as pl_pa
 
     if not pallas_interpret() and not pl_pa.supported(
         q.shape[2], q.shape[3], page_size, k_pages.dtype.itemsize
     ):
         return None
-    decision = tuning.ragged_paged_decision(
-        q.shape, page_table.shape[1], page_size, q.dtype.name,
-        allow_tune=True,
-    )
-    if decision == "eager" and get_kernel_backend() != "pallas":
-        return None
     return pl_pa.pick_pages_per_block(
         page_table.shape[1], page_size, q.shape[3],
-        tuned=tuning.tuned_pages_per_block(page_table.shape[1], decision),
         num_heads=q.shape[2], itemsize=k_pages.dtype.itemsize,
     )
 

@@ -69,7 +69,7 @@ def make_parser():
                      help="ragged-step prefill chunk width: a prompt is "
                           "admitted in slices of at most this many "
                           "tokens per step (bounded TTFT under heavy "
-                          "admission; 0 = auto)")
+                          "admission; 0 = the engine's default, 32)")
     eng.add_argument("--prefix-cache", choices=("on", "off"),
                      default="on",
                      help="shared-prefix KV page dedup: a repeat of a "

@@ -255,16 +255,8 @@ class ServeEngine:
         )
         self.pages = self._init_pages()
         # prefill-chunk width: a prompt is admitted in <= this many
-        # tokens per ragged step (bounded-TTFT slices).  0 = auto: the
-        # default, unless the autotuner measured a chunked-admission
-        # candidate winning for this engine's bucket (the pool leaves
-        # carry the heads/head-dim the workload key needs)
-        chunk = int(prefill_chunk)
-        if not chunk:
-            chunk = DEFAULT_PREFILL_CHUNK
-            tuned = self._tuned_chunk(chunk)
-            if tuned:
-                chunk = tuned
+        # tokens per ragged step (bounded-TTFT slices).  0 = the default
+        chunk = int(prefill_chunk) or DEFAULT_PREFILL_CHUNK
         self.prefill_chunk = max(1, min(chunk, self.max_context))
         # tokens the mixed step carries: derived, never configured.  A
         # recurrent model's step is the rectangle; any other gets
@@ -367,27 +359,6 @@ class ServeEngine:
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes
         )
-
-    def _tuned_chunk(self, default_chunk):
-        """Measured prefill-chunk verdict for this engine's ragged-step
-        bucket (a ``{"prefill_chunk": c}`` candidate that beat the
-        full-width dispatch when the bucket was tuned).  Lookup-only
-        and fail-open: a missing cache, an unexpected pool layout, or
-        any tuner error just keeps the default."""
-        try:
-            from unicore_tpu.ops import tuning
-
-            leaf = jax.tree_util.tree_leaves(self.pages)[0]
-            heads = int(self.model.decoder_attention_heads)
-            return tuning.tuned_prefill_chunk(tuning.ragged_paged_decision(
-                (self.max_batch, default_chunk,
-                 heads, leaf.shape[1] // heads),
-                self.table_width, self.page_size, leaf.dtype.name,
-            ), default_chunk)
-        except Exception as e:  # noqa: BLE001 - fail open to the default
-            logger.debug("tuned prefill-chunk lookup failed (%s); "
-                         "using the default", e)
-            return None
 
     # -- the one jitted step -------------------------------------------
 

@@ -252,14 +252,6 @@ class Trainer:
 
         set_spmd_mesh(self.mesh)
 
-        # kernel autotuning mode, set BEFORE any step traces (decisions
-        # are consulted at trace time and memoized per process)
-        autotune = getattr(args, "kernel_autotune", None)
-        if autotune:
-            from unicore_tpu.ops import tuning
-
-            tuning.set_autotune_mode(autotune)
-
         rng_impl = getattr(args, "rng_impl", None)
         if rng_impl:
             # rbg cuts ~21ms/step off BERT-base on v5e (threefry random
