@@ -111,8 +111,8 @@ class HybridLMModel(BaseUnicoreModel):
             name="decoder",
         )(x, positions=positions, paged=paged)
         if paged is not None and paged.last_token is not None:
-            # an attention-only pattern is served a flat token list
-            # (serve/engine.py): the head runs on each row's last token
+            # a serve step's tokens are a flat list (serve/engine.py):
+            # the head runs on each row's last token
             x = jnp.take(x, paged.last_token, axis=1)
         return Linear(self.vocab_size, name="lm_head")(x)
 

@@ -1,9 +1,11 @@
-"""The serve step's token layout (ISSUE 28): a model without recurrent
-layers runs its dense layers on a FLAT list of the tokens the step
-carries (``max_batch`` at width 1, ``ServeEngine.mixed_tokens`` at the
-prefill width), sorts them into the ``[max_batch, width]`` rectangle
-only for attention, and runs its head on each row's last token; a
-recurrent model keeps the rectangle.  CPU, the eager attention path.
+"""The serve step's token layout (ISSUE 28, ISSUE 30): every model runs
+its dense layers on a FLAT list of the tokens the step carries
+(``max_batch`` at width 1, ``ServeEngine.mixed_tokens`` at the prefill
+width), sorts them into the ``[max_batch, width]`` rectangle only inside
+the mixers that need rows (attention, a recurrent layer's chain), and
+runs its head on each row's last token; a model that holds a recurrent
+state gets its rows' state slots as one more packed operand.  CPU, the
+eager attention path.
 
 The oracle is the one of ``test_serve.py``: whatever the step's layout,
 every request's tokens equal the full-forward decode of that request
@@ -346,7 +348,9 @@ def test_mixed_tokens_is_derived_from_chunk_and_rows(lm, monkeypatch):
     assert tokens(8, 32) == 8 * 32          # a small engine: the rectangle
 
 
-# -- (d) a recurrent model keeps the rectangle -------------------------------
+# -- (d) a recurrent model takes the same flat list --------------------------
+# the hybrid of tests/test_serve_hybrid.py: 4 rows x chunks of 16, so the
+# rectangle holds 64 tokens and (the fixture above) the list 32
 
 
 @pytest.fixture(scope="module")
@@ -356,35 +360,237 @@ def hybrid():
     return build()
 
 
-def test_a_recurrent_model_keeps_the_rectangle(hybrid):
+def hybrid_engine(hybrid, **kwargs):
     from tests import test_serve_hybrid as th
 
     model, params = hybrid
-    eng = ServeEngine(model, params, prefill_chunk=16, **th.POOL)
-    assert eng.recurrent and eng.mixed_tokens == eng.max_batch * 16
-    seen = []
+    eng = ServeEngine(model, params, prefill_chunk=16, **{**th.POOL, **kwargs})
+    assert eng.recurrent
+    assert (eng.max_batch * 16, eng.mixed_tokens) == (64, 32)
+    return eng
+
+
+def reference_tokens(params, prompt, tokens):
+    from tests import test_serve_hybrid as th
+
+    want = th.reference_logits(params, prompt + tokens)
+    return np.argmax(want[len(prompt) - 1:-1], -1).tolist()
+
+
+PARENT_OPERANDS = ["tokens", "positions", "page_table", "slot_mapping",
+                   "lengths", "last", "seeds", "steps", "temperature",
+                   "top_k"]
+
+
+def test_a_recurrent_model_takes_one_packed_operand_and_returns_one_array(
+        hybrid):
+    from tests import test_serve_hybrid as th
+
+    eng = hybrid_engine(hybrid, poison_requests=["r1"])
+    B, W, N = eng.max_batch, eng.table_width, eng.mixed_tokens
+    names = {w: [n for n, _ in eng._step_operands(w)] for w in (1, 16)}
+    assert names[1] == PARENT_OPERANDS + ["poison", "state_slots"]
+    assert names[16] == names[1] + ["rect_token", "token_cell"]
+    seen, outs = [], []
     eng._input_capture = lambda key, args: seen.append(
-        (key[0], [None if a is None else tuple(a.shape) for a in args[2:]]))
-    plans = record_plans(eng)
+        (key[0], [tuple(a.shape) for a in args[2:]]))
+    real = eng._ragged_step_fn
+
+    def spying(width, sampling):
+        fn = real(width, sampling)
+
+        def call(*args):
+            out = fn(*args)
+            outs.append(out)
+            return out
+
+        return call
+
+    eng._ragged_step_fn = spying
     rng = np.random.default_rng(2)
-    prompts = [th.prompt_of(rng, n) for n in (40, 9)]
-    res = eng.generate([Request(prompt=p, max_new_tokens=3) for p in prompts])
-    B, W = eng.max_batch, eng.table_width
-    for width, shapes in seen:
-        # tokens, positions [B, w]; ...; state_slots [B], each an argument
-        # of its own: no flat list, no map to a rectangle, nothing packed
-        assert shapes == [(B, width), (B, width), (B, W), (B * width,)] \
-            + [(B,)] * 7
+    prompts = [th.prompt_of(rng, n) for n in (40, 9, 21)]
+    res = eng.generate([Request(prompt=p, max_new_tokens=3,
+                                request_id=f"r{i}")
+                        for i, p in enumerate(prompts)])
+    # ONE operand beside weights and pool, whatever the width: tokens,
+    # positions, slot_mapping (and token_cell) over the list, the table,
+    # eight [B] entries with poison and state_slots, the map [B, 16]
     assert {w for w, _ in seen} == {1, 16}
-    for plan in plans:  # one row a sequence a dispatch
+    for width, shapes in seen:
+        assert shapes == [(3 * B + B * W + 8 * B,) if width == 1 else
+                          (4 * N + B * W + 8 * B + B * 16,)]
+    # ... and ONE array back beside the pool: a token a row, -1 where the
+    # row's logits were not finite
+    assert all(len(out) == 2 and out[0].shape == (B,)
+               and out[0].dtype == jnp.int32 for out in outs)
+    assert sum(int((np.asarray(out[0]) < 0).sum()) for out in outs) == 1
+    assert res[1].finish_reason == "failed" and res[1].tokens == []
+    assert eng.stats["quarantined"] == 1
+    for i in (0, 2):
+        assert res[i].tokens == reference_tokens(
+            hybrid[1], prompts[i], res[i].tokens)
+    eng.pool.check_invariants()
+    assert eng.pool.is_idle()
+
+
+def test_a_recurrent_models_dense_layers_see_the_list_and_its_head_rows(
+        hybrid):
+    eng = hybrid_engine(hybrid)
+    B, N = eng.max_batch, eng.mixed_tokens
+    arts = eng.trace_step_fns()
+    assert sorted(arts) == ["ragged-w1", "ragged-w16"]
+    # q/k/v/b/a/g/o of a linear layer (6 of them), q/k/v/o of a full one
+    # (2), three of every FFN, the head; the rule's own contractions are
+    # batched over rows and heads and left out
+    for name, n in (("ragged-w16", N), ("ragged-w1", B)):
+        dense = dense_tokens(arts[name]["jaxpr"])
+        assert len(dense) == 6 * 7 + 2 * 4 + 8 * 3 + 1
+        assert dense[-1][0] == B                  # the head: a row a token
+        assert {t for t, _ in dense[:-1]} == {n}, (name, dense)
+    # nothing dense is left on the max_batch x width rectangle
+    assert B * 16 not in {t for t, _ in dense_tokens(
+        arts["ragged-w16"]["jaxpr"])}
+
+
+def test_a_recurrent_model_serves_the_reference_tokens_from_the_list(hybrid):
+    """Prompts of several chunks beside decode rows, a chunk cut by the
+    token budget, a row starting at position 0 beside a row continuing,
+    and empty rows: every request's tokens are the plain reference's."""
+    from tests import test_serve_hybrid as th
+
+    eng = hybrid_engine(hybrid)
+    plans = record_plans(eng)
+    rng = np.random.default_rng(30)
+    prompts = {"a": th.prompt_of(rng, 5), "b": th.prompt_of(rng, 40),
+               "c": th.prompt_of(rng, 20), "d": th.prompt_of(rng, 19)}
+    new = {"a": 14, "b": 4, "c": 5, "d": 3}
+
+    def ask(*ids):
+        eng.submit([Request(prompt=prompts[i], max_new_tokens=new[i],
+                            request_id=i) for i in ids])
+
+    ask("a")
+    eng.serve_step()
+    eng.serve_step()                       # "a" decodes from here on
+    ask("b", "c")
+    eng.serve_step()
+    eng.pool.check_invariants()
+    # one token of the 32 is a's: b takes a chunk, c is cut to 15
+    assert [(s, m, d) for _, s, m, d in plans[-1]] == [
+        (len(prompts["a"]) + 1, 1, True), (0, 16, False), (0, 15, False)]
+    eng.serve_step()
+    assert [(s, m) for _, s, m, d in plans[-1] if not d] == [(16, 16), (15, 5)]
+    ask("d")                               # starts at 0 beside b continuing
+    eng.serve_step()
+    starts = [s for _, s, m, d in plans[-1] if not d]
+    assert 0 in starts and any(s > 0 for s in starts), plans[-1]
+    while eng.serve_step():
+        eng.pool.check_invariants()
+    done = {r.request_id: r for r in eng.collect_finished()}
+    assert any(len(p) < eng.max_batch for p in plans)      # empty rows
+    for plan in plans:                     # one row a sequence a dispatch
         assert len({sid for sid, *_ in plan}) == len(plan)
-    for p, r in zip(prompts, res):
-        want = th.reference_logits(params, p + r.tokens)
-        assert r.tokens == np.argmax(want[len(p) - 1:-1], -1).tolist()
-    # the rectangle's program does run its dense layers on B x w columns:
-    # the check of (b) sees the difference
-    dense = dense_tokens(eng.trace_step_fns(widths=(16,))["ragged-w16"]["jaxpr"])
-    assert B * 16 in {t for t, _ in dense}
+        assert sum(m for _, _, m, _ in plan) <= eng.mixed_tokens
+    for i, prompt in prompts.items():
+        assert len(done[i].tokens) == new[i]
+        assert done[i].tokens == reference_tokens(
+            hybrid[1], prompt, done[i].tokens), i
+    st = eng.stats
+    assert st["state_resets"] == 4 and st["quarantined"] == 0
+    assert st["mixed_tokens_capacity"] == st["mixed_steps"] * 32
+    assert eng.pool.is_idle()
+
+
+def test_linear_attention_mixer_rows_as_rectangle_or_as_list_are_the_same():
+    """The mixer alone at a toy width: a fresh chunk, a continuing chunk,
+    a decode row and an empty row, handed once as the ``[rows, width]``
+    rectangle and once as a flat list with its maps, give the same
+    outputs, states and tails."""
+    from unicore_tpu.modules.pattern_decoder import LinearAttentionMixer
+    from unicore_tpu.serve.attention import PagedMeta
+
+    Dm, Hh, dk, dv, K = 32, 2, 8, 16, 4
+    rows, width, slots_n, N = 4, 8, 5, 20
+    mixer = LinearAttentionMixer(Dm, Hh, dk, dv, K)
+    rng = np.random.default_rng(4)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    # (first position, tokens) of each row; row 3 is empty
+    spans = [(0, 8), (11, 5), (7, 1), None]
+    slots = jnp.asarray([2, 0, 3, slots_n + 3], jnp.int32)
+    positions = np.full((rows, width), -1, np.int32)
+    rect_token = np.full((rows, width), N, np.int32)
+    token_cell = np.zeros(N, np.int32)
+    flat_positions = np.full(N, -1, np.int32)
+    x_rect = np.zeros((rows, width, Dm), np.float32)
+    x_flat = np.asarray(f(N, Dm))          # tokens nobody carries: noise
+    at = 0
+    for b, span in enumerate(spans):
+        if span is None:
+            continue
+        start, m = span
+        positions[b, :m] = start + np.arange(m)
+        rect_token[b, :m] = at + np.arange(m)
+        token_cell[at:at + m] = b * width + np.arange(m)
+        flat_positions[at:at + m] = positions[b, :m]
+        x_rect[b, :m] = x_flat[at:at + m]
+        at += m
+
+    def meta(**maps):
+        return PagedMeta(
+            page_table=jnp.zeros((rows, 1), jnp.int32),
+            slot_mapping=jnp.zeros((1,), jnp.int32),
+            lengths=jnp.zeros((rows,), jnp.int32), page_size=4,
+            state_slots=slots, num_state_slots=slots_n, **maps)
+
+    init = mixer.init(jax.random.PRNGKey(0), jnp.zeros((1, 2, Dm)),
+                      paged=meta())
+    params = jax.tree_util.tree_map(lambda p: f(*p.shape) * 0.3,
+                                    init["params"])
+    store = {"ssm_state": f(slots_n, Hh, dk, dv),
+             "conv_tail": f(slots_n, K - 1, 2 * Hh * dk + Hh * dv)}
+
+    def run(x, pos, **maps):
+        return mixer.apply({"params": params, "pagedkv": store}, x,
+                           positions=jnp.asarray(pos), paged=meta(**maps),
+                           mutable=["pagedkv"])
+
+    out_rect, kept_rect = run(jnp.asarray(x_rect), positions)
+    out_flat, kept_flat = run(
+        jnp.asarray(x_flat)[None], flat_positions[None],
+        rect_token=jnp.asarray(rect_token),
+        rect_positions=jnp.asarray(positions),
+        token_cell=jnp.asarray(token_cell))
+    assert out_rect.shape == (rows, width, Dm)
+    assert out_flat.shape == (1, N, Dm)
+    assert bool(jnp.isfinite(out_flat).all())
+    at = 0
+    for b, span in enumerate(spans):
+        if span is None:
+            continue
+        m = span[1]
+        np.testing.assert_allclose(out_flat[0, at:at + m], out_rect[b, :m],
+                                   atol=1e-5)
+        at += m
+    for name in ("ssm_state", "conv_tail"):
+        np.testing.assert_allclose(kept_flat["pagedkv"][name],
+                                   kept_rect["pagedkv"][name], atol=1e-6)
+    # the store moved where a row wrote and nowhere else: slot 2 from
+    # zeros, 0 and 3 from what they held, 1 and 4 untouched
+    after, before = kept_flat["pagedkv"]["ssm_state"], store["ssm_state"]
+    for slot, moved in enumerate([True, False, True, True, False]):
+        assert bool(jnp.any(after[slot] != before[slot])) == moved, slot
+
+
+def test_a_model_without_a_state_packs_no_state_slots(lm):
+    model, params = lm
+    eng = ServeEngine(model, params, **ENGINE)
+    assert not eng.recurrent
+    assert [n for n, _ in eng._step_operands(1)] == PARENT_OPERANDS
+    assert [n for n, _ in eng._step_operands(8)] == PARENT_OPERANDS + [
+        "rect_token", "token_cell"]
+    poisoned = ServeEngine(model, params, poison_requests=["x"], **ENGINE)
+    assert [n for n, _ in poisoned._step_operands(8)] == PARENT_OPERANDS + [
+        "poison", "rect_token", "token_cell"]
 
 
 # -- (f) the engine owns the last-token contract ----------------------------

@@ -13,19 +13,27 @@ serve tier's ``"pagedkv"`` collection, both addressed through
 
 - ``full_attention``: K/V pages, one slot per token (``k_pages`` /
   ``v_pages``, ``[num_slots, H * D]``), written at ``slot_mapping`` and
-  read through the page table by the ragged paged attention op.  QK-norm
-  over the whole projection, no rotary.
+  read through the page table by the ragged paged attention op, which
+  sorts the step's flat token list into its ``[rows, width]`` rectangle
+  (``serve/attention.py`` ``write_and_attend``).  QK-norm over the whole
+  projection, no rotary.
 - ``linear_attention``: one fixed-size recurrent state per SEQUENCE
   (``ssm_state`` ``[num_state_slots, H, dk, dv]`` float32) and the short
   convolution's tail (``conv_tail`` ``[num_state_slots, K - 1, channels]``),
   gathered by ``state_slots`` for the rows of a step and scattered back
-  in the same program.  A row whose first column is position 0 starts
-  from zeros, so a slot needs no host-side clearing; a padded column
-  (position -1) changes neither state nor tail, and an empty row's slot
-  is out of range, so its write is dropped.
+  in the same program.  The projections, the output norm and its gate
+  run on the tokens as they come (a serve step's flat list, ``[1, N,
+  ...]``); only the chain (short convolution, the rule) gets rows: with
+  ``paged.rect_token`` its inputs are gathered into ``[rows, width]``
+  and its output goes back by ``paged.token_cell``, and when the tokens
+  ARE the rectangle (the decode step's one token a row) they are only
+  reshaped.  A row whose first column is position 0 starts from zeros,
+  so a slot needs no host-side clearing; a padded column (position -1,
+  and every cell no token fills) changes neither state nor tail, and an
+  empty row's slot is out of range, so its write is dropped.
 
 Without ``paged`` a call is one full causal pass from zero state (init,
-training-style forwards, tests).
+training-style forwards, tests): ``[B, T]`` is the rectangle.
 """
 
 from typing import Optional, Tuple
@@ -140,7 +148,7 @@ class LinearAttentionMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions=None, paged=None):
-        B, T, _ = x.shape
+        lead = x.shape[:-1]
         H, dk, dv = self.num_heads, self.key_head_dim, self.value_head_dim
         K = self.conv_kernel_dim
         nk, nv = H * dk, H * dv
@@ -167,6 +175,11 @@ class LinearAttentionMixer(nn.Module):
             tails = self.variable("pagedkv", "conv_tail", jnp.zeros,
                                   (nstate, K - 1, 2 * nk + nv), u.dtype)
         if ready:
+            # the chain is the one part that needs rows (module
+            # docstring); position -1 keeps a cell no token fills out of
+            # state, tail and every real column's output
+            u, g, beta = (paged.to_rows(t) for t in (u, g, beta))
+            positions = paged.row_positions(positions)
             slots = paged.state_slots
             real = positions >= 0                             # [B, T]
             fresh = positions[:, 0] == 0
@@ -180,9 +193,10 @@ class LinearAttentionMixer(nn.Module):
             g = jnp.where(real[..., None], g, 0.0)
             beta = jnp.where(real[..., None], beta, 0.0)
         else:
-            state = jnp.zeros((B, H, dk, dv), jnp.float32)
-            tail = jnp.zeros((B, K - 1, 2 * nk + nv), u.dtype)
-            valid = jnp.full((B,), T, jnp.int32)
+            state = jnp.zeros(u.shape[:1] + (H, dk, dv), jnp.float32)
+            tail = jnp.zeros(u.shape[:1] + (K - 1, 2 * nk + nv), u.dtype)
+            valid = jnp.full(u.shape[:1], u.shape[1], jnp.int32)
+        B, T = u.shape[:2]
 
         u, tail = short_conv(u, conv_kernel, tail, valid)
         u = jax.nn.silu(u)
@@ -200,11 +214,12 @@ class LinearAttentionMixer(nn.Module):
                 state, mode="drop", unique_indices=True)
             tails.value = tails.value.at[slots].set(
                 tail, mode="drop", unique_indices=True)
+            o = paged.to_tokens(o, lead)
 
         o = RMSNorm(dv, self.eps, name="o_norm")(o.astype(x.dtype))
         gate = jax.nn.silu(Linear(nv, name="g_proj")(x))
         return Linear(self.embed_dim, name="o_proj")(
-            o.reshape(B, T, nv) * gate)
+            o.reshape(lead + (nv,)) * gate)
 
 
 class PatternDecoderLayer(nn.Module):

@@ -50,10 +50,12 @@ class PagedMeta:
     ``page_size``/``num_slots`` are static Python ints (``num_slots``
     sizes the pool variables at flax init and is ignored afterwards).
 
-    The step's tokens may come as the kernel's ``[B, width]`` rectangle
-    itself (N = B * width, row-major: the decode step, and every step of
-    a recurrent model) or as a FLAT list of N < B * width tokens, each
-    row's tokens consecutive.  The flat list brings its map both ways:
+    The step's tokens may come as the ``[B, width]`` rectangle itself
+    (N = B * width, row-major: the decode step, where token b is row b)
+    or as a FLAT list of N <= B * width tokens, each row's tokens
+    consecutive.  The flat list brings its map both ways, for the mixers
+    that need rows (the ragged kernel here, a recurrent layer's chain in
+    ``modules/pattern_decoder.py``):
     ``rect_token`` [B, width] int32, the flat token in each cell of the
     rectangle (N for a cell no token fills), ``rect_positions``
     [B, width] int32, that token's position (-1 for such a cell), and
@@ -62,7 +64,8 @@ class PagedMeta:
     token each row samples from: the model then returns logits for
     those B tokens only.
 
-    A model with recurrent layers also gets ``state_slots`` [B] int32:
+    A model with recurrent layers also gets ``state_slots`` [B] int32
+    (the layout of its tokens is the one above, like any other model's):
     the state-store slot of each row's SEQUENCE (a row is assigned anew
     every step, the state is not), out of range for an empty row so that
     its write is dropped; ``num_state_slots`` sizes the store at init."""
@@ -78,6 +81,37 @@ class PagedMeta:
     rect_positions: Any = None
     token_cell: Any = None
     last_token: Any = None
+
+    # the two mixers that need rows (attention's kernel, a recurrent
+    # layer's chain) go through these three; everything else runs on the
+    # tokens as they come
+
+    def to_rows(self, x):
+        """``x`` [*lead, ...] over the step's tokens (``lead`` two
+        dimensions: [1, N] or the rectangle's own) in the ``[B, width,
+        ...]`` rectangle: a gather by ``rect_token`` for a flat list (a
+        cell no token fills reads some token's value: ``row_positions``
+        says -1 there), a reshape when the tokens are the rectangle."""
+        if self.rect_token is None:
+            return x.reshape((self.page_table.shape[0], -1) + x.shape[2:])
+        return jnp.take(x.reshape((-1,) + x.shape[2:]), self.rect_token,
+                        axis=0, mode="clip")
+
+    def row_positions(self, positions):
+        """The tokens' ``positions`` in the rectangle, -1 for a cell no
+        token fills."""
+        if self.rect_token is None:
+            return positions.reshape(self.page_table.shape[0], -1)
+        return self.rect_positions
+
+    def to_tokens(self, x, lead):
+        """The way back: ``x`` [B, width, ...] in the tokens' own layout
+        ``[*lead, ...]``."""
+        feat = x.shape[2:]
+        if self.rect_token is not None:
+            x = jnp.take(x.reshape((-1,) + feat), self.token_cell, axis=0,
+                         mode="clip")
+        return x.reshape(lead + feat)
 
 
 def gather_slots(pages, page_table, page_size):
@@ -165,31 +199,18 @@ def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
     attends the pages its table names.  The scatter lands before the
     gather, so a row sees the keys the same program wrote.
 
-    Attention is the one place that needs rows: ``q`` goes into the
-    ``[B, width]`` rectangle the ragged kernel takes (a gather by
-    ``paged.rect_token`` for a flat list, a reshape when the tokens are
-    the rectangle already) and the output comes back in ``q``'s own
-    layout."""
+    Attention needs rows: ``q`` goes into the ``[B, width]`` rectangle
+    the ragged kernel takes (``paged.to_rows``; position -1 masks a cell
+    no token fills) and the output comes back in ``q``'s own layout."""
     width = k_pages.value.shape[-1]
     k_pages.value = k_pages.value.at[paged.slot_mapping].set(
         k.astype(k_pages.value.dtype).reshape(-1, width))
     v_pages.value = v_pages.value.at[paged.slot_mapping].set(
         v.astype(v_pages.value.dtype).reshape(-1, width))
-    lead, rows = q.shape[:-2], paged.page_table.shape[0]
-    if paged.rect_token is None:
-        q = q.reshape((rows, -1) + q.shape[-2:])
-        positions = positions.reshape(rows, -1)
-    else:
-        # a cell no token fills reads some token's q: position -1 masks it
-        q = jnp.take(q.reshape((-1,) + q.shape[-2:]), paged.rect_token,
-                     axis=0, mode="clip")
-        positions = paged.rect_positions
     o = paged_attention(
-        q, k_pages.value, v_pages.value,
-        page_table=paged.page_table, positions=positions,
+        paged.to_rows(q), k_pages.value, v_pages.value,
+        page_table=paged.page_table,
+        positions=paged.row_positions(positions),
         lengths=paged.lengths, page_size=paged.page_size, scale=scale,
     )
-    if paged.rect_token is not None:
-        o = jnp.take(o.reshape((-1,) + o.shape[-2:]), paged.token_cell,
-                     axis=0, mode="clip")
-    return o.reshape(lead + o.shape[-2:])
+    return paged.to_tokens(o, q.shape[:2])

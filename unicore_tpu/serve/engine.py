@@ -16,10 +16,11 @@ count stays constant over every prompt length.
 The step's tokens are a FLAT list of one fixed size per program:
 ``max_batch`` tokens at width 1 (the ``[max_batch, 1]`` rectangle is the
 list) and :attr:`ServeEngine.mixed_tokens` at the prefill width, 512
-tokens whatever ``max_batch`` is (a small engine: its rectangle).  Embedding, LayerNorms,
-projections and FFNs run on the tokens the step carries; only attention
-sorts them into the ``[max_batch, width]`` rectangle the ragged kernel
-takes, and the head runs on each row's last token, ``max_batch`` rows.
+tokens whatever ``max_batch`` is (a small engine: its rectangle).  Embedding, norms,
+projections and FFNs run on the tokens the step carries; only the mixers
+that need rows sort them into the ``[max_batch, width]`` rectangle
+(attention for the ragged kernel, a recurrent layer for its chain), and
+the head runs on each row's last token, ``max_batch`` rows.
 Pool buffers are DONATED through every step — after warmup nothing
 reallocates — and sampling (greedy/temperature/top-k, seeded per
 request) runs inside the step, so only the [B] sampled token ids cross
@@ -37,14 +38,14 @@ fixed-size state per sequence, in the same donated ``pagedkv`` tree as
 the pages.  The pool hands each resident sequence a state slot with its
 pages and takes it back with them; a step gathers the slots of its rows,
 runs, and scatters them back inside the one jitted program, zeroing a
-state whose row starts at position 0.  Three rules follow from the state
-being a chain: such a model gets ONE row per sequence per dispatch
-(chunk k needs the state chunk k-1 leaves, so consecutive chunks of one
-prompt cannot share a program the way K/V scatters do), its recurrence
-walks the ``[max_batch, width]`` rectangle itself, so its step keeps the
-rectangle as the layout of its tokens and returns logits for every
-column, and prefix hits are refused (a hit starts a prompt past its
-shared pages, where no state exists).
+state whose row starts at position 0.  The step is the one every model
+gets: the slots are one more entry of its packed operands, and the
+recurrence gathers its rows out of the flat list inside the layer.  Two
+rules follow from the state being a chain: such a model gets ONE row per
+sequence per dispatch (chunk k needs the state chunk k-1 leaves, so
+consecutive chunks of one prompt cannot share a program the way K/V
+scatters do), and prefix hits are refused (a hit starts a prompt past
+its shared pages, where no state exists).
 
 Metrics: per-request queue wait and TTFT, and the counters of
 :attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
@@ -67,13 +68,12 @@ annotation costs well under a microsecond::
         serve/state           a recurrent model's rows: each row's state slot
                               looked up, rows starting from zero counted
       serve/transfer          the step's operands onto the device: one
-                              packed vector (a recurrent model: one
-                              transfer an operand)
+                              packed vector
       serve/dispatch-w<n>     the compiled step at width n, until the
                               sampled tokens are on the host
         serve/launch          the compiled call returning
-        serve/fetch           the sampled tokens (and a recurrent model's
-                              row flags) to the host
+        serve/fetch           the sampled tokens to the host (-1: a row
+                              of nonfinite logits)
       serve/emit              counters, quarantine, prefill watermark,
                               register_prefix, _emit
 
@@ -192,12 +192,13 @@ class StepCompileError(RuntimeError):
 
 
 DEFAULT_PREFILL_CHUNK = 32
-# the mixed step's token list, in TOKENS whatever the chunk: the best of
-# 256 / 512 / 1024 on the chip in both opt_1.3b cells (float32 weights,
-# chunk 128, one v5e: PERF.md, PR 28), near where such weights turn from
-# weight-bound to compute-bound (197e12 / 819e9 FLOP a byte at 2 FLOP a
-# weight a token and 4 bytes a weight: ~480).  Not read for another
-# dtype or chip
+# the mixed step's token list, in TOKENS whatever the chunk and whatever
+# the model: the best of 256 / 512 / 1024 on the chip in both opt_1.3b
+# cells (float32 weights, chunk 128, one v5e: PERF.md, PR 28), near where
+# such weights turn from weight-bound to compute-bound (197e12 / 819e9
+# FLOP a byte at 2 FLOP a weight a token and 4 bytes a weight: ~480).
+# Not read for another dtype or chip; the hybrid's three-pass matmuls
+# turn at ~160, and its readings at 256 / 384 / 512 are in PERF.md, PR 30
 MIXED_STEP_TOKENS = 512
 
 
@@ -258,15 +259,14 @@ class ServeEngine:
         # tokens per ragged step (bounded-TTFT slices).  0 = the default
         chunk = int(prefill_chunk) or DEFAULT_PREFILL_CHUNK
         self.prefill_chunk = max(1, min(chunk, self.max_context))
-        # tokens the mixed step carries: derived, never configured.  A
-        # recurrent model's step is the rectangle; any other gets
+        # tokens the mixed step carries: derived, never configured.
         # MIXED_STEP_TOKENS, at least a chunk beside a token for every
         # row (so more tokens than rows: the step tells a model's
         # all-token logits from its last-token ones by that), at most
         # the rectangle
-        rect = self.max_batch * self.prefill_chunk
-        self.mixed_tokens = rect if self.recurrent else min(rect, max(
-            MIXED_STEP_TOKENS, self.max_batch + self.prefill_chunk))
+        self.mixed_tokens = min(
+            self.max_batch * self.prefill_chunk,
+            max(MIXED_STEP_TOKENS, self.max_batch + self.prefill_chunk))
         # the chunk-size -> compiled-width map, overridable so the
         # static audit (analysis/hlo_audit.py UL205) can check that it
         # never produces a lowering outside serve_step_widths()
@@ -410,13 +410,13 @@ class ServeEngine:
 
     def _step_operands(self, width):
         """``[(name, shape), ...]`` of the per-dispatch operands of the
-        step program at ``width``, in the order a recurrent model's step
-        takes them as arguments and every other step finds them in its
-        one packed vector.  All are 32 bits wide: ``temperature`` is
-        float32, ``poison`` a flag, the rest int32."""
+        step program at ``width``, in the order the step finds them in
+        its one packed vector.  All are 32 bits wide: ``temperature`` is
+        float32, ``poison`` a flag, the rest int32.  A model that holds
+        a recurrent state gets one operand more, its rows' state
+        slots."""
         B, n = self.max_batch, self._step_tokens(width)
-        lead = (B, width) if self.recurrent else (1, n)
-        ops = [("tokens", lead), ("positions", lead),
+        ops = [("tokens", (1, n)), ("positions", (1, n)),
                ("page_table", (B, self.table_width)),
                ("slot_mapping", (n,)), ("lengths", (B,)), ("last", (B,)),
                ("seeds", (B,)), ("steps", (B,)), ("temperature", (B,)),
@@ -425,7 +425,7 @@ class ServeEngine:
             ops.append(("poison", (B,)))
         if self.recurrent:
             ops.append(("state_slots", (B,)))
-        elif width > 1:
+        if width > 1:
             ops += [("rect_token", (B, width)), ("token_cell", (n,))]
         return ops
 
@@ -478,19 +478,14 @@ class ServeEngine:
         int32 vector, ``_step_operands`` end to end (one transfer a
         step, not one an operand), and are cut apart here; the sampled
         tokens go back as one too, -1 where a row's logits were not
-        finite.
-
-        A recurrent model's step keeps the rectangle, its operands
-        apart as arguments and the finite-row flags apart from the
-        tokens: ``tokens`` / ``positions`` [max_batch, width], logits
-        for every column, ``last`` the column each row samples from."""
+        finite."""
         key = (width, sampling)
         fn = self._step_fns.get(key)
         if fn is None:
             model, page_size = self.model, self.page_size
             operands = self._step_operands(width)
 
-            def forward(params, pages, o, last_token):
+            def forward(params, pages, o):
                 rect_token = o.get("rect_token")
                 meta = PagedMeta(
                     page_table=o["page_table"],
@@ -500,7 +495,7 @@ class ServeEngine:
                     rect_positions=None if rect_token is None else jnp.take(
                         o["positions"][0], rect_token, mode="fill",
                         fill_value=-1),
-                    token_cell=o.get("token_cell"), last_token=last_token,
+                    token_cell=o.get("token_cell"), last_token=o["last"],
                 )
                 logits, mutated = model.apply(
                     {"params": params, "pagedkv": pages}, o["tokens"],
@@ -521,26 +516,17 @@ class ServeEngine:
                     o["top_k"], sampling
                 ), ok
 
-            if self.recurrent:
-                def step(params, pages, *args):
-                    o = {name: x for (name, _), x in zip(operands, args)}
-                    logits, pages = forward(params, pages, o, None)
-                    toks, ok = sample(jnp.take_along_axis(
-                        logits, o["last"][:, None, None], axis=1
-                    )[:, 0], o)
-                    return toks, ok, pages
-            else:
-                def step(params, pages, packed):
-                    o = self._cut(packed, operands)
-                    o["temperature"] = jax.lax.bitcast_convert_type(
-                        o["temperature"], jnp.float32)
-                    if "poison" in o:
-                        o["poison"] = o["poison"] != 0
-                    logits, pages = forward(params, pages, o, o["last"])
-                    toks, ok = sample(self._last_token_rows(
-                        logits, o["last"], width), o)
-                    # one array to fetch: -1 for a row of nonfinite logits
-                    return jnp.where(ok, toks, -1), pages
+            def step(params, pages, packed):
+                o = self._cut(packed, operands)
+                o["temperature"] = jax.lax.bitcast_convert_type(
+                    o["temperature"], jnp.float32)
+                if "poison" in o:
+                    o["poison"] = o["poison"] != 0
+                logits, pages = forward(params, pages, o)
+                toks, ok = sample(self._last_token_rows(
+                    logits, o["last"], width), o)
+                # one array to fetch: -1 for a row of nonfinite logits
+                return jnp.where(ok, toks, -1), pages
 
             fn = self._step_fns[key] = jax.jit(
                 step, donate_argnums=(1,)
@@ -567,21 +553,14 @@ class ServeEngine:
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree
             )
 
-        dtypes = {"temperature": jnp.float32, "poison": jnp.bool_}
         params, pages = sds(self.params), sds(self.pages)
         arts = {}
         widths = self.serve_step_widths() if widths is None else widths
         for w in widths:
-            operands = self._step_operands(w)
-            if self.recurrent:
-                args = [jax.ShapeDtypeStruct(
-                    shape, dtypes.get(name, jnp.int32))
-                    for name, shape in operands]
-            else:
-                args = [jax.ShapeDtypeStruct(
-                    (self._packed_size(operands),), jnp.int32)]
+            packed = jax.ShapeDtypeStruct(
+                (self._packed_size(self._step_operands(w)),), jnp.int32)
             traced = self._ragged_step_fn(w, sampling).trace(
-                params, pages, *args)
+                params, pages, packed)
             arts[f"ragged-w{w}"] = {
                 "jaxpr": traced.jaxpr, "lowered": traced.lower(),
             }
@@ -695,12 +674,12 @@ class ServeEngine:
             B = self.max_batch
             w = self.width_fn(max(m for _, _, m, _, _ in rows))
             assert all(m <= w for _, _, m, _, _ in rows), (rows, w)
-            # the step's token list: the [B, w] rectangle itself (row b
-            # at b * w) for the decode step and a recurrent model, else
-            # the rows' tokens end to end with the map to the rectangle
+            # the step's token list: the rows' tokens end to end, with
+            # the map to the [B, w] rectangle where that is another
+            # thing (at width 1 token b is row b)
             N = self._step_tokens(w)
-            # every operand is a view of one buffer: what a step without
-            # recurrent layers is handed whole
+            # every operand is a view of one buffer: what the step is
+            # handed whole
             operands = self._step_operands(w)
             packed = np.zeros(self._packed_size(operands), np.int32)
             o = self._cut(packed, operands)
@@ -721,8 +700,7 @@ class ServeEngine:
             for seq, start, m, emit, dec in rows:
                 if seq.done:
                     continue  # failed through an earlier row this step
-                b = len(live)
-                at = carried if flat else b * w
+                b, at = len(live), carried
                 mine = slice(at, at + m)
                 try:
                     prefix = seq.prefix()
@@ -748,9 +726,8 @@ class ServeEngine:
                         o["rect_token"][b, :m] = np.arange(at, at + m)
                         o["token_cell"][mine] = b * w + np.arange(m)
                     o["lengths"][b] = start + m
-                    # the token the row samples from: a column of the
-                    # rectangle's logits, or a token of the flat list
-                    o["last"][b] = m - 1 if self.recurrent else at + m - 1
+                    # the token of the list the row samples from
+                    o["last"][b] = at + m - 1
                     o["temperature"][b] = seq.req.temperature
                     o["top_k"][b] = seq.req.top_k
                     o["seeds"][b] = seq.req.seed
@@ -783,13 +760,7 @@ class ServeEngine:
             return
         sampling = self._sampling_mode([r[0] for r in rows])
         with _span(SPAN_TRANSFER):
-            if self.recurrent:
-                if self._chaos_poison:
-                    o["poison"] = o["poison"].astype(bool)
-                args = [self.params, self.pages,
-                        *(jnp.asarray(o[name]) for name, _ in operands)]
-            else:
-                args = [self.params, self.pages, jnp.asarray(packed)]
+            args = [self.params, self.pages, jnp.asarray(packed)]
         any_decode = any(r[4] for r in rows)
         if self._input_capture is not None:
             # determinism-harness capture: before the call — the jit
@@ -801,7 +772,7 @@ class ServeEngine:
         with _span(_dispatch_span(w)), self._armed(f"serve/ragged-w{w}"):
             with _span(SPAN_LAUNCH):
                 try:
-                    *out, self.pages = step_fn(*args)
+                    out, self.pages = step_fn(*args)
                 except Exception as exc:
                     if (w, sampling) in self._step_ran:
                         raise
@@ -812,10 +783,9 @@ class ServeEngine:
                 self._step_ran.add((w, sampling))
             with _span(SPAN_FETCH):
                 # host sync: the scheduler needs the tokens, and which
-                # rows sampled from finite logits (a recurrent model's
-                # step says so apart, any other by a token of -1)
-                toks = np.asarray(out[0])
-                ok = np.asarray(out[1]) if self.recurrent else toks >= 0
+                # rows sampled from finite logits (a token of -1: not)
+                toks = np.asarray(out)
+                ok = toks >= 0
         dt = time.perf_counter() - t0
         with _span(SPAN_EMIT):
             self.stats["prefills"] += sum(1 for r in rows if not r[4])
