@@ -21,13 +21,14 @@ from unicore_tpu.models import (
     register_model_architecture,
 )
 from unicore_tpu.modules import PatternDecoder, bert_init
-from unicore_tpu.modules.pattern_decoder import FULL, LINEAR, Linear
+from unicore_tpu.modules.pattern_decoder import CONV, FULL, LINEAR, Linear
 
 
 def parse_layer_types(text):
     """``"lllf"`` or ``"linear_attention,full_attention"`` -> a tuple of
-    mixer kinds; a short pattern repeats to ``--decoder-layers``."""
-    short = {"l": LINEAR, "f": FULL}
+    mixer kinds (``c``: the gated short convolution of ``lfm2_moe_lm``);
+    a short pattern repeats to ``--decoder-layers``."""
+    short = {"l": LINEAR, "f": FULL, "c": CONV}
     parts = ([p.strip() for p in text.split(",")] if "," in text or "_" in text
              else list(text.strip()))
     return tuple(short.get(p, p) for p in parts if p)

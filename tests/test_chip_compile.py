@@ -173,3 +173,41 @@ def test_ragged_paged_attention_compiles_at_30_heads_of_128(
     _compile(fn, one_chip, ((bsz, width, heads, d), F32), pool, pool,
              ((bsz, context // page_size), I32), ((bsz, width), I32),
              ((bsz,), I32))
+
+
+# the cell `lfm2_moe_longgen` whole: both step programs of the engine at
+# the configuration's own shapes (32 rows; lists of 32 and 512 tokens; 64
+# experts of 2048 x 1536 in four layers; grouped queries folded into 4 and
+# 512 query cells a row of the ragged kernel).  What has to hold on the
+# chip: the kernel takes the folded rows, the loop over expert blocks
+# compiles, and arguments + temporaries fit the chip's 16 GB beside each
+# other (10.8 GB of float32 weights)
+@pytest.mark.parametrize("width", [1, 128])
+def test_lfm2_moe_step_programs_compile(width, one_chip, compiled_kernels):
+    import json
+    import os
+
+    from benchmarks.lib import serve_cell, spec
+    from unicore_tpu.serve import ServeEngine
+
+    cell = spec.load_cell("lfm2_moe_longgen")
+    cfg = cell["config"]
+    model = cell["family"].build_model(cfg)
+    abstract = serve_cell.abstract_params(model)
+    eng = ServeEngine(model, abstract, **cfg["engine"])
+    assert eng.serve_step_widths() == (1, 128) and eng.mixed_tokens == 512
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    packed = jax.ShapeDtypeStruct(
+        (eng._packed_size(eng._step_operands(width)),), I32,
+        sharding=one_chip)
+    compiled = eng._ragged_step_fn(width, "greedy").lower(
+        placed(abstract), placed(eng.pages), packed).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1      # the one attention layer
+    assert len([l for l in text.splitlines() if " while(" in l]) == 4
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 10.8e9 < mem.argument_size_in_bytes < 11.3e9, mem
+    assert held < 14e9, mem

@@ -202,6 +202,10 @@ def test_load_snapshot_is_stable_typed_dict(lm):
         # the prefill width, tokens carried, tokens compiled for)
         "mixed_steps": int, "mixed_tokens_carried": int,
         "mixed_tokens_capacity": int,
+        # ISSUE 31: a model with sparse experts counts its routing (zeros
+        # for any other; the skew as moe_stats() last read it)
+        "moe_assignments": int, "moe_experts_touched": int,
+        "moe_load_max_over_mean": float,
     }
     assert set(snap) == set(want_types), snap
     for k, t in want_types.items():

@@ -130,13 +130,17 @@ def test_fused_ce_byte_rule(rows, vocab, chunk):
 
 
 def _serve_shapes(name, width):
+    """What ``write_and_attend`` hands the kernel: under grouped queries
+    the K/V heads, and the ``g`` query heads of a group as ``g`` query
+    cells a position (``serve/attention.py``)."""
     cfg = _config(name)
     eng = cfg["engine"]
     h = cfg["num_attention_heads"]
+    kv = cfg.get("num_key_value_heads", h)  # opt_1.3b has no such key
     d = cfg["hidden_size"] // h
     table = cfg["max_position_embeddings"] // eng["page_size"]
-    q = SDS((eng["max_batch"], width, h, d), F32)
-    pool = SDS((eng["num_pages"] * eng["page_size"], h * d), F32)
+    q = SDS((eng["max_batch"], width * (h // kv), kv, d), F32)
+    pool = SDS((eng["num_pages"] * eng["page_size"], kv * d), F32)
     return q, pool, SDS((eng["max_batch"], table), jnp.int32), eng
 
 
@@ -145,13 +149,19 @@ def _serve_shapes(name, width):
     ("opt_1.3b", 128, (32, 32, 64, 32)),
     ("olmo_hybrid_7b", 1, (12, 30, 128, 128)),
     ("olmo_hybrid_7b", 64, (12, 30, 128, 128)),
+    # 32 query heads over 8 K/V heads: the kernel sees the 8, and four
+    # query cells a position (4 and 512 to a row)
+    ("lfm2_24b_a2b", 1, (32, 8, 64, 32)),
+    ("lfm2_24b_a2b", 128, (32, 8, 64, 32)),
 ])
 def test_serve_cells_pages_per_block(on_chip, name, width, geometry):
-    """Both widths of both serve configurations: the compiled kernel
+    """Both widths of the serve configurations: the compiled kernel
     supports the shape, and 4 pages of 64 (256 slots) go into a block."""
     q, pool, table, eng = _serve_shapes(name, width)
     assert (q.shape[0], q.shape[2], q.shape[3], table.shape[1]) == geometry
     assert width in (1, eng["prefill_chunk"])
+    assert q.shape[1] * q.shape[2] == width * _config(name)[
+        "num_attention_heads"]
     assert pa.supported(q.shape[2], q.shape[3], eng["page_size"], 4)
     assert serve_attention._kernel_ok(q, pool, table, eng["page_size"]) == 4
     with backend.kernel_backend("reference"):

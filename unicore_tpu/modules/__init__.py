@@ -25,12 +25,15 @@ from .transformer_decoder import (  # noqa: F401
     TransformerDecoderLayer,
 )
 from .pattern_decoder import (  # noqa: F401
+    ExpertFFN,
+    ExpertSpec,
     FullAttentionMixer,
     GatedFFN,
     LinearAttentionMixer,
     PatternDecoder,
     PatternDecoderLayer,
     RMSNorm,
+    ShortConvMixer,
 )
 from .triangle_attention import (  # noqa: F401
     EvoformerPairBlock,

@@ -1,22 +1,30 @@
 """A decoder assembled from a per-layer pattern.
 
-``layer_types`` names each layer's MIXER (``"full_attention"`` or
-``"linear_attention"``); every layer shares one block shape, the
-Olmo 2/3 convention: the norm sits on the sub-layer's OUTPUT,
+``layer_types`` names each layer's MIXER (``"full_attention"``,
+``"linear_attention"`` or ``"conv"``) and ``ffn_types`` its feed-forward
+(``"dense"`` or ``"experts"``; empty: dense everywhere); the block's
+residual form is the model's (``norm_placement``):
 
-    h = x + RMSNorm(Mixer(x))
-    y = h + RMSNorm(W_down(silu(W_gate h) * W_up h))
+    output (Olmo 2/3)   h = x + RMSNorm(Mixer(x))
+                        y = h + RMSNorm(FFN(h))
+    input  (pre-norm)   h = x + Mixer(RMSNorm(x))
+                        y = h + FFN(RMSNorm(h))
 
-with no bias anywhere.  The two mixers hold two kinds of cache in the
-serve tier's ``"pagedkv"`` collection, both addressed through
+with no bias anywhere.  The dense FFN is SwiGLU; the expert FFN a router
+over ``experts.num_experts`` SwiGLU experts (``ops/moe.py``), of which a
+token of the serve step's list that nobody carries (position -1) reaches
+none.  The mixers hold their caches in the serve tier's ``"pagedkv"``
+collection, all addressed through
 :class:`~unicore_tpu.serve.attention.PagedMeta`:
 
 - ``full_attention``: K/V pages, one slot per token (``k_pages`` /
-  ``v_pages``, ``[num_slots, H * D]``), written at ``slot_mapping`` and
-  read through the page table by the ragged paged attention op, which
-  sorts the step's flat token list into its ``[rows, width]`` rectangle
-  (``serve/attention.py`` ``write_and_attend``).  QK-norm over the whole
-  projection, no rotary.
+  ``v_pages``, ``[num_slots, kv_heads * D]``), written at
+  ``slot_mapping`` and read through the page table by the ragged paged
+  attention op, which sorts the step's flat token list into its ``[rows,
+  width]`` rectangle (``serve/attention.py`` ``write_and_attend``, which
+  also folds grouped query heads).  QK-norm over the whole projection
+  and no rotary (Olmo), or per head with rotary after it
+  (``qk_norm_per_head``, ``rope_theta``).
 - ``linear_attention``: one fixed-size recurrent state per SEQUENCE
   (``ssm_state`` ``[num_state_slots, H, dk, dv]`` float32) and the short
   convolution's tail (``conv_tail`` ``[num_state_slots, K - 1, channels]``),
@@ -31,22 +39,52 @@ serve tier's ``"pagedkv"`` collection, both addressed through
   so a slot needs no host-side clearing; a padded column (position -1,
   and every cell no token fills) changes neither state nor tail, and an
   empty row's slot is out of range, so its write is dropped.
+- ``conv`` (the gated short convolution): a state that is ONLY a tail,
+  the last ``K - 1`` gated inputs of the sequence (``conv_tail``
+  ``[num_state_slots, K - 1, embed_dim]``), through the same rows, slots
+  and rules as the linear-attention layer's.
+
+An expert FFN keeps two counters beside the caches, in the same donated
+collection: ``moe_load`` (tokens each expert got, summed over steps) and
+``moe_touched`` (experts that got a token, summed over steps); the
+engine reads them on demand (``ServeEngine.moe_stats``).
 
 Without ``paged`` a call is one full causal pass from zero state (init,
 training-style forwards, tests): ``[B, T]`` is the rectangle.
 """
 
+import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from unicore_tpu.ops import moe
 from unicore_tpu.ops.gated_delta_rule import gated_delta_rule, short_conv
 
 from .multihead_attention import bert_init
+from .rotary import apply_rotary_qk
 
-FULL, LINEAR = "full_attention", "linear_attention"
+FULL, LINEAR, CONV = "full_attention", "linear_attention", "conv"
+DENSE, EXPERTS = "dense", "experts"
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSpec:
+    """An expert FFN's sizes: ``num_experts`` routed, ``top_k`` a token,
+    each a SwiGLU of ``ffn_dim``; ``use_bias``: a per-expert bias on the
+    selection; ``scale``: the factor on the renormalised weights.  The
+    layer holds experts ``first_expert .. first_expert + experts_held -
+    1`` (``experts_held`` 0: all of them) and computes their part."""
+    num_experts: int
+    top_k: int
+    ffn_dim: int
+    use_bias: bool = True
+    scale: float = 1.0
+    first_expert: int = 0
+    experts_held: int = 0
 
 
 class Linear(nn.Module):
@@ -103,23 +141,40 @@ class FullAttentionMixer(nn.Module):
     embed_dim: int
     num_heads: int
     eps: float = 1e-6
+    kv_heads: int = 0            # 0: as many as query heads
+    qk_norm_per_head: bool = False
+    rope_theta: float = 0.0      # 0: no rotary
 
     @nn.compact
     def __call__(self, x, positions=None, paged=None):
         B, T, D = x.shape
         H, hd = self.num_heads, self.embed_dim // self.num_heads
-        q = RMSNorm(D, self.eps, name="q_norm")(Linear(D, name="q_proj")(x))
-        k = RMSNorm(D, self.eps, name="k_norm")(Linear(D, name="k_proj")(x))
-        v = Linear(D, name="v_proj")(x)
-        q, k, v = (t.reshape(B, T, H, hd) for t in (q, k, v))
+        KV = self.kv_heads or H
+        # the norm over the whole projection (Olmo), or over each head
+        whole = not self.qk_norm_per_head
+        q = Linear(D, name="q_proj")(x)
+        if whole:
+            q = RMSNorm(D, self.eps, name="q_norm")(q)
+        k = Linear(KV * hd, name="k_proj")(x)
+        if whole:
+            k = RMSNorm(KV * hd, self.eps, name="k_norm")(k)
+        v = Linear(KV * hd, name="v_proj")(x)
+        q = q.reshape(B, T, H, hd)
+        k, v = (t.reshape(B, T, KV, hd) for t in (k, v))
+        if not whole:
+            q = RMSNorm(hd, self.eps, name="q_norm")(q)
+            k = RMSNorm(hd, self.eps, name="k_norm")(k)
+        if self.rope_theta:
+            q, k = apply_rotary_qk(q, k, base=self.rope_theta,
+                                   positions=positions)
         scale = hd ** -0.5
         ready = paged is not None and self.has_variable("pagedkv", "k_pages")
         if paged is not None:
             nslots = None if ready else int(paged.num_slots)
             k_pages = self.variable("pagedkv", "k_pages", jnp.zeros,
-                                    (nslots, D), k.dtype)
+                                    (nslots, KV * hd), k.dtype)
             v_pages = self.variable("pagedkv", "v_pages", jnp.zeros,
-                                    (nslots, D), v.dtype)
+                                    (nslots, KV * hd), v.dtype)
         if ready:
             from unicore_tpu.serve.attention import write_and_attend
 
@@ -128,6 +183,8 @@ class FullAttentionMixer(nn.Module):
         else:
             from unicore_tpu.utils import causal_iota_mask
 
+            if KV != H:
+                k, v = (jnp.repeat(t, H // KV, axis=2) for t in (k, v))
             s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
             s = s + causal_iota_mask(T, T)[None, None]
             p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
@@ -222,6 +279,94 @@ class LinearAttentionMixer(nn.Module):
             o.reshape(lead + (nv,)) * gate)
 
 
+class ShortConvMixer(nn.Module):
+    """The gated short convolution: ``[B, C, u] = split3(W_in x)``, ``z =
+    B * u``, a depthwise causal convolution of ``kernel_dim`` taps over
+    ``z`` (no bias, no activation), ``W_out (C * conv)``.  The state of a
+    sequence is its last ``kernel_dim - 1`` values of ``z`` (module
+    docstring)."""
+    embed_dim: int
+    kernel_dim: int = 3
+
+    @nn.compact
+    def __call__(self, x, positions=None, paged=None):
+        lead = x.shape[:-1]
+        D, K = self.embed_dim, self.kernel_dim
+        gate_in, gate_out, u = jnp.split(
+            Linear(3 * D, name="in_proj")(x), 3, axis=-1)
+        z = gate_in * u
+        conv_kernel = self.param("conv_kernel", bert_init, (K, D),
+                                 jnp.float32)
+        ready = paged is not None and self.has_variable("pagedkv",
+                                                        "conv_tail")
+        if paged is not None:
+            nstate = None if ready else int(paged.num_state_slots)
+            tails = self.variable("pagedkv", "conv_tail", jnp.zeros,
+                                  (nstate, K - 1, D), z.dtype)
+        if ready:
+            # as LinearAttentionMixer: the chain gets rows, a row at
+            # position 0 starts from zeros, position -1 carries nothing
+            z = paged.to_rows(z)
+            positions = paged.row_positions(positions)
+            slots = paged.state_slots
+            fresh = positions[:, 0] == 0
+            tail = jnp.where(
+                fresh[:, None, None], 0.0,
+                jnp.take(tails.value, slots, axis=0, mode="clip"))
+            valid = jnp.sum(positions >= 0, axis=1, dtype=jnp.int32)
+        else:
+            tail = jnp.zeros(z.shape[:1] + (K - 1, D), z.dtype)
+            valid = jnp.full(z.shape[:1], z.shape[1], jnp.int32)
+        c, tail = short_conv(z, conv_kernel, tail, valid)
+        if ready:
+            tails.value = tails.value.at[slots].set(
+                tail, mode="drop", unique_indices=True)
+            c = paged.to_tokens(c, lead)
+        return Linear(D, name="out_proj")(gate_out * c)
+
+
+class ExpertFFN(nn.Module):
+    """A router over ``spec.num_experts`` SwiGLU experts (``ops/moe.py``
+    has the equations).  The scores are computed in float32 at
+    ``highest``: they decide which expert runs."""
+    embed_dim: int
+    spec: ExpertSpec
+
+    @nn.compact
+    def __call__(self, x, positions=None, paged=None):
+        sp, D = self.spec, self.embed_dim
+        held = sp.experts_held or sp.num_experts
+        router = self.param("router", bert_init, (D, sp.num_experts),
+                            jnp.float32)
+        bias = self.param("expert_bias", nn.initializers.zeros,
+                          (sp.num_experts,), jnp.float32) \
+            if sp.use_bias else None
+        w1 = self.param("w1", bert_init, (held, D, sp.ffn_dim), jnp.float32)
+        w3 = self.param("w3", bert_init, (held, D, sp.ffn_dim), jnp.float32)
+        w2 = self.param("w2", bert_init, (held, sp.ffn_dim, D), jnp.float32)
+        tokens = x.reshape(-1, D)
+        scores = jax.nn.sigmoid(jnp.dot(
+            tokens.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        sel, w = moe.route(scores, bias, sp.top_k, sp.scale)
+        # a token of the step's list that nobody carries: position -1
+        valid = None if paged is None else positions.reshape(-1) >= 0
+        y, load = moe.expert_ffn(tokens, valid, w1, w3, w2, sel, w,
+                                 first_expert=sp.first_expert)
+        ready = paged is not None and self.has_variable("pagedkv",
+                                                        "moe_load")
+        if paged is not None:
+            total = self.variable("pagedkv", "moe_load", jnp.zeros,
+                                  (held,), jnp.int32)
+            touched = self.variable("pagedkv", "moe_touched", jnp.zeros,
+                                    (), jnp.int32)
+        if ready:
+            total.value = total.value + load
+            touched.value = touched.value + jnp.sum(load > 0,
+                                                    dtype=jnp.int32)
+        return y.reshape(x.shape)
+
+
 class PatternDecoderLayer(nn.Module):
     mixer: str
     embed_dim: int
@@ -233,27 +378,50 @@ class PatternDecoderLayer(nn.Module):
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = True
     eps: float = 1e-6
+    kv_heads: int = 0
+    qk_norm_per_head: bool = False
+    rope_theta: float = 0.0
+    short_conv_kernel_dim: int = 3
+    norm_placement: str = "output"
+    experts: Optional[ExpertSpec] = None    # None: the dense FFN
 
     @nn.compact
     def __call__(self, x, positions=None, paged=None):
         if self.mixer == FULL:
             mixer = FullAttentionMixer(
-                self.embed_dim, self.num_heads, self.eps, name="self_attn")
+                self.embed_dim, self.num_heads, self.eps, self.kv_heads,
+                self.qk_norm_per_head, self.rope_theta, name="self_attn")
         elif self.mixer == LINEAR:
             mixer = LinearAttentionMixer(
                 self.embed_dim, self.linear_num_heads,
                 self.linear_key_head_dim, self.linear_value_head_dim,
                 self.linear_conv_kernel_dim, self.linear_allow_neg_eigval,
                 self.eps, name="linear_attn")
+        elif self.mixer == CONV:
+            mixer = ShortConvMixer(self.embed_dim,
+                                   self.short_conv_kernel_dim, name="conv")
         else:
             raise ValueError(f"unknown mixer kind {self.mixer!r} (known: "
-                             f"{FULL!r}, {LINEAR!r})")
-        h = x + RMSNorm(self.embed_dim, self.eps,
-                        name="post_attention_layernorm")(
-            mixer(x, positions=positions, paged=paged))
-        ffn = GatedFFN(self.embed_dim, self.ffn_embed_dim, name="mlp")(h)
-        return h + RMSNorm(self.embed_dim, self.eps,
-                           name="post_feedforward_layernorm")(ffn)
+                             f"{FULL!r}, {LINEAR!r}, {CONV!r})")
+        norm = lambda name: RMSNorm(self.embed_dim, self.eps, name=name)
+        if self.experts is None:
+            ffn = GatedFFN(self.embed_dim, self.ffn_embed_dim,
+                           name="mlp" if self.norm_placement == "output"
+                           else "feed_forward")
+        else:
+            ffn = functools.partial(
+                ExpertFFN(self.embed_dim, self.experts, name="feed_forward"),
+                positions=positions, paged=paged)
+        if self.norm_placement == "output":
+            h = x + norm("post_attention_layernorm")(
+                mixer(x, positions=positions, paged=paged))
+            return h + norm("post_feedforward_layernorm")(ffn(h))
+        if self.norm_placement != "input":
+            raise ValueError(f"unknown norm placement "
+                             f"{self.norm_placement!r} (output, input)")
+        h = x + mixer(norm("operator_norm")(x), positions=positions,
+                      paged=paged)
+        return h + ffn(norm("ffn_norm")(h))
 
 
 class PatternDecoder(nn.Module):
@@ -263,22 +431,33 @@ class PatternDecoder(nn.Module):
     embed_dim: int
     ffn_embed_dim: int
     num_heads: int
-    linear_num_heads: int
-    linear_key_head_dim: int
-    linear_value_head_dim: int
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = True
     eps: float = 1e-6
+    kv_heads: int = 0
+    qk_norm_per_head: bool = False
+    rope_theta: float = 0.0
+    short_conv_kernel_dim: int = 3
+    norm_placement: str = "output"
+    ffn_types: Tuple[str, ...] = ()         # empty: dense everywhere
+    experts: Optional[ExpertSpec] = None
 
     @nn.compact
     def __call__(self, x, positions: Optional[jnp.ndarray] = None,
                  paged=None):
         for i, kind in enumerate(self.layer_types):
+            sparse = bool(self.ffn_types) and self.ffn_types[i] == EXPERTS
             x = PatternDecoderLayer(
                 kind, self.embed_dim, self.ffn_embed_dim, self.num_heads,
                 self.linear_num_heads, self.linear_key_head_dim,
                 self.linear_value_head_dim, self.linear_conv_kernel_dim,
-                self.linear_allow_neg_eigval, self.eps,
+                self.linear_allow_neg_eigval, self.eps, self.kv_heads,
+                self.qk_norm_per_head, self.rope_theta,
+                self.short_conv_kernel_dim, self.norm_placement,
+                self.experts if sparse else None,
                 name=f"layers_{i}",
             )(x, positions=positions, paged=paged)
         return RMSNorm(self.embed_dim, self.eps, name="final_layer_norm")(x)

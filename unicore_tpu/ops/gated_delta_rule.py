@@ -176,6 +176,8 @@ def short_conv(x, kernel, tail, valid):
     padded columns and empty rows carry nothing forward."""
     K = kernel.shape[0]
     T = x.shape[1]
+    note_dispatch("short_conv", "b%d w%d c%d k%d %s" % (
+        x.shape[0], T, x.shape[2], K, x.dtype.name), False)
     with jax.named_scope("short_conv"):
         ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
         y = sum(ext[:, i:i + T] * kernel[i].astype(x.dtype)

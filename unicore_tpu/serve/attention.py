@@ -3,7 +3,9 @@
 Layouts: the attention core takes queries in the module convention
 ``[B, T, H, D]`` (a serve step whose tokens are a flat list sorts them
 into that rectangle here, in ``write_and_attend``); the pool
-is FLAT — ``k_pages``/``v_pages`` are ``[num_slots, H*D]`` (heads folded
+is FLAT — ``k_pages``/``v_pages`` are ``[num_slots, H*D]`` (``H`` the K/V
+heads: fewer than the query heads under grouped queries, see
+``write_and_attend``; heads folded
 into the minor dim, so a page is a tile-aligned slab the kernel can DMA
 and no HBM tile is half empty at ``D == 64``) where slot
 ``page * page_size + offset`` holds the token at ``position`` such that
@@ -201,16 +203,32 @@ def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
 
     Attention needs rows: ``q`` goes into the ``[B, width]`` rectangle
     the ragged kernel takes (``paged.to_rows``; position -1 masks a cell
-    no token fills) and the output comes back in ``q``'s own layout."""
+    no token fills) and the output comes back in ``q``'s own layout.
+
+    Grouped queries: ``k``/``v`` may hold fewer heads than ``q``, the
+    pages are as wide as THEY are, and query head ``h`` reads K/V head
+    ``h // g`` (``g`` query heads a K/V head).  The ``g`` heads of a
+    group then ride as ``g`` query CELLS at one position, ``[B, T, kv *
+    g, D] -> [B, T * g, kv, D]`` with the positions repeated, so the
+    kernel runs as it is over ``kv`` heads and reads each page once for
+    the heads that share it.  With ``g == 1`` nothing is folded."""
     width = k_pages.value.shape[-1]
     k_pages.value = k_pages.value.at[paged.slot_mapping].set(
         k.astype(k_pages.value.dtype).reshape(-1, width))
     v_pages.value = v_pages.value.at[paged.slot_mapping].set(
         v.astype(v_pages.value.dtype).reshape(-1, width))
+    rows, row_positions = paged.to_rows(q), paged.row_positions(positions)
+    B, T, H, D = rows.shape
+    g = H * D // width
+    if g > 1:
+        rows = rows.reshape(B, T, H // g, g, D).swapaxes(2, 3).reshape(
+            B, T * g, H // g, D)
+        row_positions = jnp.repeat(row_positions, g, axis=1)
     o = paged_attention(
-        paged.to_rows(q), k_pages.value, v_pages.value,
-        page_table=paged.page_table,
-        positions=paged.row_positions(positions),
+        rows, k_pages.value, v_pages.value,
+        page_table=paged.page_table, positions=row_positions,
         lengths=paged.lengths, page_size=paged.page_size, scale=scale,
     )
+    if g > 1:
+        o = o.reshape(B, T, g, H // g, D).swapaxes(2, 3).reshape(B, T, H, D)
     return paged.to_tokens(o, q.shape[:2])

@@ -345,6 +345,7 @@ def _fleet_main(args, model, params, requests, shutdown):
     pool_clean = all(e.pool.is_idle() for e in audited.values())
     for eng in audited.values():
         eng.pool.check_invariants()
+        eng.moe_stats()  # the routing counters, read into stats once
     report = {
         "results": [_result_record(results[r.request_id])
                     for r in requests],
@@ -473,6 +474,7 @@ def _serve(args):
         shutdown.uninstall()
     pool_clean = engine.pool.is_idle()
     engine.pool.check_invariants()
+    engine.moe_stats()  # the routing counters, read into stats once
     report = {
         "results": [_result_record(r) for r in results],
         "stats": {k: (round(v, 4) if isinstance(v, float) else v)

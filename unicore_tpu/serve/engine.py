@@ -47,6 +47,17 @@ consecutive chunks of one prompt cannot share a program the way K/V
 scatters do), and prefix hits are refused (a hit starts a prompt past
 its shared pages, where no state exists).
 
+A model with SPARSE EXPERTS (``modules/pattern_decoder.py``
+``ExpertFFN``) routes the tokens the step carries and no others: a cell
+of the list nobody carries sits at position -1 and reaches no expert.
+Each expert layer counts its routing ON THE DEVICE, in two variables of
+the same donated tree (``moe_load``: tokens each expert got,
+``moe_touched``: experts that got a token, both summed over steps);
+:meth:`ServeEngine.moe_stats` reads them on demand.  What a step added to
+their sums comes back with its tokens, two numbers behind them in the one
+array the step fetches anyway, and feeds ``stats["moe_assignments"]`` /
+``["moe_experts_touched"]`` with no transfer of its own.
+
 Metrics: per-request queue wait and TTFT, and the counters of
 :attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
 occupancy, prefix-cache hits), which the JSON report and the fleet
@@ -127,6 +138,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from unicore_tpu.ops import moe
 
 from .attention import PagedMeta
 from .kv_pool import PagedKVPool, PoolExhausted
@@ -255,6 +268,8 @@ class ServeEngine:
             max_waiting=max_waiting, request_retries=request_retries,
         )
         self.pages = self._init_pages()
+        # expert layers that count their routing on the device
+        self.moe_layers = len(self._moe_counters(self.pages)[0])
         # prefill-chunk width: a prompt is admitted in <= this many
         # tokens per ragged step (bounded-TTFT slices).  0 = the default
         chunk = int(prefill_chunk) or DEFAULT_PREFILL_CHUNK
@@ -327,6 +342,12 @@ class ServeEngine:
             # width, the tokens they carried, mixed_tokens x dispatches
             "mixed_steps": 0, "mixed_tokens_carried": 0,
             "mixed_tokens_capacity": 0,
+            # a model with sparse experts (module docstring): token x
+            # expert assignments and experts that got a token, summed
+            # over expert layers and steps; the hottest expert's load
+            # over the mean as moe_stats() last read it
+            "moe_assignments": 0, "moe_experts_touched": 0,
+            "moe_load_max_over_mean": 0.0,
         }
         # live weight swaps installed via swap_weights (ISSUE 18);
         # _owns_params flips on the first swap — boot params may be
@@ -359,6 +380,39 @@ class ServeEngine:
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes
         )
+
+    @staticmethod
+    def _moe_counters(pages):
+        """``(loads, touched)``: the ``moe_load`` and ``moe_touched``
+        leaves of a ``pagedkv`` tree in layer order, empty for a model
+        without expert layers."""
+        found = {"moe_load": [], "moe_touched": []}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(pages)[0]:
+            name = getattr(path[-1], "key", None)
+            if name in found:
+                found[name].append(leaf)
+        return found["moe_load"], found["moe_touched"]
+
+    def moe_stats(self):
+        """The expert layers' routing counters, read from the device
+        now: ``{"load": [[tokens each expert got] per expert layer],
+        "experts_touched": [per layer, summed over steps], "assignments":
+        all of ``load`` summed, "load_max_over_mean": the hottest
+        expert's load over its layer's mean, the largest over the
+        layers}``; None for a model without expert layers.  Also leaves
+        the last of these in ``stats["moe_load_max_over_mean"]``, which
+        ``load_snapshot`` carries without a read of its own."""
+        loads, touched = self._moe_counters(self.pages)
+        if not loads:
+            return None
+        loads = [np.asarray(x).astype(np.int64) for x in loads]
+        skew = max((float(x.max() / x.mean()) for x in loads if x.sum()),
+                   default=0.0)
+        self.stats["moe_load_max_over_mean"] = skew
+        return {"load": [x.tolist() for x in loads],
+                "experts_touched": [int(np.asarray(x)) for x in touched],
+                "assignments": int(sum(x.sum() for x in loads)),
+                "load_max_over_mean": skew}
 
     # -- the one jitted step -------------------------------------------
 
@@ -516,17 +570,29 @@ class ServeEngine:
                     o["top_k"], sampling
                 ), ok
 
+            def moe_sums(pages):
+                loads, touched = self._moe_counters(pages)
+                return jnp.stack([sum(jnp.sum(x) for x in loads),
+                                  sum(touched)])
+
             def step(params, pages, packed):
                 o = self._cut(packed, operands)
                 o["temperature"] = jax.lax.bitcast_convert_type(
                     o["temperature"], jnp.float32)
                 if "poison" in o:
                     o["poison"] = o["poison"] != 0
+                before = pages
                 logits, pages = forward(params, pages, o)
                 toks, ok = sample(self._last_token_rows(
                     logits, o["last"], width), o)
                 # one array to fetch: -1 for a row of nonfinite logits
-                return jnp.where(ok, toks, -1), pages
+                out = jnp.where(ok, toks, -1)
+                if self.moe_layers:
+                    # and, behind the tokens, what this step added to the
+                    # expert layers' counters (module docstring)
+                    out = jnp.concatenate(
+                        [out, moe_sums(pages) - moe_sums(before)])
+                return out, pages
 
             fn = self._step_fns[key] = jax.jit(
                 step, donate_argnums=(1,)
@@ -785,10 +851,16 @@ class ServeEngine:
                 # host sync: the scheduler needs the tokens, and which
                 # rows sampled from finite logits (a token of -1: not)
                 toks = np.asarray(out)
+                toks, routed = toks[:B], toks[B:]
                 ok = toks >= 0
         dt = time.perf_counter() - t0
         with _span(SPAN_EMIT):
             self.stats["prefills"] += sum(1 for r in rows if not r[4])
+            if self.moe_layers:
+                assigned, touched = int(routed[0]), int(routed[1])
+                self.stats["moe_assignments"] += assigned
+                self.stats["moe_experts_touched"] += touched
+                moe.note_routing(assigned, touched)
             if w > 1:
                 self.stats["mixed_steps"] += 1
                 self.stats["mixed_tokens_carried"] += carried
@@ -1253,7 +1325,16 @@ class ServeEngine:
         width, ``mixed_tokens_carried`` (int) the tokens they carried,
         ``mixed_tokens_capacity`` (int) the tokens their programs were
         compiled for; carried over capacity is how much of a mixed
-        step's dense work was for tokens somebody sent."""
+        step's dense work was for tokens somebody sent.
+
+        A model with sparse experts (zeros for any other):
+        ``moe_assignments`` (int) token x expert assignments and
+        ``moe_experts_touched`` (int) experts that got a token, both
+        summed over expert layers and steps (touched over steps x expert
+        layers is how many experts' weights a step reads);
+        ``moe_load_max_over_mean`` (float) the hottest expert's load over
+        the mean, as :meth:`moe_stats` last read it from the device (0.0
+        before the first read: the snapshot itself reads nothing)."""
         sched = self.scheduler
         recent = list(self.decode_ms)[-33:]
         step_ms = float(sorted(recent)[len(recent) // 2]) if recent else 0.0
@@ -1279,6 +1360,10 @@ class ServeEngine:
             "mixed_tokens_carried": int(self.stats["mixed_tokens_carried"]),
             "mixed_tokens_capacity": int(
                 self.stats["mixed_tokens_capacity"]),
+            "moe_assignments": int(self.stats["moe_assignments"]),
+            "moe_experts_touched": int(self.stats["moe_experts_touched"]),
+            "moe_load_max_over_mean": round(
+                float(self.stats["moe_load_max_over_mean"]), 4),
         }
 
     def reclaim_waiting(self, *, include_running=False):
