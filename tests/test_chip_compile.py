@@ -211,3 +211,41 @@ def test_lfm2_moe_step_programs_compile(width, one_chip, compiled_kernels):
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 10.8e9 < mem.argument_size_in_bytes < 11.3e9, mem
     assert held < 14e9, mem
+
+
+# the cell `pangu_mla_shareddocs` whole: both step programs of the engine
+# at the configuration's own shapes (16 rows x 64; lists of 16 and 512 tokens;
+# five latent-attention layers whose heads ride as 128 and 512 query cells
+# a row of the ragged kernel over one K/V head of 640 lanes, the page table
+# repeated once a tile; a shared expert beside 8 of 256 routed experts in
+# four layers).  What has to hold on the chip: the kernel takes the cells
+# and the repeated table (VMEM, SMEM), the loop over expert blocks
+# compiles, and arguments + temporaries fit the chip's 16 GB beside each
+# other (13.64 GB of float32 weights, 0.94 GB of latent pages)
+@pytest.mark.parametrize("width", [1, 64])
+def test_pangu_mla_step_programs_compile(width, one_chip, compiled_kernels):
+    from benchmarks.lib import serve_cell, spec
+    from unicore_tpu.serve import ServeEngine
+
+    cell = spec.load_cell("pangu_mla_shareddocs")
+    cfg = cell["config"]
+    model = cell["family"].build_model(cfg)
+    abstract = serve_cell.abstract_params(model)
+    eng = ServeEngine(model, abstract, **cfg["engine"])
+    assert eng.serve_step_widths() == (1, 64) and eng.mixed_tokens == 512
+    assert eng.stats["cache_bytes_per_token"] == 5 * 640 * 4
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    packed = jax.ShapeDtypeStruct(
+        (eng._packed_size(eng._step_operands(width)),), I32,
+        sharding=one_chip)
+    compiled = eng._ragged_step_fn(width, "greedy").lower(
+        placed(abstract), placed(eng.pages), packed).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5       # a kernel a layer
+    assert len([l for l in text.splitlines() if " while(" in l]) == 4
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 14.5e9 < mem.argument_size_in_bytes < 14.7e9, mem
+    assert held < 16e9, mem

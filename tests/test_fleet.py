@@ -206,6 +206,9 @@ def test_load_snapshot_is_stable_typed_dict(lm):
         # for any other; the skew as moe_stats() last read it)
         "moe_assignments": int, "moe_experts_touched": int,
         "moe_load_max_over_mean": float,
+        # ISSUE 35: the assignments that landed on experts held here, and
+        # what one token holds in the pool over all layers
+        "moe_assignments_held": int, "cache_bytes_per_token": int,
     }
     assert set(snap) == set(want_types), snap
     for k, t in want_types.items():

@@ -169,6 +169,43 @@ def test_serve_cells_pages_per_block(on_chip, name, width, geometry):
             q, pool, table, eng["page_size"]) is None
 
 
+@pytest.mark.parametrize("width,rows,cells", [
+    (1, 16, 128),        # a decode row: one token's 128 heads
+    (64, 256, 512),      # a chunk of 64 in 16 tiles of 4 tokens x 128 heads
+])
+def test_the_latent_cells_kernel_choices(on_chip, width, rows, cells):
+    """``pangu_mla_shareddocs``: both widths hand the ragged kernel ONE
+    K/V head as wide as a latent page (512 + 64 + 64 lanes), the heads of
+    a token as query cells, 512 cells a kernel row at most; the shape is
+    supported, 4 pages of 64 go into a block, and a share of 8 of 256
+    experts sizes its blocks from the choices it can expect."""
+    from unicore_tpu.modules.pattern_decoder import LATENT_LANES
+    from unicore_tpu.ops import moe
+
+    cfg = _config("openpangu_ultra_moe_718b")
+    eng = cfg["engine"]
+    heads = cfg["num_attention_heads"]
+    lanes = -(-(cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"])
+              // LATENT_LANES) * LATENT_LANES
+    assert lanes == 640 and width in (1, eng["prefill_chunk"])
+    tokens = min(width, serve_attention.LATENT_QUERY_CELLS // heads)
+    assert (eng["max_batch"] * width // tokens, tokens * heads) == (
+        rows, cells)
+    q = SDS((rows, cells, 1, lanes), F32)
+    pool = SDS((eng["num_pages"] * eng["page_size"], lanes), F32)
+    table = SDS((rows, cfg["max_position_embeddings"] // eng["page_size"]),
+                jnp.int32)
+    assert pa.supported(1, lanes, eng["page_size"], 4)
+    assert serve_attention._kernel_ok(q, pool, table, eng["page_size"]) == 4
+    with backend.kernel_backend("reference"):
+        assert serve_attention._kernel_ok(
+            q, pool, table, eng["page_size"]) is None
+    held, k = cfg["n_routed_experts"], cfg["num_experts_per_tok"]
+    step_tokens = eng["max_batch"] if width == 1 else 512
+    expected = -(-step_tokens * k * held // cfg["router_outputs"])
+    assert moe.pick_block_rows(expected, held) == (8 if width == 1 else 32)
+
+
 SD_SHAPES = {
     # name: (x, mask, bias), all bf16
     "bert_base": ((64, 12, 512, 512), None, (1, 12, 512, 512)),

@@ -197,3 +197,147 @@ def test_the_op_is_in_the_dispatch_report_and_the_routing_record():
     after = moe.routing_report()
     assert after[-1] == (12, 7) and after[:-1] == before[-len(after) + 1:]
     assert after is not moe.routing_report()      # a copy
+
+
+# -- a share of the experts beside a shared expert (PR 35) ------------------
+
+
+@pytest.mark.parametrize("eps", [1e-6, 1e-20])
+def test_route_divides_with_the_models_epsilon(eps):
+    """Scores small enough for the epsilon to show: 1e-6 under the sum
+    pulls the weights off 1, 1e-20 does not."""
+    scores = jnp.asarray([[3e-6, 1e-6, 5e-7, 0.0]], jnp.float32)
+    _, w = moe.route(scores, None, 2, eps=eps)
+    np.testing.assert_allclose(
+        w, np.asarray([[3e-6, 1e-6]]) / (4e-6 + eps), rtol=1e-5)
+    assert (abs(float(w.sum()) - 1.0) < 1e-5) == (eps == 1e-20)
+
+
+def test_the_scores_alone_choose_without_a_bias():
+    """``use_bias=False``: the layer has no ``expert_bias`` parameter and
+    the selection is the top of the scores themselves."""
+    rng = np.random.default_rng(9)
+    x = rng.normal(size=(1, N, D)).astype(np.float32)
+    module = ExpertFFN(D, ExpertSpec(E, K, F, use_bias=False, scale=2.5,
+                                     eps=1e-20))
+    params = module.init(jax.random.PRNGKey(0), x)["params"]
+    assert sorted(params) == ["router", "w1", "w2", "w3"]
+    params = {k: jnp.asarray(0.3 * rng.normal(size=v.shape), jnp.float32)
+              for k, v in params.items()}
+    scores = jax.nn.sigmoid(jnp.asarray(x[0] @ np.asarray(params["router"])))
+    sel, w = moe.route(scores, None, K, 2.5, 1e-20)
+    want = dense_oracle(x[0], np.ones(N, bool), np.asarray(params["w1"]),
+                        np.asarray(params["w3"]), np.asarray(params["w2"]),
+                        sel, w)
+    got = module.apply({"params": params}, x)[0]
+    assert np.abs(got - want).max() < TOL
+
+
+def _pangu_layer(rng, experts, first=0, held=0):
+    spec = ExpertSpec(experts, 2, F, use_bias=False, scale=2.5,
+                      first_expert=first, experts_held=held, eps=1e-20,
+                      shared_experts=1)
+    return ExpertFFN(D, spec)
+
+
+def test_four_shares_and_the_shared_expert_counted_once_are_the_layer():
+    """4 shares of 2 of 8 experts, each beside the whole shared expert:
+    the routed parts add up to the routed sum of the uncut layer, and with
+    the shared expert counted ONCE that is the uncut reference's layer
+    (``benchmarks/reference/pangu_moe_lm.py`` given all 8 experts)."""
+    from benchmarks.reference import pangu_moe_lm
+
+    experts, held = 8, 2
+    rng = np.random.default_rng(10)
+    f = lambda *s: jnp.asarray(0.3 * rng.normal(size=s), jnp.float32)
+    x = f(1, N, D)
+    tree = {"router": f(D, experts), "w1": f(experts, D, F),
+            "w3": f(experts, D, F), "w2": f(experts, F, D),
+            "shared_experts": {"gate_proj": {"kernel": f(D, F)},
+                               "up_proj": {"kernel": f(D, F)},
+                               "down_proj": {"kernel": f(F, D)}}}
+    whole = pangu_moe_lm.expert_ffn(x[0], tree, top_k=2, scale=2.5,
+                                    first_expert=0, precision="fp32")
+    shared = pangu_moe_lm.swiglu_by_rows(x[0], tree["shared_experts"], "fp32")
+    routed, ref_routed = [], []
+    for first in range(0, experts, held):
+        cut = {**tree, **{k: tree[k][first:first + held]
+                          for k in ("w1", "w3", "w2")}}
+        got = _pangu_layer(rng, experts, first, held).apply(
+            {"params": cut}, x)[0]
+        routed.append(got - shared)
+        ref_routed.append(pangu_moe_lm.expert_ffn(
+            x[0], cut, top_k=2, scale=2.5, first_expert=first,
+            precision="fp32") - shared)
+    for parts in (routed, ref_routed):
+        assert np.abs(shared + sum(parts) - whole).max() < TOL
+    uncut = _pangu_layer(rng, experts).apply({"params": tree}, x)[0]
+    assert np.abs(uncut - whole).max() < TOL
+    # one share alone is not the layer, and the shared expert is in it
+    assert np.abs(shared + routed[0] - whole).max() > 100 * TOL
+    assert np.abs(np.asarray(shared)).max() > 100 * TOL
+
+
+def test_a_step_in_which_no_held_expert_got_a_token_runs_no_block(
+        monkeypatch):
+    """Every token chooses experts 0 and 1; the share holds 6 and 7: the
+    loop over blocks has nothing to run, the part is zero, the load is
+    zero, and the lowered loop's trip count is the blocks USED (a traced
+    value), not the blocks the buffers could hold."""
+    rng = np.random.default_rng(11)
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    w1, w3, w2 = _weights(rng, 2)
+    scores = jnp.asarray(np.tile([0.9, 0.8] + [0.1] * 6, (N, 1)), jnp.float32)
+    sel, w = moe.route(scores, None, 2)
+    y, load = moe.expert_ffn(x, None, w1, w3, w2, sel, w, first_expert=6,
+                             num_experts=8)
+    assert not np.asarray(y).any() and load.tolist() == [0, 0]
+    blocks = []
+    real = jax.lax.fori_loop
+
+    def counting(lo, hi, body, init):
+        blocks.append(hi)
+        return real(lo, hi, body, init)
+
+    monkeypatch.setattr(jax.lax, "fori_loop", counting)
+    for first in (6, 0):
+        moe.expert_ffn(x, None, w1, w3, w2, sel, w, first_expert=first,
+                       num_experts=8)
+    # 37 tokens on each of experts 0 and 1, blocks of 24 rows: 2 + 2
+    assert [int(b) for b in blocks] == [0, 4]
+
+
+@pytest.mark.parametrize("tokens,k,held,experts,rows", [
+    (512, 8, 8, 256, 32),      # the cell's mixed list: 128 of 4,096 land here
+    (16, 8, 8, 256, 8),        # its decode step: 4 of 128
+    (512, 4, 64, 64, 64),      # every expert held: as before
+    (37, 2, 2, 8, 24),
+])
+def test_a_share_sizes_its_blocks_from_the_choices_it_can_expect(
+        tokens, k, held, experts, rows):
+    rng = np.random.default_rng(12)
+    x = rng.normal(size=(tokens, 8)).astype(np.float32)
+    w = lambda *s: np.zeros(s, np.float32)
+    sel, wt = moe.route(jnp.full((tokens, experts), 0.5), None, k)
+    jax.eval_shape(lambda: moe.expert_ffn(
+        x, None, w(held, 8, 16), w(held, 8, 16), w(held, 16, 8), sel, wt,
+        num_experts=experts))
+    seen = backend.dispatch_report()["moe_experts"]
+    assert f"n{tokens} k{k} e{held} d8 f16 blk{rows} float32" in seen
+
+
+def test_a_share_still_serves_every_choice_that_lands_on_it():
+    """Blocks are sized for an eighth of the choices, the buffers for all
+    of them: when EVERY token chooses the two held experts nothing is
+    dropped."""
+    rng = np.random.default_rng(13)
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    w1, w3, w2 = _weights(rng, 2)
+    scores = jnp.asarray(np.tile([0.1] * 4 + [0.9, 0.8] + [0.1] * 10,
+                                 (N, 1)), jnp.float32)
+    sel, w = moe.route(scores, None, 2)
+    y, load = moe.expert_ffn(x, None, w1, w3, w2, sel, w, first_expert=4,
+                             num_experts=16)
+    assert load.tolist() == [N, N]
+    want = dense_oracle(x, np.ones(N, bool), w1, w3, w2, sel, w, first=4)
+    assert np.abs(y - want).max() < TOL

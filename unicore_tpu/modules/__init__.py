@@ -29,6 +29,8 @@ from .pattern_decoder import (  # noqa: F401
     ExpertSpec,
     FullAttentionMixer,
     GatedFFN,
+    LatentAttentionMixer,
+    LatentSpec,
     LinearAttentionMixer,
     PatternDecoder,
     PatternDecoderLayer,

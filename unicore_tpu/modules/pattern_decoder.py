@@ -1,21 +1,24 @@
 """A decoder assembled from a per-layer pattern.
 
 ``layer_types`` names each layer's MIXER (``"full_attention"``,
-``"linear_attention"`` or ``"conv"``) and ``ffn_types`` its feed-forward
-(``"dense"`` or ``"experts"``; empty: dense everywhere); the block's
-residual form is the model's (``norm_placement``):
+``"linear_attention"``, ``"conv"`` or ``"latent_attention"``) and
+``ffn_types`` its feed-forward (``"dense"`` or ``"experts"``; empty: dense
+everywhere); the block's residual form is the model's
+(``norm_placement``):
 
     output (Olmo 2/3)   h = x + RMSNorm(Mixer(x))
                         y = h + RMSNorm(FFN(h))
     input  (pre-norm)   h = x + Mixer(RMSNorm(x))
                         y = h + FFN(RMSNorm(h))
+    both   (sandwich)   h = x + RMSNorm(Mixer(RMSNorm(x)))
+                        y = h + RMSNorm(FFN(RMSNorm(h)))
 
 with no bias anywhere.  The dense FFN is SwiGLU; the expert FFN a router
 over ``experts.num_experts`` SwiGLU experts (``ops/moe.py``), of which a
 token of the serve step's list that nobody carries (position -1) reaches
-none.  The mixers hold their caches in the serve tier's ``"pagedkv"``
-collection, all addressed through
-:class:`~unicore_tpu.serve.attention.PagedMeta`:
+none, beside ``experts.shared_experts`` that every token gets.  The
+mixers hold their caches in the serve tier's ``"pagedkv"`` collection,
+all addressed through :class:`~unicore_tpu.serve.attention.PagedMeta`:
 
 - ``full_attention``: K/V pages, one slot per token (``k_pages`` /
   ``v_pages``, ``[num_slots, kv_heads * D]``), written at
@@ -43,11 +46,24 @@ collection, all addressed through
   the last ``K - 1`` gated inputs of the sequence (``conv_tail``
   ``[num_state_slots, K - 1, embed_dim]``), through the same rows, slots
   and rules as the linear-attention layer's.
+- ``latent_attention`` (multi-head latent attention): ONE vector per
+  token for all heads, ``latent_pages`` ``[num_slots, LATENT_LANES *
+  k]``: the normed latent ``c_kv`` (``kv_lora_rank``), the rotated rope
+  key shared by the heads (``qk_rope_head_dim``), zeros up to whole
+  128-lane slabs (512 + 64 + 64 at the published widths).  A serve step
+  attends in the ABSORBED form (``serve/attention.py``
+  ``write_latent_and_attend``): the key up-projection folded into the
+  query and the value up-projection applied to the output, so the entry
+  itself is key and value and a row's context is never expanded to
+  per-head keys and values.
 
 An expert FFN keeps two counters beside the caches, in the same donated
 collection: ``moe_load`` (tokens each expert got, summed over steps) and
 ``moe_touched`` (experts that got a token, summed over steps); the
-engine reads them on demand (``ServeEngine.moe_stats``).
+engine reads them on demand (``ServeEngine.moe_stats``).  A layer that
+holds a SHARE of its experts counts ``moe_load`` over all of them (the
+router's histogram is the deployment's), ``moe_touched`` over its own,
+and keeps a third, ``moe_held``: the choices that landed on its own.
 
 Without ``paged`` a call is one full causal pass from zero state (init,
 training-style forwards, tests): ``[B, T]`` is the rectangle.
@@ -68,16 +84,21 @@ from .multihead_attention import bert_init
 from .rotary import apply_rotary_qk
 
 FULL, LINEAR, CONV = "full_attention", "linear_attention", "conv"
+LATENT = "latent_attention"
 DENSE, EXPERTS = "dense", "experts"
+LATENT_LANES = 128   # a latent page is whole lane slabs wide
 
 
 @dataclasses.dataclass(frozen=True)
 class ExpertSpec:
     """An expert FFN's sizes: ``num_experts`` routed, ``top_k`` a token,
     each a SwiGLU of ``ffn_dim``; ``use_bias``: a per-expert bias on the
-    selection; ``scale``: the factor on the renormalised weights.  The
-    layer holds experts ``first_expert .. first_expert + experts_held -
-    1`` (``experts_held`` 0: all of them) and computes their part."""
+    selection (False: the scores alone choose); ``scale``: the factor on
+    the renormalised weights, ``eps`` what their sum is divided with
+    (None: ``ops.moe.route``'s own 1e-6).  The layer holds experts
+    ``first_expert .. first_expert + experts_held - 1`` (``experts_held``
+    0: all of them) and computes their part, beside ``shared_experts``
+    that every token gets (one SwiGLU of ``shared_experts x ffn_dim``)."""
     num_experts: int
     top_k: int
     ffn_dim: int
@@ -85,6 +106,26 @@ class ExpertSpec:
     scale: float = 1.0
     first_expert: int = 0
     experts_held: int = 0
+    eps: Optional[float] = None
+    shared_experts: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """A latent-attention mixer's sizes, under the published names."""
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+def _float32_in_float32(a, b):
+    """The type two operands multiply in and the precision for it: float32
+    at ``HIGH`` (:class:`Linear` says why), anything else at the
+    default."""
+    dtype = jnp.result_type(a.dtype, b.dtype)
+    return dtype, (jax.lax.Precision.HIGH if dtype == jnp.float32 else None)
 
 
 class Linear(nn.Module):
@@ -104,8 +145,7 @@ class Linear(nn.Module):
     def __call__(self, x):
         kernel = self.param("kernel", bert_init,
                             (x.shape[-1], self.features), jnp.float32)
-        dtype = jnp.result_type(x.dtype, kernel.dtype)
-        precision = jax.lax.Precision.HIGH if dtype == jnp.float32 else None
+        dtype, precision = _float32_in_float32(x, kernel)
         return jnp.dot(x.astype(dtype), kernel.astype(dtype),
                        precision=precision)
 
@@ -190,6 +230,92 @@ class FullAttentionMixer(nn.Module):
             p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
         return Linear(D, name="o_proj")(o.reshape(B, T, D))
+
+
+def _einsum(spec, a, b):
+    """Float32 operands multiply in float32, as :class:`Linear`'s do."""
+    dtype, precision = _float32_in_float32(a, b)
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      precision=precision)
+
+
+class LatentAttentionMixer(nn.Module):
+    """Multi-head latent attention::
+
+        c_q = N_q(W_qa x);  [q_nope_h, q_rope_h] = W_qb,h c_q
+        [c, k_r] = split(W_kva x);  c_kv = N_kv(c);  k_r = RoPE(k_r)
+        [k_nope_h, v_h] = W_kvb,h c_kv
+        score_h(t, s) = (q_nope_h(t) . k_nope_h(s)
+                         + RoPE(q_rope_h)(t) . k_r(s)) / sqrt(nope + rope)
+        out = W_o concat_h(softmax_s(score_h) v_h)
+
+    ``k_r`` is ONE rope key for all heads.  What a token leaves in the
+    cache is ``c_kv`` after its norm and ``k_r`` after its rotation.
+    Without pages this is the per-head form as written; a serve step
+    takes the absorbed form, equal in exact arithmetic: ``q_lat_h =
+    W_kvb,K,h^T q_nope_h``, scores against the entries themselves,
+    ``o_h = W_kvb,V,h sum_s p_h c_kv(s)`` (module docstring)."""
+    embed_dim: int
+    num_heads: int
+    spec: LatentSpec
+    eps: float = 1e-6
+    rope_theta: float = 10000.0
+
+    @nn.compact
+    def __call__(self, x, positions=None, paged=None):
+        sp, H = self.spec, self.num_heads
+        L, nope, rope, vd = (sp.kv_lora_rank, sp.qk_nope_head_dim,
+                             sp.qk_rope_head_dim, sp.v_head_dim)
+        lead = x.shape[:-1]
+        scale = (nope + rope) ** -0.5
+        with jax.named_scope("mla_project"):
+            c_q = RMSNorm(sp.q_lora_rank, self.eps, name="q_a_layernorm")(
+                Linear(sp.q_lora_rank, name="q_a_proj")(x))
+            q = Linear(H * (nope + rope), name="q_b_proj")(c_q).reshape(
+                lead + (H, nope + rope))
+            c_kv, k_r = jnp.split(
+                Linear(L + rope, name="kv_a_proj_with_mqa")(x), [L], axis=-1)
+            c_kv = RMSNorm(L, self.eps, name="kv_a_layernorm")(c_kv)
+            q_nope, q_r = q[..., :nope], q[..., nope:]
+            q_r, k_r = apply_rotary_qk(q_r, k_r[..., None, :],
+                                       base=self.rope_theta,
+                                       positions=positions)
+        kv_b = Linear(H * (nope + vd), name="kv_b_proj")
+        out = Linear(self.embed_dim, name="o_proj")
+        lanes = -(-(L + rope) // LATENT_LANES) * LATENT_LANES
+        ready = paged is not None and self.has_variable("pagedkv",
+                                                        "latent_pages")
+        if paged is not None:
+            nslots = None if ready else int(paged.num_slots)
+            pages = self.variable("pagedkv", "latent_pages", jnp.zeros,
+                                  (nslots, lanes), c_kv.dtype)
+        if ready:
+            from unicore_tpu.serve.attention import write_latent_and_attend
+
+            w_kv = kv_b.variables["params"]["kernel"].reshape(
+                L, H, nope + vd)
+            pad = jnp.zeros(lead + (H, lanes - L - rope), q.dtype)
+            with jax.named_scope("mla_project"):
+                q_lat = _einsum("...hn,lhn->...hl", q_nope, w_kv[..., :nope])
+            o_lat = write_latent_and_attend(
+                jnp.concatenate([q_lat, q_r, pad], axis=-1),
+                jnp.concatenate([c_kv, k_r[..., 0, :], pad[..., 0, :]],
+                                axis=-1),
+                pages, paged, positions, scale, value_lanes=L)
+            with jax.named_scope("mla_project"):
+                o = _einsum("...hl,lhv->...hv", o_lat, w_kv[..., nope:])
+        else:
+            from unicore_tpu.utils import causal_iota_mask
+
+            T = x.shape[-2]
+            kv = kv_b(c_kv).reshape(lead + (H, nope + vd))
+            k_nope, v = kv[..., :nope], kv[..., nope:]
+            s = (_einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+                 + _einsum("bqhd,bkd->bhqk", q_r, k_r[..., 0, :])) * scale
+            s = s + causal_iota_mask(T, T)[None, None]
+            p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+            o = _einsum("bhqk,bkhd->bqhd", p, v)
+        return out(o.reshape(lead + (H * vd,)))
 
 
 class LinearAttentionMixer(nn.Module):
@@ -348,23 +474,43 @@ class ExpertFFN(nn.Module):
         scores = jax.nn.sigmoid(jnp.dot(
             tokens.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-        sel, w = moe.route(scores, bias, sp.top_k, sp.scale)
+        sel, w = moe.route(scores, bias, sp.top_k, sp.scale,
+                           **({} if sp.eps is None else {"eps": sp.eps}))
         # a token of the step's list that nobody carries: position -1
         valid = None if paged is None else positions.reshape(-1) >= 0
         y, load = moe.expert_ffn(tokens, valid, w1, w3, w2, sel, w,
-                                 first_expert=sp.first_expert)
+                                 first_expert=sp.first_expert,
+                                 num_experts=sp.num_experts)
+        share = held < sp.num_experts
         ready = paged is not None and self.has_variable("pagedkv",
                                                         "moe_load")
         if paged is not None:
             total = self.variable("pagedkv", "moe_load", jnp.zeros,
-                                  (held,), jnp.int32)
+                                  (sp.num_experts,), jnp.int32)
             touched = self.variable("pagedkv", "moe_touched", jnp.zeros,
                                     (), jnp.int32)
+            if share:
+                landed = self.variable("pagedkv", "moe_held", jnp.zeros,
+                                       (), jnp.int32)
         if ready:
+            held_load = load
+            if share:
+                # the choices that landed on the experts held here, and
+                # the router's histogram over ALL experts
+                landed.value = landed.value + jnp.sum(held_load)
+                chose = sel[..., None] == jnp.arange(sp.num_experts,
+                                                     dtype=sel.dtype)
+                load = jnp.sum(chose & valid[:, None, None], axis=(0, 1),
+                               dtype=jnp.int32)
             total.value = total.value + load
-            touched.value = touched.value + jnp.sum(load > 0,
+            touched.value = touched.value + jnp.sum(held_load > 0,
                                                     dtype=jnp.int32)
-        return y.reshape(x.shape)
+        y = y.reshape(x.shape)
+        if sp.shared_experts:
+            with jax.named_scope("moe_shared_expert"):
+                y = y + GatedFFN(D, sp.shared_experts * sp.ffn_dim,
+                                 name="shared_experts")(x)
+        return y
 
 
 class PatternDecoderLayer(nn.Module):
@@ -384,10 +530,15 @@ class PatternDecoderLayer(nn.Module):
     short_conv_kernel_dim: int = 3
     norm_placement: str = "output"
     experts: Optional[ExpertSpec] = None    # None: the dense FFN
+    latent: Optional[LatentSpec] = None     # a latent_attention layer's
 
     @nn.compact
     def __call__(self, x, positions=None, paged=None):
-        if self.mixer == FULL:
+        if self.mixer == LATENT:
+            mixer = LatentAttentionMixer(
+                self.embed_dim, self.num_heads, self.latent, self.eps,
+                self.rope_theta, name="self_attn")
+        elif self.mixer == FULL:
             mixer = FullAttentionMixer(
                 self.embed_dim, self.num_heads, self.eps, self.kv_heads,
                 self.qk_norm_per_head, self.rope_theta, name="self_attn")
@@ -402,7 +553,7 @@ class PatternDecoderLayer(nn.Module):
                                    self.short_conv_kernel_dim, name="conv")
         else:
             raise ValueError(f"unknown mixer kind {self.mixer!r} (known: "
-                             f"{FULL!r}, {LINEAR!r}, {CONV!r})")
+                             f"{FULL!r}, {LINEAR!r}, {CONV!r}, {LATENT!r})")
         norm = lambda name: RMSNorm(self.embed_dim, self.eps, name=name)
         if self.experts is None:
             ffn = GatedFFN(self.embed_dim, self.ffn_embed_dim,
@@ -416,9 +567,14 @@ class PatternDecoderLayer(nn.Module):
             h = x + norm("post_attention_layernorm")(
                 mixer(x, positions=positions, paged=paged))
             return h + norm("post_feedforward_layernorm")(ffn(h))
+        if self.norm_placement == "both":
+            h = x + norm("post_attention_layernorm")(mixer(
+                norm("input_layernorm")(x), positions=positions, paged=paged))
+            return h + norm("post_mlp_layernorm")(
+                ffn(norm("pre_mlp_layernorm")(h)))
         if self.norm_placement != "input":
             raise ValueError(f"unknown norm placement "
-                             f"{self.norm_placement!r} (output, input)")
+                             f"{self.norm_placement!r} (output, input, both)")
         h = x + mixer(norm("operator_norm")(x), positions=positions,
                       paged=paged)
         return h + ffn(norm("ffn_norm")(h))
@@ -444,6 +600,7 @@ class PatternDecoder(nn.Module):
     norm_placement: str = "output"
     ffn_types: Tuple[str, ...] = ()         # empty: dense everywhere
     experts: Optional[ExpertSpec] = None
+    latent: Optional[LatentSpec] = None
 
     @nn.compact
     def __call__(self, x, positions: Optional[jnp.ndarray] = None,
@@ -457,7 +614,7 @@ class PatternDecoder(nn.Module):
                 self.linear_allow_neg_eigval, self.eps, self.kv_heads,
                 self.qk_norm_per_head, self.rope_theta,
                 self.short_conv_kernel_dim, self.norm_placement,
-                self.experts if sparse else None,
+                self.experts if sparse else None, self.latent,
                 name=f"layers_{i}",
             )(x, positions=positions, paged=paged)
         return RMSNorm(self.embed_dim, self.eps, name="final_layer_norm")(x)

@@ -6,7 +6,9 @@ A layer of ``E`` experts gives every token ``top_k`` of them::
     s   = sigmoid(W_g x)                      E scores, float32
     sel = top_k(s + b)                        b: a bias per expert that
                                               enters the SELECTION only
-    w   = s[sel] / (sum s[sel] + 1e-6)        renormalised, times a scale
+    w   = s[sel] / (sum s[sel] + eps)         renormalised, times a scale
+                                              (eps: the model's, 1e-6
+                                              where it gives none)
     y   = sum_{e in sel} w_e W2_e(silu(W1_e x) * W3_e x)
 
 :func:`route` is the first three lines, :func:`expert_ffn` the last.  No
@@ -35,7 +37,11 @@ step's 32 rows and 18-20 ms against 3.9-4.4 for a mixed step's 512
 and as many as its stacked weights have): the router runs over all ``E``
 and the layer computes its own experts' part of ``y``.  The parts of all
 the shares add up to the whole layer; on one chip that holds them all
-there is one share and no exchange.
+there is one share and no exchange.  A share pays for ITS assignments:
+the sort, the blocks (sized from the ``Eh / E`` of the choices a share
+expects, ``num_experts``) and the loop run over the held experts only,
+and a step in which no held expert got a token runs no block.  What the
+absent experts would add is added by nobody here.
 
 Plain XLA on every backend (``dispatch_report()`` says ``reference``).
 Float32 operands multiply as ``modules/pattern_decoder.py`` ``Linear``
@@ -55,19 +61,20 @@ SUBLANES = 8      # rows of one float32 tile: the least a block can be
 MAX_BLOCK_ROWS = 128
 
 
-def route(scores, bias, top_k, scale=1.0):
+def route(scores, bias, top_k, scale=1.0, eps=1e-6):
     """``scores`` [N, E] float32 (already through the sigmoid), ``bias``
-    [E] or None.  Returns ``(sel [N, top_k] int32, w [N, top_k]
-    float32)``: the experts chosen by ``scores + bias`` (ties as
-    ``jax.lax.top_k`` breaks them: the lower index first) and their
-    weights from ``scores`` alone, renormalised over the chosen."""
+    [E] or None (the scores alone choose).  Returns ``(sel [N, top_k]
+    int32, w [N, top_k] float32)``: the experts chosen by ``scores +
+    bias`` (ties as ``jax.lax.top_k`` breaks them: the lower index first)
+    and their weights from ``scores`` alone, renormalised over the chosen
+    with ``eps`` under the division."""
     with jax.named_scope("moe_router"):
         scores = scores.astype(jnp.float32)
         chosen_by = scores if bias is None else scores + bias.astype(
             jnp.float32)
         _, sel = jax.lax.top_k(chosen_by, top_k)
         w = jnp.take_along_axis(scores, sel, axis=-1)
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
         return sel.astype(jnp.int32), w * scale
 
 
@@ -82,7 +89,10 @@ def pick_block_rows(assignments, experts_held):
     of 32 / 64 / 128, a third-full one 3.94 / 3.96 / 4.47 (7.01 at 8), a
     decode step of 32 rows 3.11 / 3.12 / 3.13 at 8 / 16 / 32 (PERF.md, PR
     31).  A decode step of 32 rows x 4 over 64 experts: 8; a mixed step
-    of 512 tokens: 64."""
+    of 512 tokens: 64.  ``assignments`` are those the held experts can
+    expect: a share of 8 of 256 experts under 8 a token gets 128 of a
+    512-token list's 4,096 (blocks of 32) and 4 of a 16-row decode
+    step's 128 (blocks of 8)."""
     twice = 2 * -(-int(assignments) // max(1, int(experts_held)))
     return max(SUBLANES,
                min(MAX_BLOCK_ROWS, -(-twice // SUBLANES) * SUBLANES))
@@ -95,21 +105,24 @@ def _dot(a, b):
 
 
 def expert_ffn(x, valid, w1, w3, w2, sel, w, first_expert=0,
-               block_rows=None):
+               block_rows=None, num_experts=None):
     """``x`` [N, D] tokens, ``valid`` [N] bool (or None: all), ``w1`` /
     ``w3`` [Eh, D, F] and ``w2`` [Eh, F, D] the stacked weights of the
     ``Eh`` experts held here, which are experts ``first_expert ..
-    first_expert + Eh - 1`` of the layer; ``sel`` / ``w`` [N, top_k]
-    from :func:`route`.  Returns ``(y [N, D], load [Eh] int32)``: this
-    share's part of the layer's output (zero for a token that is not
-    valid or chose no expert held here) and how many valid tokens each
-    held expert got."""
+    first_expert + Eh - 1`` of the layer's ``num_experts`` (None: ``Eh``,
+    all of them); ``sel`` / ``w`` [N, top_k] from :func:`route`.  Returns
+    ``(y [N, D], load [Eh] int32)``: this share's part of the layer's
+    output (zero for a token that is not valid or chose no expert held
+    here) and how many valid tokens each held expert got."""
     N, D = x.shape
     Eh = w1.shape[0]
     k = sel.shape[1]
     A = N * k
-    bm = int(block_rows or pick_block_rows(A, Eh))
-    # the most blocks any load can need: every group's last block ragged
+    # the choices a share can expect: Eh / num_experts of them
+    bm = int(block_rows or pick_block_rows(
+        -(-A * Eh // int(num_experts or Eh)), Eh))
+    # the most blocks any load can need (every choice of every token may
+    # still land here, no token is dropped): every group's last block ragged
     NB = -(-(A + Eh * (bm - 1)) // bm)
     R = NB * bm
     note_dispatch("moe_experts",
@@ -177,5 +190,8 @@ def note_routing(assignments, experts_touched):
 def routing_report():
     """The last 4,096 serve steps of this process, oldest first, as
     ``(assignments, experts_touched)``: token x expert pairs and experts
-    that got a token, each summed over the step's expert layers."""
+    that got a token, each summed over the step's expert layers.  Of an
+    engine whose layers hold a SHARE of their experts: the pairs that
+    landed on held experts and the held experts that got a token, which
+    is the work its expert loops did."""
     return list(_ROUTING)

@@ -31,6 +31,16 @@ unwritten/stale slots, since every real query position is below the
 row's length.  Inactive query columns (position -1) mask everything and
 come out finite (garbage by contract, discarded by the caller).
 
+The rule for float32 operands is the step's: they multiply in three
+bfloat16 passes, as ``Linear`` does outside the kernel at
+``Precision.HIGH`` (``three_pass=True``; the latent step asks for it).
+ONE exception is left: per-head K/V pages (``serve/attention.py``
+``write_and_attend``) take the one pass the kernel had before ``Linear``
+went to ``HIGH``, because every cell that runs them was measured and its
+limits read with it.  Ending the exception is ROADMAP M12's question,
+not a caller's choice: no third arithmetic, and no new caller of the
+one-pass form.
+
 Dispatch (serve/attention.py) gates on ``use_pallas`` and ``supported``
 (a static shape rule).  There is no compile probe: a shape ``supported``
 admits and the chip's compiler refuses fails the serve step's compile,
@@ -84,9 +94,30 @@ def pick_pages_per_block(num_table_pages, page_size, head_dim,
     return pp
 
 
+def _dot(a, b, contract, three_pass):
+    """``a`` contracted with ``b`` over ``contract``, float32 out.  As the
+    kernel always had it, float32 operands take ONE bfloat16 pass (both
+    rounded to 8 bits of mantissa).  ``three_pass``: each float32 operand
+    is split into a bfloat16 head and a bfloat16 remainder and the three
+    products that matter are summed (``hi x hi + hi x lo + lo x hi``:
+    2^-16 of a product, what XLA's ``Precision.HIGH`` does), three times
+    the MXU's work."""
+    dot = lambda x, y: jax.lax.dot_general(  # noqa: E731
+        x, y, ((contract), ((), ())), preferred_element_type=jnp.float32)
+    if not three_pass:
+        return dot(a, b)
+
+    def split(x):
+        hi = x.astype(jnp.bfloat16)
+        return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    (a_hi, a_lo), (b_hi, b_lo) = split(a), split(b)
+    return dot(a_hi, b_hi) + dot(a_hi, b_lo) + dot(a_lo, b_hi)
+
+
 def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
             k_scr, v_scr, m_scr, l_scr, acc_scr, sems, *, page_size,
-            pages_per_block, scale, heads, head_dim):
+            pages_per_block, scale, heads, head_dim, three_pass):
     b = pl.program_id(0)
     length = len_ref[b]
     n_table = pt_ref.shape[1]
@@ -139,10 +170,7 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
                 mine = lane_head == g
                 qh = q if group == 1 else jnp.where(
                     mine, q, jnp.zeros_like(q))
-                s = jax.lax.dot_general(  # [T, S]
-                    qh, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
+                s = _dot(qh, k, ((1,), (1,)), three_pass)  # [T, S]
                 s = jnp.where(valid, s, -1e30)
                 m = m_scr[h]
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -155,10 +183,8 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
                 l_scr[h] = l_scr[h] * alpha + jnp.sum(
                     p, axis=-1, keepdims=True)
                 m_scr[h] = m_new
-                pv = jax.lax.dot_general(  # [T, slab]
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
+                pv = _dot(p.astype(v.dtype), v, ((1,), (0,)),
+                          three_pass)  # [T, slab]
                 # only this head's lanes of pv are its p @ v
                 acc = jnp.where(mine, acc * alpha + pv, acc)
             acc_scr[:, lanes] = acc
@@ -200,10 +226,11 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("page_size", "pages_per_block", "scale", "heads",
-                     "head_dim", "interpret"),
+                     "head_dim", "interpret", "three_pass"),
 )
 def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
-          page_size, pages_per_block, scale, heads, head_dim, interpret):
+          page_size, pages_per_block, scale, heads, head_dim, interpret,
+          three_pass=False):
     bsz, t, hd = q3.shape
     qo_spec = pl.BlockSpec((1, t, hd), lambda b, pt, ln: (b, 0, 0))
     # [B, T, 1]: the block's last two dims are the array's own, which
@@ -233,6 +260,7 @@ def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
         functools.partial(
             _kernel, page_size=page_size, pages_per_block=pages_per_block,
             scale=scale, heads=heads, head_dim=head_dim,
+            three_pass=three_pass,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, t, hd), q3.dtype),
@@ -248,12 +276,14 @@ def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, positions,
                            lengths, *, page_size, scale,
-                           pages_per_block=None):
+                           pages_per_block=None, three_pass=False):
     """Mixed prefill+decode paged attention: q [B, T, H, D], flat pools
     [num_slots, H*D], page_table [B, P] (pad rows with page 0),
     positions [B, T] per-token global positions (-1 = inactive),
     lengths [B] valid token count incl. this step's (0 = inactive row).
-    Returns [B, T, H, D]."""
+    Returns [B, T, H, D].  ``three_pass``: float32 queries against a
+    float32 pool multiply in three bfloat16 passes (``_dot``; the module
+    docstring says who asks); every other operand type takes one."""
     bsz, t, heads, d = q.shape
     num_pages = k_pages.shape[0] // page_size
     if pages_per_block is None:
@@ -269,6 +299,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, positions,
         page_size=page_size, pages_per_block=pages_per_block,
         scale=float(scale), heads=heads, head_dim=d,
         interpret=pallas_interpret(),
+        three_pass=bool(three_pass and q.dtype == jnp.float32
+                        and k_pages.dtype == jnp.float32),
     )
     return out.reshape(bsz, t, heads, d)
 

@@ -232,3 +232,75 @@ def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
     if g > 1:
         o = o.reshape(B, T, g, H // g, D).swapaxes(2, 3).reshape(B, T, H, D)
     return paged.to_tokens(o, q.shape[:2])
+
+
+# query cells one program of the ragged kernel holds in VMEM beside its
+# page blocks: 512 x 640 float32 lanes is 1.3 MB a buffer (queries,
+# outputs, accumulator), and PR 31's 512 cells of 512 lanes compiled where
+# 1,024 did not
+LATENT_QUERY_CELLS = 512
+
+
+def write_latent_and_attend(q, entry, pages, paged, positions, scale,
+                            value_lanes):
+    """One latent-attention layer's paged step, in the ABSORBED form.
+
+    ``entry`` [..., W] is what each of the step's N tokens leaves in the
+    cache (normed latent | rotated rope key | zero lanes up to ``W``),
+    scattered into ``pages`` (a flax variable of collection ``"pagedkv"``,
+    ``[num_slots, W]``) at ``paged.slot_mapping`` before anything is read;
+    ``q`` [..., H, W] are the heads' queries in the entries' own space
+    (``W_kvb,K^T q_nope`` | rotated ``q_rope`` | zeros).  An entry is the
+    key of every head and, in its first ``value_lanes`` lanes, the value:
+    the ragged kernel runs over ONE K/V head of ``W`` lanes with the ``H``
+    heads of a token as ``H`` query cells at its position, handed the one
+    pool as keys and as values (it reads each page twice), and a row's
+    context is never expanded to per-head keys and values.  Returns the
+    heads' sums over the latents, ``[..., H, value_lanes]``; the caller
+    applies ``W_kvb,V`` and ``W_o``.
+
+    A decode step's row is one token: ``H`` cells.  A prefill chunk's
+    ``T x H`` cells do not fit one program's VMEM, so the ``[B, T]``
+    rectangle is cut into TILES of ``t`` tokens (``t x H <=
+    LATENT_QUERY_CELLS``), each a row of the kernel with its row's page
+    table and a length of its own, its last position + 1: a tile stops
+    at its own causal edge and an empty one (every position -1) reads no
+    page.  A one-token row of a mixed step takes a tile like any other
+    row's.  Causal over ``positions``; position -1 masks a cell."""
+    from unicore_tpu.ops.backend import note_dispatch
+
+    W = pages.value.shape[-1]
+    with jax.named_scope("mla_cache_write"):
+        pages.value = pages.value.at[paged.slot_mapping].set(
+            entry.astype(pages.value.dtype).reshape(-1, W))
+    rows, row_positions = paged.to_rows(q), paged.row_positions(positions)
+    B, T, H, _ = rows.shape
+    form = "decode" if T == 1 else "prefill"
+    t = max(1, min(T, LATENT_QUERY_CELLS // H))
+    while T % t:
+        t -= 1
+    tiles = T // t
+    cells = rows.reshape(B * tiles, t * H, 1, W)
+    tile_positions = row_positions.reshape(B * tiles, t)
+    lengths = jnp.max(tile_positions, axis=1) + 1
+    cell_positions = jnp.repeat(tile_positions, H, axis=1)
+    table = jnp.repeat(paged.page_table, tiles, axis=0)
+    pages_per_block = _kernel_ok(cells, pages.value, table, paged.page_size)
+    with jax.named_scope("mla_attend_" + form):
+        if note_dispatch(
+                "latent_attention_" + form,
+                "b%d cells%d lanes%d page%d %s" % (
+                    B * tiles, t * H, W, paged.page_size, q.dtype.name),
+                pages_per_block is not None):
+            from unicore_tpu.ops.pallas import paged_attention as pl_pa
+
+            o = pl_pa.ragged_paged_attention(
+                cells, pages.value, pages.value, table, cell_positions,
+                lengths, page_size=paged.page_size, scale=scale,
+                pages_per_block=pages_per_block, three_pass=True)
+        else:
+            o = paged_attention_reference(
+                cells, pages.value, pages.value, table, cell_positions,
+                lengths, paged.page_size, scale)
+    o = o.reshape(B, T, H, W)[..., :value_lanes]
+    return paged.to_tokens(o, q.shape[:2])

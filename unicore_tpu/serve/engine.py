@@ -56,7 +56,22 @@ the same donated tree (``moe_load``: tokens each expert got,
 :meth:`ServeEngine.moe_stats` reads them on demand.  What a step added to
 their sums comes back with its tokens, two numbers behind them in the one
 array the step fetches anyway, and feeds ``stats["moe_assignments"]`` /
-``["moe_experts_touched"]`` with no transfer of its own.
+``["moe_experts_touched"]`` with no transfer of its own.  A layer that
+holds a SHARE of its experts keeps a third counter, ``moe_held`` (the
+choices that landed on its own experts): ``stats["moe_assignments"]`` is
+then what the router chose over all experts, ``["moe_assignments_held"]``
+what this engine's experts got, and ``moe_touched`` counts held experts.
+
+A model with LATENT attention (``modules/pattern_decoder.py``
+``LatentAttentionMixer``) keeps ``latent_pages`` where another keeps K/V
+pages: one narrow vector a token a layer for all heads.  Pages are
+counted, not measured, so pool, page table, admission, prefix cache and
+its hashes are the same; ``stats["cache_bytes_per_token"]`` says what one
+token holds over all layers.  Both step widths attend in the absorbed
+form (``serve/attention.py``): ``stats["latent_decode_tokens"]`` counts
+the tokens the decode width served (each is one query cell a head),
+``["latent_prefill_tokens"]`` the tokens the prefill width served, a
+one-token row riding a mixed step among them.
 
 Metrics: per-request queue wait and TTFT, and the counters of
 :attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
@@ -268,8 +283,13 @@ class ServeEngine:
             max_waiting=max_waiting, request_retries=request_retries,
         )
         self.pages = self._init_pages()
-        # expert layers that count their routing on the device
-        self.moe_layers = len(self._moe_counters(self.pages)[0])
+        # expert layers that count their routing on the device, and
+        # whether they hold a share of their experts (a third counter)
+        loads, _, held = self._moe_counters(self.pages)
+        self.moe_layers, self.moe_share = len(loads), bool(held)
+        # layers whose cache is a latent page (module docstring)
+        self.latent_layers = len(self._leaves_named(self.pages,
+                                                    "latent_pages"))
         # prefill-chunk width: a prompt is admitted in <= this many
         # tokens per ragged step (bounded-TTFT slices).  0 = the default
         chunk = int(prefill_chunk) or DEFAULT_PREFILL_CHUNK
@@ -348,6 +368,17 @@ class ServeEngine:
             # over the mean as moe_stats() last read it
             "moe_assignments": 0, "moe_experts_touched": 0,
             "moe_load_max_over_mean": 0.0,
+            # the choices that landed on experts held HERE (all of them
+            # unless the layers hold a share)
+            "moe_assignments_held": 0,
+            # what one token holds in the pool over all layers, bytes
+            "cache_bytes_per_token": sum(
+                x.shape[1] * x.dtype.itemsize
+                for name in ("k_pages", "v_pages", "latent_pages")
+                for x in self._leaves_named(self.pages, name)),
+            # latent attention (module docstring): tokens the decode
+            # width served, tokens the prefill width served
+            "latent_decode_tokens": 0, "latent_prefill_tokens": 0,
         }
         # live weight swaps installed via swap_weights (ISSUE 18);
         # _owns_params flips on the first swap — boot params may be
@@ -382,16 +413,21 @@ class ServeEngine:
         )
 
     @staticmethod
-    def _moe_counters(pages):
-        """``(loads, touched)``: the ``moe_load`` and ``moe_touched``
-        leaves of a ``pagedkv`` tree in layer order, empty for a model
-        without expert layers."""
-        found = {"moe_load": [], "moe_touched": []}
-        for path, leaf in jax.tree_util.tree_flatten_with_path(pages)[0]:
-            name = getattr(path[-1], "key", None)
-            if name in found:
-                found[name].append(leaf)
-        return found["moe_load"], found["moe_touched"]
+    def _leaves_named(pages, name):
+        """The leaves of a ``pagedkv`` tree called ``name``, in layer
+        order."""
+        return [leaf for path, leaf
+                in jax.tree_util.tree_flatten_with_path(pages)[0]
+                if getattr(path[-1], "key", None) == name]
+
+    @classmethod
+    def _moe_counters(cls, pages):
+        """``(loads, touched, held)``: the ``moe_load``, ``moe_touched``
+        and ``moe_held`` leaves of a ``pagedkv`` tree in layer order;
+        all empty for a model without expert layers, the last for one
+        whose layers hold all of their experts."""
+        return tuple(cls._leaves_named(pages, name)
+                     for name in ("moe_load", "moe_touched", "moe_held"))
 
     def moe_stats(self):
         """The expert layers' routing counters, read from the device
@@ -402,7 +438,7 @@ class ServeEngine:
         layers}``; None for a model without expert layers.  Also leaves
         the last of these in ``stats["moe_load_max_over_mean"]``, which
         ``load_snapshot`` carries without a read of its own."""
-        loads, touched = self._moe_counters(self.pages)
+        loads, touched, _ = self._moe_counters(self.pages)
         if not loads:
             return None
         loads = [np.asarray(x).astype(np.int64) for x in loads]
@@ -571,9 +607,11 @@ class ServeEngine:
                 ), ok
 
             def moe_sums(pages):
-                loads, touched = self._moe_counters(pages)
-                return jnp.stack([sum(jnp.sum(x) for x in loads),
-                                  sum(touched)])
+                loads, touched, held = self._moe_counters(pages)
+                sums = [sum(jnp.sum(x) for x in loads), sum(touched)]
+                if held:  # a share of the experts: what landed on it
+                    sums.append(sum(held))
+                return jnp.stack(sums)
 
             def step(params, pages, packed):
                 o = self._cut(packed, operands)
@@ -858,9 +896,14 @@ class ServeEngine:
             self.stats["prefills"] += sum(1 for r in rows if not r[4])
             if self.moe_layers:
                 assigned, touched = int(routed[0]), int(routed[1])
+                held = int(routed[2]) if self.moe_share else assigned
                 self.stats["moe_assignments"] += assigned
+                self.stats["moe_assignments_held"] += held
                 self.stats["moe_experts_touched"] += touched
-                moe.note_routing(assigned, touched)
+                moe.note_routing(held, touched)
+            if self.latent_layers:
+                self.stats["latent_decode_tokens" if w == 1
+                           else "latent_prefill_tokens"] += carried
             if w > 1:
                 self.stats["mixed_steps"] += 1
                 self.stats["mixed_tokens_carried"] += carried
@@ -1334,7 +1377,13 @@ class ServeEngine:
         layers is how many experts' weights a step reads);
         ``moe_load_max_over_mean`` (float) the hottest expert's load over
         the mean, as :meth:`moe_stats` last read it from the device (0.0
-        before the first read: the snapshot itself reads nothing)."""
+        before the first read: the snapshot itself reads nothing);
+        ``moe_assignments_held`` (int) the assignments that landed on
+        experts this replica holds (all of them unless its layers hold a
+        share).  ``cache_bytes_per_token`` (int): what one token holds in
+        the pool over all layers (K/V pages, or a latent model's one
+        narrow vector a layer): with ``free_pages`` x the page size, how
+        many tokens of context this replica can still take."""
         sched = self.scheduler
         recent = list(self.decode_ms)[-33:]
         step_ms = float(sorted(recent)[len(recent) // 2]) if recent else 0.0
@@ -1364,6 +1413,9 @@ class ServeEngine:
             "moe_experts_touched": int(self.stats["moe_experts_touched"]),
             "moe_load_max_over_mean": round(
                 float(self.stats["moe_load_max_over_mean"]), 4),
+            "moe_assignments_held": int(self.stats["moe_assignments_held"]),
+            "cache_bytes_per_token": int(
+                self.stats["cache_bytes_per_token"]),
         }
 
     def reclaim_waiting(self, *, include_running=False):
