@@ -202,8 +202,9 @@ def test_lfm2_moe_step_programs_compile(width, one_chip, compiled_kernels):
     packed = jax.ShapeDtypeStruct(
         (eng._packed_size(eng._step_operands(width)),), I32,
         sharding=one_chip)
+    prev = jax.ShapeDtypeStruct((eng._out_size(),), I32, sharding=one_chip)
     compiled = eng._ragged_step_fn(width, "greedy").lower(
-        placed(abstract), placed(eng.pages), packed).compile()
+        placed(abstract), placed(eng.pages), packed, prev).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 1      # the one attention layer
     assert len([l for l in text.splitlines() if " while(" in l]) == 4
@@ -240,8 +241,9 @@ def test_pangu_mla_step_programs_compile(width, one_chip, compiled_kernels):
     packed = jax.ShapeDtypeStruct(
         (eng._packed_size(eng._step_operands(width)),), I32,
         sharding=one_chip)
+    prev = jax.ShapeDtypeStruct((eng._out_size(),), I32, sharding=one_chip)
     compiled = eng._ragged_step_fn(width, "greedy").lower(
-        placed(abstract), placed(eng.pages), packed).compile()
+        placed(abstract), placed(eng.pages), packed, prev).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 5       # a kernel a layer
     assert len([l for l in text.splitlines() if " while(" in l]) == 4

@@ -209,6 +209,9 @@ def test_load_snapshot_is_stable_typed_dict(lm):
         # ISSUE 35: the assignments that landed on experts held here, and
         # what one token holds in the pool over all layers
         "moe_assignments_held": int, "cache_bytes_per_token": int,
+        # ISSUE 37: launches made with a step in flight (how often the
+        # batch was full), sampled tokens dropped for a late end
+        "steps_run_ahead": int, "tokens_overrun": int,
     }
     assert set(snap) == set(want_types), snap
     for k, t in want_types.items():

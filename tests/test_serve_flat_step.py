@@ -304,7 +304,8 @@ def test_two_programs_and_the_warm_up_recipe_meets_both(lm):
                           max_new_tokens=3) for _ in range(2)])
     assert set(eng._step_fns) == {(1, "greedy"), (8, "greedy")}
     # one operand a step beside weights and pool: ONE transfer, whatever
-    # the program
+    # the program (and the array the step before handed back, which is
+    # on the device already)
     B, W, N = eng.max_batch, eng.table_width, eng.mixed_tokens
     eng._input_capture = lambda key, args: packed.__setitem__(
         key[0], [tuple(a.shape) for a in args[2:]])
@@ -324,8 +325,8 @@ def test_two_programs_and_the_warm_up_recipe_meets_both(lm):
     assert len(shapes) > 8, shapes
     assert set(eng._step_fns) == {(1, "greedy"), (8, "greedy")}
     assert {k: f._cache_size() for k, f in eng._step_fns.items()} == sizes
-    assert packed == {1: [(3 * B + B * W + 6 * B,)],
-                      8: [(4 * N + B * W + 6 * B + B * 8,)]}
+    assert packed == {1: [(4 * B + B * W + 6 * B,), (B,)],
+                      8: [(5 * N + B * W + 6 * B + B * 8,), (B,)]}
 
 
 def test_mixed_tokens_is_derived_from_chunk_and_rows(lm, monkeypatch):
@@ -377,9 +378,13 @@ def reference_tokens(params, prompt, tokens):
     return np.argmax(want[len(prompt) - 1:-1], -1).tolist()
 
 
+# ISSUE 37: ``token_src`` closes the list every step packs: for each cell
+# of the token list the row of the step BEFORE that sampled its token
+# (that step's output, still on the device, is the program's fourth
+# input), or -1 for the token the host wrote
 PARENT_OPERANDS = ["tokens", "positions", "page_table", "slot_mapping",
                    "lengths", "last", "seeds", "steps", "temperature",
-                   "top_k"]
+                   "top_k", "token_src"]
 
 
 def test_a_recurrent_model_takes_one_packed_operand_and_returns_one_array(
@@ -413,12 +418,13 @@ def test_a_recurrent_model_takes_one_packed_operand_and_returns_one_array(
                                 request_id=f"r{i}")
                         for i, p in enumerate(prompts)])
     # ONE operand beside weights and pool, whatever the width: tokens,
-    # positions, slot_mapping (and token_cell) over the list, the table,
-    # eight [B] entries with poison and state_slots, the map [B, 16]
+    # positions, slot_mapping, token_src (and token_cell) over the list,
+    # the table, eight [B] entries with poison and state_slots, the map
+    # [B, 16]; and the step before's tokens, which cost no transfer
     assert {w for w, _ in seen} == {1, 16}
     for width, shapes in seen:
-        assert shapes == [(3 * B + B * W + 8 * B,) if width == 1 else
-                          (4 * N + B * W + 8 * B + B * 16,)]
+        assert shapes == [(4 * B + B * W + 8 * B,) if width == 1 else
+                          (5 * N + B * W + 8 * B + B * 16,), (B,)]
     # ... and ONE array back beside the pool: a token a row, -1 where the
     # row's logits were not finite
     assert all(len(out) == 2 and out[0].shape == (B,)
@@ -591,6 +597,55 @@ def test_a_model_without_a_state_packs_no_state_slots(lm):
     poisoned = ServeEngine(model, params, poison_requests=["x"], **ENGINE)
     assert [n for n, _ in poisoned._step_operands(8)] == PARENT_OPERANDS + [
         "poison", "rect_token", "token_cell"]
+
+
+def test_a_step_launched_ahead_takes_its_decode_tokens_from_the_device(lm):
+    """ISSUE 37: with the batch full a step is launched before the tokens
+    of the step before are fetched.  Its decode rows' cells of the list
+    hold no token: ``token_src`` names the row of that step that samples
+    it, and the program's fourth input IS that step's output."""
+    model, params = lm
+    eng = ServeEngine(model, params, **{**ENGINE, "max_batch": 2})
+    seen, outs = [], []
+    eng._input_capture = lambda key, args: seen.append(
+        (key[0], np.asarray(args[2]), args[3]))
+    real = eng._ragged_step_fn
+
+    def spying(width, sampling):
+        fn = real(width, sampling)
+
+        def call(*args):
+            out = fn(*args)
+            outs.append(out[0])
+            return out
+
+        return call
+
+    eng._ragged_step_fn = spying
+    rng = np.random.default_rng(4)
+    prompts = [prompt_of(rng, 5), prompt_of(rng, 7)]
+    res = eng.generate([Request(prompt=p, max_new_tokens=5)
+                        for p in prompts])
+    for p, r in zip(prompts, res):
+        assert r.tokens == solo_greedy(model, params, p, 5)
+    # two rows, two requests: every step but the first is launched ahead,
+    # until the last tokens are known to be the last
+    assert eng.stats["steps_run_ahead"] == len(seen) - 1 == 4
+    assert [w for w, _, _ in seen] == [8, 1, 1, 1, 1]
+    for i, (width, packed, prev) in enumerate(seen):
+        o = eng._cut(packed, eng._step_operands(width))
+        if i == 0:      # the prompts: the host wrote every token
+            assert (o["token_src"] == -1).all()
+            assert not np.asarray(prev).any()
+            continue
+        # row b's one token is the one row b of the step before samples
+        assert o["token_src"].tolist() == [0, 1]
+        assert o["tokens"].tolist() == [[0, 0]]
+        assert o["steps"].tolist() == [i, i]        # the sampling index
+        assert prev is outs[i - 1]
+    # still one program a width: the fourth input has one shape
+    assert {k: f._cache_size() for k, f in eng._step_fns.items()} == {
+        (1, "greedy"): 1, (8, "greedy"): 1}
 
 
 # -- (f) the engine owns the last-token contract ----------------------------

@@ -386,7 +386,8 @@ def test_a_model_without_experts_counts_nothing_and_fetches_as_before():
     out = jax.eval_shape(
         eng._ragged_step_fn(1, "greedy"), eng.params, eng.pages,
         jax.ShapeDtypeStruct(
-            (eng._packed_size(eng._step_operands(1)),), jnp.int32))[0]
+            (eng._packed_size(eng._step_operands(1)),), jnp.int32),
+        jax.ShapeDtypeStruct((eng._out_size(),), jnp.int32))[0]
     assert out.shape == (4,)              # the tokens and nothing behind
 
 

@@ -2,7 +2,10 @@
 read back from a real profiler trace of a tiny engine on the CPU: every
 span is there, children lie inside their parents, a step that had
 nothing to do records nothing, and a fault inside a step leaves no span
-open."""
+open.  With a full batch (ISSUE 37) a call launches the next step before
+it fetches the one in flight: the names stay, ``serve/launch`` comes
+before ``serve/fetch`` inside one ``serve/dispatch-w<n>``, and n is the
+width LAUNCHED in that call."""
 
 import re
 
@@ -20,13 +23,16 @@ from unicore_tpu.serve.engine import ServeEngine
 V = 29
 CHUNK = 4
 DISPATCH = re.compile(r"^serve/dispatch-w(\d+)$")
-# child -> parent; the per-width dispatch spans are matched by DISPATCH
+# child -> parents it may lie in; the per-width dispatch spans are matched
+# by DISPATCH.  A call that only settles a step in flight fetches with no
+# dispatch span around it: nothing was launched
 PARENT = {
-    "serve/schedule": "serve/step", "serve/admit": "serve/schedule",
-    "serve/plan": "serve/step", "serve/assemble": "serve/step",
-    "serve/transfer": "serve/step", "serve/dispatch": "serve/step",
-    "serve/launch": "serve/dispatch", "serve/fetch": "serve/dispatch",
-    "serve/emit": "serve/step",
+    "serve/schedule": ("serve/step",), "serve/admit": ("serve/schedule",),
+    "serve/plan": ("serve/step",), "serve/assemble": ("serve/step",),
+    "serve/transfer": ("serve/step",), "serve/dispatch": ("serve/step",),
+    "serve/launch": ("serve/dispatch",),
+    "serve/fetch": ("serve/dispatch", "serve/step"),
+    "serve/emit": ("serve/step",),
 }
 EVERY_STEP = ("serve/schedule", "serve/plan", "serve/assemble",
               "serve/transfer", "serve/launch", "serve/fetch", "serve/emit")
@@ -93,12 +99,13 @@ def assert_nested(spans):
     steps = by_family["serve/step"]
     assert all(e1 <= s2 for (_, e1), (s2, _) in zip(steps, steps[1:]))
     for name, a, b in spans:
-        parent = PARENT.get(family(name))
-        if parent is None:
+        parents = PARENT.get(family(name))
+        if parents is None:
             assert name == "serve/step", name
             continue
-        assert any(pa <= a and b <= pb for pa, pb in by_family[parent]), \
-            f"{name} [{a}, {b}] lies in no {parent}"
+        assert any(pa <= a and b <= pb for parent in parents
+                   for pa, pb in by_family.get(parent, ())), \
+            f"{name} [{a}, {b}] lies in no {parents}"
 
 
 def steps_holding(spans, pattern):
@@ -131,6 +138,65 @@ def test_every_span_of_the_tree_is_recorded_and_nested(lm, tmp_path):
     widths = [int(DISPATCH.match(n).group(1)) for n in names
               if DISPATCH.match(n)]
     assert widths == dispatched and set(widths) == {1, CHUNK}
+    assert engine.stats["decode_steps"] == len(engine.decode_ms) > 0
+
+
+def test_a_call_that_runs_ahead_launches_before_it_fetches(lm, tmp_path):
+    """Two rows, two requests: the batch is full, so every call but the
+    first launches behind a step in flight."""
+    model, params = lm
+    engine = ServeEngine(model, params, num_pages=16, page_size=4,
+                         max_batch=2, prefill_chunk=CHUNK)
+    dispatched = count_dispatches(engine)
+    # "long" samples its first token a step after "short": both end with
+    # the sixth step
+    engine.submit([Request(prompt=[3, 7, 2, 9, 4, 6], max_new_tokens=5,
+                           request_id="long"),
+                   Request(prompt=[11, 5], max_new_tokens=6,
+                           request_id="short")])
+
+    def drive():
+        while engine.serve_step():
+            pass
+
+    spans = traced(tmp_path, drive)
+    names = [n for n, _, _ in spans]
+    assert_nested(spans)
+    assert engine.stats["steps_run_ahead"] == len(dispatched) - 1 == 5
+    for name in ("serve/step", "serve/admit") + EVERY_STEP:
+        assert name in names, name
+    # a dispatch span a launch, named by the width LAUNCHED in that call
+    widths = [int(DISPATCH.match(n).group(1)) for n in names
+              if DISPATCH.match(n)]
+    assert widths == dispatched and set(widths) == {1, CHUNK}
+    assert names.count("serve/launch") == len(dispatched)
+    # every launched step is fetched and emitted once, one a call at most
+    assert names.count("serve/fetch") == names.count("serve/emit") \
+        == len(dispatched)
+    assert max(steps_holding(spans, "serve/emit")) == 1
+    by = {k: [(a, b) for n, a, b in spans if n == k]
+          for k in ("serve/launch", "serve/fetch", "serve/emit")}
+    holds = lambda outer, inner: [
+        (a, b) for a, b in inner if outer[0] <= a and b <= outer[1]]
+    dispatches = [(a, b) for n, a, b in spans if DISPATCH.match(n)]
+    # the first call launches and leaves its step in flight
+    assert holds(dispatches[0], by["serve/fetch"]) == []
+    for d in dispatches[1:]:
+        # launch of N+1, THEN the fetch of N, inside the one span
+        [launch], [fetch] = (holds(d, by["serve/launch"]),
+                             holds(d, by["serve/fetch"]))
+        assert launch[1] <= fetch[0]
+    # and the last step's tokens come from a call that launches nothing:
+    # schedule, fetch, emit
+    steps = [(a, b) for n, a, b in spans if n == "serve/step"]
+    assert len(steps) == len(dispatched) + 1
+    last = [family(n) for n, a, b in spans
+            if steps[-1][0] <= a and b <= steps[-1][1]]
+    assert last == ["serve/step", "serve/schedule", "serve/fetch",
+                    "serve/emit"]
+    # the first decode launch waited for the MIXED step's tokens: the
+    # span's name is the width launched, not the width fetched
+    assert widths[:3] == [CHUNK, CHUNK, 1]
     assert engine.stats["decode_steps"] == len(engine.decode_ms) > 0
 
 

@@ -73,6 +73,74 @@ the tokens the decode width served (each is one query cell a head),
 ``["latent_prefill_tokens"]`` the tokens the prefill width served, a
 one-token row riding a mixed step among them.
 
+THE STEP IN FLIGHT.  The device starts a step when the host launches
+it, and a host that fetches step N's tokens before it plans, assembles,
+transfers and launches step N+1 leaves the device idle for all of that,
+every step.  So ``serve_step`` may return with ONE launched step whose
+tokens it has not fetched; the next call launches step N+1 first and
+fetches and emits step N after, so that emit, the caller's own work
+between two calls, schedule, plan, assemble, transfer and the launch's
+latency all lie under the device time of a step already queued.  What
+makes it possible:
+
+- *The step program takes its decode tokens from the device.*  Its
+  fourth input is the output array of the step before (zeros when
+  nothing is in flight), and the packed operand ``token_src`` says, for
+  each cell of the token list, which row of that step sampled its token
+  (-1: the token the host wrote).  A gather of int32 ids: every row's
+  arithmetic is the synchronous engine's.
+- *A sequence knows what it has in flight* (``Sequence.in_flight``, 0 or
+  1 sampled tokens; ``Sequence.launched``, KV positions past
+  ``prefilled``).  Whatever PLANS a step reads a sequence through
+  ``length()`` / ``written()``: decode readiness, the rows, the page the
+  next step writes, the sampling step index.  ``prefilled``,
+  ``register_prefix``, ``generated``, ``first_token_at``, the counters
+  and the routing record move at EMIT, in step order, as they always did.
+- *The rule*, from what the engine observes and nothing else.  Every
+  call first does what cannot wait: deadline expiry and a drain's
+  sheds.  Then, with a step in flight, it launches N+1 before fetching
+  N if and only if (a) the batch is full — every one of ``max_batch``
+  rows is held by a running sequence that goes on past what has been
+  launched (an end known ahead, ``max_new_tokens`` or ``max_context``,
+  frees its row, and so does whoever just expired or was shed) — which
+  is ``Scheduler.admit``'s own test, so no request that arrived
+  meanwhile could have been admitted into N+1 and nobody's time to first
+  token pays; and (b) the plan is plain continuation: no drain under
+  way, no waiting head to fail for capacity, no page whose extension
+  needs an eviction, no chaos preemption configured, the split
+  ``unified=False`` baseline not in use.  Otherwise the call SETTLES: it
+  fetches and emits N and returns, and the next call schedules, admits
+  and launches exactly as a synchronous engine would.  A synchronous
+  step is the same code with nothing in flight: launch, then (the batch
+  not full) fetch and emit.  There is no second path and nothing to
+  configure; a call emits one step at most.
+- *Ends that are known late.*  ``eos_id``, a row of nonfinite logits
+  (token -1), a deadline blown and a drain's shed are found after N+1
+  was launched with that sequence's row.  The row's token is an OVERRUN: at
+  N+1's emit it is dropped and counted (``stats["tokens_overrun"]``), so
+  the sequence's result is the synchronous engine's, ``finish_reason``
+  included; a sequence preempted by hand between two calls is told by
+  its ``evictions`` and treated alike (its token is sampled again after
+  the re-prefill).  The overrun WRITE is harmless: it lands in a page,
+  and for a recurrent model a state slot, that the sequence still owned
+  when N+1 was launched.  Pages and slots freed at N's emit can only be
+  handed to rows of N+2, which the device runs after N+1.  A registered
+  prefix covers full PROMPT pages only, and an overrun writes position
+  ``len(prompt) + len(generated)``, past every one of them.  A
+  recurrent state's next owner starts at position 0 and is zeroed in its
+  own step.  The expert layers' device counters do count an overrun
+  row's routing: it was routed.
+- *Whoever reads the engine between two steps settles first*:
+  ``generate()`` returns with nothing in flight, ``has_work()`` is true
+  while a step is, ``moe_stats()``, ``swap_weights()`` (the step in
+  flight ran on the old weights), ``reclaim_waiting(include_running=
+  True)``, ``reopen()``, a host fault and the ``PoolExhausted`` recovery
+  all hand out its tokens before they act.  ``collect_finished()`` hands
+  out what has been emitted.
+
+``stats["steps_run_ahead"]`` counts the launches made with a step in
+flight, beside the step counts: over them, how often the rule held.
+
 Metrics: per-request queue wait and TTFT, and the counters of
 :attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
 occupancy, prefix-cache hits), which the JSON report and the fleet
@@ -87,7 +155,8 @@ annotation costs well under a microsecond::
 
     serve/step                one scheduler iteration that had work
       serve/schedule          expiry, drain, capacity fail-fast, admission,
-                              chaos preemption, prepare_decode
+                              chaos preemption, prepare_decode; behind a
+                              step in flight: the rule
         serve/admit           Scheduler.admit: prefix match, can_alloc, alloc
       serve/plan              _plan_rows
       serve/assemble          the numpy rows of one dispatch
@@ -95,13 +164,23 @@ annotation costs well under a microsecond::
                               looked up, rows starting from zero counted
       serve/transfer          the step's operands onto the device: one
                               packed vector
-      serve/dispatch-w<n>     the compiled step at width n, until the
-                              sampled tokens are on the host
+      serve/dispatch-w<n>     the compiled step at width n LAUNCHED in this
+                              call, and the fetch the call waits in
         serve/launch          the compiled call returning
         serve/fetch           the sampled tokens to the host (-1: a row
-                              of nonfinite logits)
+                              of nonfinite logits): this step's or, in a
+                              call that ran ahead, the step's before
       serve/emit              counters, quarantine, prefill watermark,
-                              register_prefix, _emit
+                              register_prefix, _emit, of the fetched step
+
+A synchronous call's ``serve/dispatch-w<n>`` is one step's launch until
+its tokens are on the host: about its device time.  In a call that ran
+ahead it is how long the host STOOD in the launch of N+1 and the fetch
+of N, which is the rest of N's device time and no step's whole; n is
+the width launched (what ``width_fn`` returned in that call).  A call
+that launches and leaves its step in flight records launch and no fetch
+or emit; a call that only settles records ``serve/step`` { ``serve/
+schedule``, ``serve/fetch``, ``serve/emit`` } and no dispatch span.
 
 Robustness (ISSUE 7), layered on the ``resilience/`` machinery:
 
@@ -219,6 +298,25 @@ class StepCompileError(RuntimeError):
     per-request fault isolation."""
 
 
+class _SettleFirst(Exception):
+    """A row's assembly failed while a step was in flight: the launch is
+    given up, and the next call assembles the row again with that
+    step's tokens emitted (``ServeEngine._dispatch``)."""
+
+
+@dataclasses.dataclass
+class _Launched:
+    """A launched step whose tokens the host has not fetched."""
+    out: object        # the step's one output array, on the device
+    rows: list         # the planned rows that went in; row b is rows[b]
+    epochs: list       # each row's ``seq.evictions`` at launch
+    row_of: dict       # sid -> the row that samples its next token
+    width: int
+    carried: int       # tokens the list carried
+    launched_at: float
+    seconds: float = 0.0  # launch (or the step before done) to fetched
+
+
 DEFAULT_PREFILL_CHUNK = 32
 # the mixed step's token list, in TOKENS whatever the chunk and whatever
 # the model: the best of 256 / 512 / 1024 on the chip in both opt_1.3b
@@ -315,6 +413,14 @@ class ServeEngine:
         # (donates) the pages — tools/unicore_determinism.py captures
         # host copies here and replays them twice
         self._input_capture = None
+        # the step in flight (module docstring): launched, its tokens
+        # not fetched; the zeros a step launched behind nothing takes in
+        # its output's place (no ``token_src`` points at them); when the
+        # last fetch came back; steps emitted so far
+        self._in_flight = None
+        self._no_prev = jnp.zeros((self._out_size(),), jnp.int32)
+        self._fetched_at = 0.0
+        self._steps_emitted = 0
         # one host clock for enqueue stamps, TTFT, deadlines, and the
         # drain timer — injectable so deadline/drain tests are exact
         self._clock = clock or time.perf_counter
@@ -362,6 +468,11 @@ class ServeEngine:
             # width, the tokens they carried, mixed_tokens x dispatches
             "mixed_steps": 0, "mixed_tokens_carried": 0,
             "mixed_tokens_capacity": 0,
+            # the step in flight: launches made before the fetch of the
+            # step before, and sampled tokens dropped because their
+            # sequence had ended (or was preempted) by the time they
+            # came back
+            "steps_run_ahead": 0, "tokens_overrun": 0,
             # a model with sparse experts (module docstring): token x
             # expert assignments and experts that got a token, summed
             # over expert layers and steps; the hottest expert's load
@@ -437,7 +548,10 @@ class ServeEngine:
         expert's load over its layer's mean, the largest over the
         layers}``; None for a model without expert layers.  Also leaves
         the last of these in ``stats["moe_load_max_over_mean"]``, which
-        ``load_snapshot`` carries without a read of its own."""
+        ``load_snapshot`` carries without a read of its own.  Settles a
+        step in flight first, so the device's sums and the host's
+        counters cover the same steps."""
+        self._settle()
         loads, touched, _ = self._moe_counters(self.pages)
         if not loads:
             return None
@@ -502,15 +616,18 @@ class ServeEngine:
         """``[(name, shape), ...]`` of the per-dispatch operands of the
         step program at ``width``, in the order the step finds them in
         its one packed vector.  All are 32 bits wide: ``temperature`` is
-        float32, ``poison`` a flag, the rest int32.  A model that holds
-        a recurrent state gets one operand more, its rows' state
-        slots."""
+        float32, ``poison`` a flag, the rest int32.  ``token_src`` says,
+        for each cell of the token list, which row of the step BEFORE
+        sampled its token (that step's output is the program's fourth
+        input, still on the device), or -1 for the token the host wrote.
+        A model that holds a recurrent state gets one operand more, its
+        rows' state slots."""
         B, n = self.max_batch, self._step_tokens(width)
         ops = [("tokens", (1, n)), ("positions", (1, n)),
                ("page_table", (B, self.table_width)),
                ("slot_mapping", (n,)), ("lengths", (B,)), ("last", (B,)),
                ("seeds", (B,)), ("steps", (B,)), ("temperature", (B,)),
-               ("top_k", (B,))]
+               ("top_k", (B,)), ("token_src", (n,))]
         if self._chaos_poison:
             ops.append(("poison", (B,)))
         if self.recurrent:
@@ -522,6 +639,13 @@ class ServeEngine:
     @staticmethod
     def _packed_size(operands):
         return sum(math.prod(shape) for _, shape in operands)
+
+    def _out_size(self):
+        """Entries of the one array a step hands back: a token a row
+        and, behind them, what the step added to the expert layers'
+        counters."""
+        return self.max_batch + (
+            0 if not self.moe_layers else 3 if self.moe_share else 2)
 
     @staticmethod
     def _cut(packed, operands):
@@ -568,7 +692,10 @@ class ServeEngine:
         int32 vector, ``_step_operands`` end to end (one transfer a
         step, not one an operand), and are cut apart here; the sampled
         tokens go back as one too, -1 where a row's logits were not
-        finite."""
+        finite.  ``prev`` is that array of the step BEFORE (zeros when
+        nothing is in flight; never donated: the host fetches it after
+        this step is launched): a cell whose ``token_src`` is not -1
+        takes its token from there, an exact gather of int32 ids."""
         key = (width, sampling)
         fn = self._step_fns.get(key)
         if fn is None:
@@ -613,10 +740,19 @@ class ServeEngine:
                     sums.append(sum(held))
                 return jnp.stack(sums)
 
-            def step(params, pages, packed):
+            def step(params, pages, packed, prev):
                 o = self._cut(packed, operands)
                 o["temperature"] = jax.lax.bitcast_convert_type(
                     o["temperature"], jnp.float32)
+                src = o.pop("token_src")
+                # a row of nonfinite logits left -1 there: its sequence is
+                # quarantined when that step is emitted, and this row's
+                # token dropped with it
+                o["tokens"] = jnp.where(
+                    src >= 0,
+                    jnp.maximum(jnp.take(
+                        prev[:self.max_batch], src, mode="clip"), 0),
+                    o["tokens"][0])[None]
                 if "poison" in o:
                     o["poison"] = o["poison"] != 0
                 before = pages
@@ -663,8 +799,9 @@ class ServeEngine:
         for w in widths:
             packed = jax.ShapeDtypeStruct(
                 (self._packed_size(self._step_operands(w)),), jnp.int32)
+            prev = jax.ShapeDtypeStruct((self._out_size(),), jnp.int32)
             traced = self._ragged_step_fn(w, sampling).trace(
-                params, pages, packed)
+                params, pages, packed, prev)
             arts[f"ragged-w{w}"] = {
                 "jaxpr": traced.jaxpr, "lowered": traced.lower(),
             }
@@ -687,7 +824,9 @@ class ServeEngine:
             f"waiting={len(sched.waiting)} running={len(sched.running)} "
             f"prefills={self.stats['prefills']} "
             f"decode_steps={self.stats['decode_steps']} "
-            f"pool_free_pages={self.pool.num_free_pages}"
+            f"pool_free_pages={self.pool.num_free_pages} in_flight="
+            + ("none" if self._in_flight is None
+               else f"ragged-w{self._in_flight.width}")
         )
 
     def _poison_row(self, seq):
@@ -709,8 +848,8 @@ class ServeEngine:
     def _is_decode_ready(seq):
         """A sequence whose only missing KV is its newest generated
         token (steady-state decode) vs one still advancing prefill."""
-        return (bool(seq.generated)
-                and seq.prefilled == len(seq.prefix()) - 1)
+        return (bool(seq.generated or seq.in_flight)
+                and seq.written() == seq.length() - 1)
 
     def _plan_rows(self, seqs):
         """Assign this step's batch rows: ``[(seq, start, m, emit,
@@ -741,16 +880,16 @@ class ServeEngine:
         prefilling = []
         for seq in seqs:
             if self._is_decode_ready(seq):
-                rows.append((seq, seq.prefilled, 1, True, True))
+                rows.append((seq, seq.written(), 1, True, True))
             else:
-                prefilling.append([seq, seq.prefilled])
+                prefilling.append([seq, seq.written()])
         budget = self.mixed_tokens - len(rows)
         while prefilling and len(rows) < self.max_batch and budget > 0:
             for entry in list(prefilling):
                 if len(rows) >= self.max_batch or budget <= 0:
                     break
                 seq, start = entry
-                total = len(seq.prefix())
+                total = seq.length()
                 m = min(self.prefill_chunk, total - start, budget)
                 rows.append((seq, start, m, start + m == total, False))
                 budget -= m
@@ -763,9 +902,11 @@ class ServeEngine:
 
     def _dispatch(self, rows):
         """ONE ragged step over planned ``rows`` (mixed prefill-chunk
-        and decode rows): build the per-row metadata, run the unified
-        compiled program, advance each sequence's prefill watermark,
-        emit or quarantine.
+        and decode rows): build the per-row metadata, LAUNCH the unified
+        compiled program, then fetch and emit the step that is due: the
+        one in flight where there is one (this launch then ran ahead of
+        its tokens), else this one, unless the batch is full and it
+        stays in flight for the next call (module docstring).
 
         Row ASSEMBLY faults stay per-request: the host work most likely
         to be poisoned by one bad request's state (slot lookups, prefix
@@ -773,7 +914,11 @@ class ServeEngine:
         sequence — the unified dispatch must not widen a single
         request's blast radius from 1 to ``max_batch`` (the per-seq
         isolation the old split prefill path had).  Only a fault in the
-        compiled call itself still fails the whole in-flight batch."""
+        compiled call itself still fails the whole in-flight batch.
+        Behind a step in flight a row's fault aborts the launch instead
+        (:class:`_SettleFirst`): the next call meets it with that step's
+        tokens emitted, as a synchronous engine would."""
+        prev = self._in_flight
         with _span(SPAN_ASSEMBLE):
             B = self.max_batch
             w = self.width_fn(max(m for _, _, m, _, _ in rows))
@@ -793,6 +938,7 @@ class ServeEngine:
             positions = o["positions"].reshape(-1)
             positions[:] = -1
             slot_mapping = o["slot_mapping"]  # 0 = trash slot
+            o["token_src"][:] = -1
             if flat:
                 o["rect_token"][:] = N
             if self.recurrent:
@@ -807,7 +953,6 @@ class ServeEngine:
                 b, at = len(live), carried
                 mine = slice(at, at + m)
                 try:
-                    prefix = seq.prefix()
                     ptable = np.asarray(self.pool.page_table(seq.sid),
                                         np.int32)
                     pos = np.arange(start, start + m)
@@ -817,7 +962,12 @@ class ServeEngine:
                             f"position {start + m - 1} beyond the "
                             f"{len(ptable)} page(s) of sequence {seq.sid!r}"
                         )
-                    tokens[mine] = prefix[start:start + m]
+                    if seq.in_flight:
+                        # a decode row whose token the step in flight is
+                        # still sampling: the device hands it over
+                        o["token_src"][at] = prev.row_of[seq.sid]
+                    else:
+                        tokens[mine] = seq.prefix()[start:start + m]
                     positions[mine] = pos
                     o["page_table"][b, :len(ptable)] = ptable
                     # a chunk's write slots, vectorized: one table fetch per
@@ -835,10 +985,12 @@ class ServeEngine:
                     o["temperature"][b] = seq.req.temperature
                     o["top_k"][b] = seq.req.top_k
                     o["seeds"][b] = seq.req.seed
-                    o["steps"][b] = len(seq.generated)
+                    o["steps"][b] = len(seq.generated) + seq.in_flight
                     if self._chaos_poison:
                         o["poison"][b] = self._poison_row(seq)
                 except Exception as exc:  # noqa: BLE001 - per-row isolation
+                    if prev is not None:
+                        raise _SettleFirst() from exc
                     # scrub the half-written row (trash-slot defaults) and
                     # fail ONLY this sequence
                     tokens[mine] = 0
@@ -864,17 +1016,20 @@ class ServeEngine:
             return
         sampling = self._sampling_mode([r[0] for r in rows])
         with _span(SPAN_TRANSFER):
-            args = [self.params, self.pages, jnp.asarray(packed)]
-        any_decode = any(r[4] for r in rows)
+            args = [self.params, self.pages, jnp.asarray(packed),
+                    self._no_prev if prev is None else prev.out]
         if self._input_capture is not None:
             # determinism-harness capture: before the call — the jit
             # donates the pages (argnums 1), so the buffers are gone
             # the moment it is issued
             self._input_capture((w, sampling), args)
-        t0 = time.perf_counter()
         step_fn = self._ragged_step_fn(w, sampling)
-        with _span(_dispatch_span(w)), self._armed(f"serve/ragged-w{w}"):
+        # the call that waits is the fetch: of the step in flight where
+        # there is one, and the watchdog names THAT step
+        waits = w if prev is None else prev.width
+        with _span(_dispatch_span(w)), self._armed(f"serve/ragged-w{waits}"):
             with _span(SPAN_LAUNCH):
+                t0 = time.perf_counter()
                 try:
                     out, self.pages = step_fn(*args)
                 except Exception as exc:
@@ -885,15 +1040,85 @@ class ServeEngine:
                         f"first call (trace, lowering or compile): {exc}"
                     ) from exc
                 self._step_ran.add((w, sampling))
-            with _span(SPAN_FETCH):
-                # host sync: the scheduler needs the tokens, and which
-                # rows sampled from finite logits (a token of -1: not)
-                toks = np.asarray(out)
-                toks, routed = toks[:B], toks[B:]
-                ok = toks >= 0
-        dt = time.perf_counter() - t0
+            step = self._in_flight = _Launched(
+                out=out, rows=rows, epochs=[r[0].evictions for r in rows],
+                row_of={r[0].sid: b for b, r in enumerate(rows) if r[3]},
+                width=w, carried=carried, launched_at=t0)
+            for seq, _, m, emit, _ in rows:
+                seq.launched += m
+                seq.in_flight += emit
+            if prev is not None:
+                self.stats["steps_run_ahead"] += 1
+            # the step whose tokens this call hands out: the one that
+            # was in flight, else this one unless the batch is full
+            due = prev or (None if self._batch_full() else step)
+            if due is not None:
+                toks = self._fetch(due)
+        if due is not None:
+            self._emit_step(due, toks)
+
+    def _batch_full(self):
+        """The rule's observable (module docstring): every row is held
+        by a sequence that goes on past what has been launched, so
+        ``Scheduler.admit`` could let nobody into the next step
+        (``len(running) < max_batch`` is its test) whoever arrives
+        meanwhile.  A sequence whose token in flight is known to be its
+        last (``max_new_tokens``, ``max_context``) frees its row.  Never
+        with the split A/B baseline, nor under chaos preemption, whose
+        draws a step in flight would move."""
+        sched = self.scheduler
+        return (self.unified and len(sched.running) >= self.max_batch
+                and not (sched.chaos_rate > 0 and sched.chaos_rng is not None)
+                and not any(
+                    s.in_flight and (
+                        len(s.generated) + 1 >= s.req.max_new_tokens
+                        or s.length() > self.max_context)
+                    for s in sched.running))
+
+    def _fetch(self, step):
+        """The tokens of a launched step onto the host: the one call
+        that waits for the device."""
+        with _span(SPAN_FETCH):
+            # host sync: the scheduler needs the tokens, and which
+            # rows sampled from finite logits (a token of -1: not)
+            toks = np.asarray(step.out)
+        t1 = time.perf_counter()
+        # the device took it up when it was launched or, launched ahead,
+        # when the step before it was done
+        step.seconds = t1 - max(step.launched_at, self._fetched_at)
+        self._fetched_at = t1
+        if step is self._in_flight:
+            self._in_flight = None
+        return toks
+
+    def _settle(self):
+        """Fetch and emit the step in flight, if there is one: what
+        every reader of the engine between two steps does first."""
+        step = self._in_flight
+        if step is not None:
+            with self._armed(f"serve/ragged-w{step.width}"):
+                toks = self._fetch(step)
+            self._emit_step(step, toks)
+        return step is not None
+
+    def _emit_step(self, step, toks):
+        """A fetched step into the scheduler's state: counters,
+        quarantine, prefill watermark, ``register_prefix``, ``_emit``,
+        in row order.  A row whose sequence ended or was preempted after
+        the step was launched is dropped: its token is an OVERRUN
+        (module docstring), counted and never emitted."""
         with _span(SPAN_EMIT):
-            self.stats["prefills"] += sum(1 for r in rows if not r[4])
+            self._steps_emitted += 1
+            B, w, dt = self.max_batch, step.width, step.seconds
+            toks, routed = toks[:B], toks[B:]
+            ok = toks >= 0
+            rows = []
+            for b, (row, epoch) in enumerate(zip(step.rows, step.epochs)):
+                if row[0].done or row[0].evictions != epoch:
+                    self.stats["tokens_overrun"] += row[3]
+                else:
+                    rows.append((b, row))
+            self.stats["prefills"] += sum(1 for _, r in rows if not r[4])
             if self.moe_layers:
                 assigned, touched = int(routed[0]), int(routed[1])
                 held = int(routed[2]) if self.moe_share else assigned
@@ -903,26 +1128,28 @@ class ServeEngine:
                 moe.note_routing(held, touched)
             if self.latent_layers:
                 self.stats["latent_decode_tokens" if w == 1
-                           else "latent_prefill_tokens"] += carried
+                           else "latent_prefill_tokens"] += step.carried
             if w > 1:
                 self.stats["mixed_steps"] += 1
-                self.stats["mixed_tokens_carried"] += carried
-                self.stats["mixed_tokens_capacity"] += N
-            if any_decode:
+                self.stats["mixed_tokens_carried"] += step.carried
+                self.stats["mixed_tokens_capacity"] += self._step_tokens(w)
+            if any(r[4] for _, r in rows):
                 self.stats["decode_time_s"] += dt
                 self.decode_ms.append(dt * 1e3)
                 self.stats["decode_steps"] += 1
-                self.stats["decode_tokens"] += sum(1 for r in rows if r[4])
+                self.stats["decode_tokens"] += sum(
+                    1 for _, r in rows if r[4])
                 if self.progress_path:
                     with open(self.progress_path, "a") as fh:
                         fh.write(f"{self.stats['decode_steps']}\n")
-            for b, (seq, start, m, emit, _) in enumerate(rows):
+            for b, (seq, start, m, emit, _) in rows:
                 if seq.done:
                     continue  # quarantined through an earlier row this step
                 if not bool(ok[b]):
                     self._quarantine(seq, f"ragged-w{w}")
                     continue
                 seq.prefilled = start + m  # rows per seq are ascending
+                seq.launched -= m
                 if (not seq.prefix_registered
                         and seq.prefilled >= len(seq.req.prompt)):
                     # the prompt's KV is fully written: index its full
@@ -930,6 +1157,7 @@ class ServeEngine:
                     self.pool.register_prefix(seq.sid, seq.req.prompt)
                     seq.prefix_registered = True
                 if emit:
+                    seq.in_flight -= 1
                     self._emit(seq, int(toks[b]))
 
     def _emit(self, seq, token):
@@ -943,7 +1171,7 @@ class ServeEngine:
             self.scheduler.finish(seq, "eos")
         elif len(seq.generated) >= req.max_new_tokens:
             self.scheduler.finish(seq, "length")
-        elif len(seq.prefix()) > self.max_context:
+        elif len(req.prompt) + len(seq.generated) > self.max_context:
             # the NEXT decode would need a KV slot at position
             # max_context — beyond the table width; truncate here
             self.scheduler.finish(seq, "capacity")
@@ -1031,6 +1259,7 @@ class ServeEngine:
             # call's unfinished sequences and free their pages so the
             # engine stays usable — otherwise the next generate() would
             # silently decode this call's ghosts against its pool
+            self._settle_or_abandon()
             for seq in seqs:
                 if seq.done:
                     continue
@@ -1150,7 +1379,9 @@ class ServeEngine:
             pass
 
     def has_work(self):
-        return self.scheduler.has_work()
+        """Work queued, or a launched step whose tokens have yet to be
+        handed out."""
+        return self.scheduler.has_work() or self._in_flight is not None
 
     def _step_rows(self, todo):
         """Dispatch this step's planned rows.  Unified (production):
@@ -1177,11 +1408,14 @@ class ServeEngine:
         """Advance the engine by ONE scheduler iteration: deadline
         expiry, drain bookkeeping, capacity fail-fast, admission, one
         ragged dispatch (mixed prefill-chunk + decode rows).  Returns
-        True while work remains queued — the fleet router's
-        interleaving unit (and what ``generate()`` loops on).  An idle
+        True while work remains — queued, or a step in flight — the
+        fleet router's interleaving unit (and what ``generate()`` loops
+        on).  With a step in flight the call either launches the next
+        step and then hands out that one's tokens, or only hands them
+        out (module docstring); a call emits one step at most.  An idle
         call is cheap, records no span and finalizes a pending drain
         report."""
-        if not self.scheduler.has_work():
+        if not self.has_work():
             return self._settle_idle()
         with _span(SPAN_STEP):
             return self._step_with_work()
@@ -1192,12 +1426,49 @@ class ServeEngine:
         self._stalled = 0
         return False
 
+    def _continuation(self):
+        """With a step in flight: the sequences of the next step where
+        it may be launched BEFORE that step's tokens are fetched, else
+        None (settle first).  The rule (module docstring): the batch is
+        full, so nobody who arrived meanwhile could have been admitted,
+        and the plan is plain continuation — no drain under way, no
+        waiting head to fail for capacity, no page that needs an
+        eviction.  (Expiry and the drain's sheds have run: whoever they
+        ended freed a row.)"""
+        sched = self.scheduler
+        if not self._batch_full() or self._draining:
+            return None
+        if self._head_cannot_fit():
+            return None
+        return sched.prepare_decode(evict=False)
+
+    def _head_cannot_fit(self):
+        """The waiting head needs more pages than an EMPTY pool holds."""
+        waiting = self.scheduler.waiting
+        return bool(waiting) and (
+            self.pool.pages_for(waiting[0].length())
+            > self.pool.num_usable_pages)
+
+    def _settle_or_abandon(self):
+        """Settle, or where the fetch itself fails give the step in
+        flight up: what it launched of the running sequences is
+        forgotten (a fault's clean-up, not a way to take a step back:
+        a recurrent state has moved)."""
+        try:
+            self._settle()
+        except Exception:  # noqa: BLE001 - the fault is the caller's to report
+            self._in_flight = None
+            for seq in self.scheduler.running:
+                seq.in_flight = seq.launched = 0
+
     def _step_with_work(self):
         sched = self.scheduler
         failed_fast = shed_now = 0
-        admitted, did_dispatch = [], False
+        admitted, did_dispatch, expired = [], False, False
+        emitted0 = self._steps_emitted
         try:
             with _span(SPAN_SCHEDULE):
+                todo = []
                 now = self._clock()
                 # deadline expiry at the ADMISSION boundary: a blown
                 # request must not take (or keep) pool pages
@@ -1225,40 +1496,56 @@ class ServeEngine:
                             sched.finish(seq, "shed")
                             shed_now += 1
                 self._sync_lifecycle_stats()
-                if not sched.has_work():
+                if self._in_flight is not None:
+                    # whoever ended above has its token in flight dropped
+                    # when that step is emitted: the result a synchronous
+                    # engine gives, which had emitted as far
+                    todo = self._continuation()
+                elif not sched.has_work():
                     return self._settle_idle()
-                # capacity fail-fast BEFORE admission: a head request
-                # that can never fit would otherwise stall the queue
-                while (sched.waiting
-                       and self.pool.pages_for(
-                           len(sched.waiting[0].prefix()))
-                       > self.pool.num_usable_pages):
-                    self._fail_capacity(sched.waiting[0])
-                    failed_fast += 1
-                if not self._draining:
-                    # admit() hands back fresh AND resumed sequences —
-                    # their ragged prefill starts past any shared-prefix
-                    # pages the pool matched (a resumed one re-creates
-                    # exactly the KV its eviction dropped)
-                    with _span(SPAN_ADMIT):
-                        admitted = sched.admit(
-                            bucket=lambda n: min(n, self.prefill_chunk))
-                    for seq in admitted:
-                        if seq.admitted_at is None:  # a resumed one keeps it
-                            seq.admitted_at = now
-                    sched.chaos_preempt()
-                todo = sched.prepare_decode() if sched.running else []
-            if todo:
+                else:
+                    # capacity fail-fast BEFORE admission: a head request
+                    # that can never fit would otherwise stall the queue
+                    while self._head_cannot_fit():
+                        self._fail_capacity(sched.waiting[0])
+                        failed_fast += 1
+                    if not self._draining:
+                        # admit() hands back fresh AND resumed sequences —
+                        # their ragged prefill starts past any
+                        # shared-prefix pages the pool matched (a resumed
+                        # one re-creates exactly the KV its eviction
+                        # dropped)
+                        with _span(SPAN_ADMIT):
+                            admitted = sched.admit(
+                                bucket=lambda n: min(n, self.prefill_chunk))
+                        for seq in admitted:
+                            if seq.admitted_at is None:  # resumed: kept
+                                seq.admitted_at = now
+                        sched.chaos_preempt()
+                    if sched.running:
+                        todo = sched.prepare_decode()
+            if todo is None:
+                # a step in flight and no plain continuation: hand out
+                # its tokens; the next call schedules and launches as a
+                # synchronous engine would
+                self._settle()
+            elif todo:
                 try:
                     self._step_rows(todo)
                 except StepCompileError:
                     raise  # the program is broken, not a request
+                except _SettleFirst:
+                    self._settle()
                 except Exception as exc:  # host fault isolation
+                    # the step that was in flight is older than the
+                    # fault: its tokens are the requests' own
+                    self._settle_or_abandon()
                     self._host_fault(todo, "ragged-step", exc)
                 did_dispatch = True
-            # deadline expiry at the DECODE boundary: pages free
-            # the moment the deadline blows, not a decode tail later
-            expired = bool(sched.expire(self._clock())) or expired
+            if self._steps_emitted != emitted0:
+                # deadline expiry at the DECODE boundary: pages free
+                # the moment the deadline blows, not a decode tail later
+                expired = bool(sched.expire(self._clock())) or expired
         except PoolExhausted:
             # a pathological admission race got past the
             # can_alloc/extend guards (e.g. page accounting the
@@ -1267,6 +1554,7 @@ class ServeEngine:
             # same requeue-front path organic exhaustion takes, so
             # nothing is lost and its re-prefill recreates the
             # dropped KV — and retry the step on the freed pages.
+            self._settle_or_abandon()
             if not sched.running:
                 if sched.waiting and self.pool.is_idle():
                     # even an EMPTY pool cannot hold the head
@@ -1290,8 +1578,10 @@ class ServeEngine:
         # that drained the batch): the freed pages guarantee the
         # NEXT iteration admits.  Two empty iterations in a row
         # cannot happen unless the scheduler is genuinely wedged.
+        # A launch is progress, and so are a step's tokens handed out.
         progressed = bool(admitted or did_dispatch or expired
-                          or failed_fast or shed_now)
+                          or failed_fast or shed_now
+                          or self._steps_emitted != emitted0)
         self._stalled = 0 if progressed else self._stalled + 1
         if self._stalled >= 2 and sched.has_work():
             raise RuntimeError(
@@ -1299,7 +1589,7 @@ class ServeEngine:
                 "(the admission guard should make progress "
                 "inevitable)"
             )
-        if not sched.has_work():
+        if not self.has_work():
             return self._settle_idle()
         return True
 
@@ -1370,6 +1660,13 @@ class ServeEngine:
         compiled for; carried over capacity is how much of a mixed
         step's dense work was for tokens somebody sent.
 
+        The step in flight (monotonic): ``steps_run_ahead`` (int)
+        launches made before the tokens of the step before were
+        fetched — over the steps served, how often the batch was full;
+        ``tokens_overrun`` (int) sampled tokens dropped, never emitted,
+        because their sequence had ended (``eos_id``, a quarantine, a
+        deadline) by the time they came back.
+
         A model with sparse experts (zeros for any other):
         ``moe_assignments`` (int) token x expert assignments and
         ``moe_experts_touched`` (int) experts that got a token, both
@@ -1409,6 +1706,8 @@ class ServeEngine:
             "mixed_tokens_carried": int(self.stats["mixed_tokens_carried"]),
             "mixed_tokens_capacity": int(
                 self.stats["mixed_tokens_capacity"]),
+            "steps_run_ahead": int(self.stats["steps_run_ahead"]),
+            "tokens_overrun": int(self.stats["tokens_overrun"]),
             "moe_assignments": int(self.stats["moe_assignments"]),
             "moe_experts_touched": int(self.stats["moe_experts_touched"]),
             "moe_load_max_over_mean": round(
@@ -1441,6 +1740,9 @@ class ServeEngine:
             reqs = [seq.req for seq in sched.waiting]
             sched.waiting.clear()
             return reqs
+        # a step in flight holds tokens of the running ones: hand them
+        # out first (best effort, like the frees: the replica is dying)
+        self._settle_or_abandon()
         salvaged = []
         for seq in list(sched.running):
             salvaged.append((seq.req, list(seq.generated)))
@@ -1464,6 +1766,7 @@ class ServeEngine:
         is given.  Refuses on a non-idle pool or queued work: reopening
         mid-drain would resurrect exactly the half-drained state the
         drain existed to retire."""
+        self._settle()
         if self.scheduler.has_work() or not self.pool.is_idle():
             raise RuntimeError(
                 "reopen() on a busy engine: drain to idle first "
@@ -1509,7 +1812,10 @@ class ServeEngine:
 
         Must be called at a step boundary (the deploy subscriber hooks
         the fleet router's step loop); never from inside a dispatch.
-        Returns the host-side stall in seconds."""
+        A step in flight is settled first (part of the stall): it ran
+        on the OLD weights, so behind a full batch the new ones serve
+        from the step after it.  Returns the host-side stall in
+        seconds."""
         old = self.params
         old_struct = jax.tree_util.tree_structure(old)
         new_struct = jax.tree_util.tree_structure(new_params)
@@ -1533,6 +1839,9 @@ class ServeEngine:
                     f"{n_shape}/{n_dtype}"
                 )
         t0 = self._clock()
+        # a step in flight was launched on the old weights: its tokens
+        # are handed out before the buffers it reads may go
+        self._settle()
         placed = jax.tree_util.tree_map(jnp.asarray, new_params)
         # commit before the cutover: a device transfer failing halfway
         # must leave the engine on its OLD params, not a broken tree.

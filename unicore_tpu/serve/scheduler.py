@@ -107,11 +107,27 @@ class Sequence:
         # token's)
         self.prefilled = 0
         self.prefix_registered = False
+        # what a step LAUNCHED and not yet emitted holds of this
+        # sequence (the engine's step in flight): ``in_flight`` sampled
+        # tokens (0 or 1) and the KV of ``launched`` tokens beyond
+        # ``prefilled``.  Both are 0 whenever nothing is in flight, and
+        # whatever plans the next step reads through ``length()`` and
+        # ``written()``; ``prefilled`` and ``generated`` move at emit
+        self.in_flight = 0
+        self.launched = 0
 
     def prefix(self):
         """Tokens whose KV must be live before the next decode step can
         run (prompt + everything generated so far)."""
         return list(self.req.prompt) + self.generated
+
+    def length(self):
+        """``len(prefix())`` once the token in flight has landed."""
+        return len(self.req.prompt) + len(self.generated) + self.in_flight
+
+    def written(self):
+        """Tokens whose KV is written once the step in flight has run."""
+        return self.prefilled + self.launched
 
     @property
     def done(self):
@@ -296,24 +312,31 @@ class Scheduler:
             return victim
         return None
 
-    def prepare_decode(self):
+    def prepare_decode(self, evict=True):
         """Grow every running sequence's pool length to cover its
         current prefix (a decode-ready sequence grows by one — the
-        token this step writes; a mid-prefill sequence is already
-        covered by its admission alloc), evicting LIFO on exhaustion.
-        Returns the sequences that take a row in this step's ragged
-        dispatch."""
+        token this step writes, past the one in flight if there is one;
+        a mid-prefill sequence is already covered by its admission
+        alloc), evicting LIFO on exhaustion.  Returns the sequences that
+        take a row in this step's ragged dispatch.
+
+        ``evict=False`` (the engine planning a step behind one still in
+        flight): nobody is preempted, and None comes back where a page
+        could not be had without it.  The pages taken until then are the
+        ones the next call takes first anyway."""
         for seq in list(self.running):
             if seq not in self.running:
                 continue  # evicted by an earlier iteration
             while True:
-                grow = len(seq.prefix()) - self.pool.seq_len(seq.sid)
+                grow = seq.length() - self.pool.seq_len(seq.sid)
                 if grow <= 0:
                     break
                 try:
                     self.pool.extend(seq.sid, grow)
                     break
                 except PoolExhausted:
+                    if not evict:
+                        return None
                     victim = self._pick_victim()
                     self.preempt(victim)
                     if victim is seq:
@@ -344,6 +367,9 @@ class Scheduler:
         self.running.remove(seq)
         self.waiting.appendleft(seq)
         seq.prefilled = 0
+        # what a step in flight holds of it is dropped at that step's
+        # emit (the engine tells by ``evictions``) and sampled again
+        seq.in_flight = seq.launched = 0
         seq.prefix_registered = False
         seq.evictions += 1
         self.num_evictions += 1
