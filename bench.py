@@ -284,6 +284,22 @@ _SERVE_MODEL = {}
 FLEET_TRACE_SEED = 1106
 
 
+def _decode_ms_since(engine, written):
+    """Wall ms of the decode steps among the rows the engine's step log
+    has written since it had written ``written``.  The ring holds its
+    last 4,096 steps of every kind: a longer stretch would read a
+    shortened window, so it is refused."""
+    log = engine.step_log
+    fresh = log.written - written
+    if fresh > log.size:
+        raise RuntimeError(
+            f"{fresh} steps since the mark, and the step log holds "
+            f"{log.size}: the window would be cut short")
+    rows = log.rows()[len(log) - fresh:]
+    rows = rows[rows["decode_rows"] > 0]
+    return (rows["device_s"] * 1e3).tolist()
+
+
 def _serve_engine(**engine_kw):
     """Small-LM serve engine at the bench serving shape.  The model and
     params build ONCE per process (cached) so multi-engine micros — the
@@ -482,11 +498,11 @@ def _serve_robustness(out):
     del model
     capacity = engine.max_batch + max_waiting
     engine.generate(reqs(2, 128, 2))  # warmup: compile + pool touch
-    n0 = len(engine.decode_ms)
+    n0 = engine.step_log.written
     flood = reqs(2 * capacity, 128, 32)
     results = engine.generate(flood)
     shed = sum(1 for r in results if r.finish_reason == "shed")
-    window = list(engine.decode_ms)[n0:]
+    window = _decode_ms_since(engine, n0)
     out["serve_decode_p99_ms"] = round(
         float(np.percentile(window, 99)), 2)
     out["serve_flood_requests"] = len(flood)
@@ -568,7 +584,7 @@ def _fleet_slo_micros(out):
         # router's collect() would otherwise harvest them into the
         # result map and their compile-heavy TTFT would pollute p99
         eng.collect_finished()
-    warm_ms = {rid: len(eng.decode_ms)
+    warm_ms = {rid: eng.step_log.written
                for rid, eng in engines.items()}
     router = FleetRouter(engines)
     trace = generate_trace(
@@ -583,7 +599,7 @@ def _fleet_slo_micros(out):
     agg = router.fleet_report()["aggregate"]
     intertoken = []
     for rid, eng in engines.items():
-        intertoken.extend(list(eng.decode_ms)[warm_ms[rid]:])
+        intertoken.extend(_decode_ms_since(eng, warm_ms[rid]))
     out["fleet_ttft_p50_ms"] = round(
         float(np.percentile(ttfts, 50)), 2)
     out["fleet_ttft_p99_ms"] = round(

@@ -229,6 +229,14 @@ def test_what_a_token_holds_in_the_cache(lm):
     assert eng.load_snapshot()["cache_bytes_per_token"] == 1536
 
 
+def carried_by_width(eng):
+    """Tokens the decode width and the prefill width served, from the
+    engine's step log: the sum of ``carried`` by width."""
+    rows = eng.step_log.rows()
+    return (int(rows["carried"][rows["width"] == 1].sum()),
+            int(rows["carried"][rows["width"] > 1].sum()))
+
+
 def test_cache_bytes_per_token_of_a_model_with_kv_pages():
     from examples.lm.model import TransformerLMModel
 
@@ -242,6 +250,9 @@ def test_cache_bytes_per_token_of_a_model_with_kv_pages():
     assert eng.stats["cache_bytes_per_token"] == 2 * 2 * 64 * 4
     assert eng.latent_layers == 0
     eng.generate([Request(prompt=[5, 6, 7], max_new_tokens=2)])
+    # the step log has what each width carried for every model; the two
+    # latent counters move for a latent model alone
+    assert carried_by_width(eng) == (1, 3)
     assert eng.stats["latent_decode_tokens"] == 0
     assert eng.stats["latent_prefill_tokens"] == 0
 
@@ -389,8 +400,9 @@ def test_the_two_forms_counters_count_what_each_width_served(lm):
     eng = ServeEngine(model, params, prefill_chunk=16, **POOL)
     eng.generate([Request(prompt=prompt_of(np.random.default_rng(2), 45),
                           max_new_tokens=9)])
-    assert eng.stats["latent_prefill_tokens"] == 45
-    assert eng.stats["latent_decode_tokens"] == 8
+    assert carried_by_width(eng) == (8, 45)
+    assert (eng.stats["latent_decode_tokens"],
+            eng.stats["latent_prefill_tokens"]) == (8, 45)
     report = backend.dispatch_report()
     assert set(report["latent_attention_decode"].values()) == {"reference"}
     assert set(report["latent_attention_prefill"].values()) == {"reference"}

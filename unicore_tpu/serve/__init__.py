@@ -23,6 +23,7 @@ _EXPORTS = {
     "step_key": ("unicore_tpu.serve.sampling", "step_key"),
     "finite_rows": ("unicore_tpu.serve.sampling", "finite_rows"),
     "reject_newest": ("unicore_tpu.serve.scheduler", "reject_newest"),
+    "step_logs": ("unicore_tpu.serve.step_log", "step_logs"),
 }
 
 __all__ = sorted(_EXPORTS)

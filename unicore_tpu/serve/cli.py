@@ -15,7 +15,11 @@ Two sources of model + prompts:
   admit/prefill/decode/evict machinery on CPU).
 
 Output: one JSON object (``--json FILE`` or stdout) with per-request
-generated ids, finish reasons, TTFT, and the engine's aggregate stats.
+generated ids, finish reasons, TTFT, the engine's aggregate stats and
+``"steps"``, a summary of its step log (``serve/step_log.py``: the rows
+the ring holds, by width class the median and 95th percentile of the
+time the device had a step, the fill of the mixed program, the share of
+steps launched ahead, the CPU a step by the two clocks).
 
 ``--fleet`` routes the same requests through a
 :class:`~unicore_tpu.fleet.router.FleetRouter` over ``--replicas``
@@ -268,6 +272,7 @@ def _fleet_main(args, model, params, requests, shutdown):
     pool and one drain record per replica (the CI smoke asserts it)."""
     from unicore_tpu.fleet.health import CircuitBreaker, ReplicaHealth
     from unicore_tpu.fleet.router import FleetRouter
+    from unicore_tpu.serve import step_log
     from unicore_tpu.serve.engine import ServeEngine
 
     def make_engine(rid):
@@ -353,6 +358,7 @@ def _fleet_main(args, model, params, requests, shutdown):
             rid: {
                 "stats": {k: (round(v, 4) if isinstance(v, float) else v)
                           for k, v in engines[rid].stats.items()},
+                "steps": step_log.summary(engines[rid].step_log.rows()),
                 # a replica evicted by failover has no drain record —
                 # the fleet report's "lost" section carries its story
                 "drain": drains.get(rid),
@@ -417,6 +423,7 @@ def _serve(args):
     """Build the model, the requests and the engine (or the fleet),
     run to completion, write the report."""
     from unicore_tpu.ops.backend import dispatch_report
+    from unicore_tpu.serve import step_log
     from unicore_tpu.serve.engine import ServeEngine
 
     if args.demo:
@@ -479,6 +486,7 @@ def _serve(args):
         "results": [_result_record(r) for r in results],
         "stats": {k: (round(v, 4) if isinstance(v, float) else v)
                   for k, v in engine.stats.items()},
+        "steps": step_log.summary(engine.step_log.rows()),
         "drain": engine.drain_report,
         "pool_clean": pool_clean,
         # which path each compiled width's attention took

@@ -71,7 +71,9 @@ token holds over all layers.  Both step widths attend in the absorbed
 form (``serve/attention.py``): ``stats["latent_decode_tokens"]`` counts
 the tokens the decode width served (each is one query cell a head),
 ``["latent_prefill_tokens"]`` the tokens the prefill width served, a
-one-token row riding a mixed step among them.
+one-token row riding a mixed step among them.  Both are the step log's
+``carried`` summed by width (below), for every model: the counters stay
+only because ``tests/bench/test_bench_run_pangu.py`` reads them.
 
 THE STEP IN FLIGHT.  The device starts a step when the host launches
 it, and a host that fetches step N's tokens before it plans, assembles,
@@ -141,10 +143,28 @@ makes it possible:
 ``stats["steps_run_ahead"]`` counts the launches made with a step in
 flight, beside the step counts: over them, how often the rule held.
 
-Metrics: per-request queue wait and TTFT, and the counters of
+Metrics: per-request queue wait and TTFT, the counters of
 :attr:`ServeEngine.stats` (aggregate decode tokens/sec, peak pool
 occupancy, prefix-cache hits), which the JSON report and the fleet
-router read.
+router read, and THE TWO LOGS (``serve/step_log.py``), always on, each a
+preallocated ring of 4,096 rows written by index.
+:attr:`ServeEngine.step_log` gets one row a step EMITTED, at the end of
+``_emit_step``: ``ordinal``, ``width``, ``carried`` / ``capacity`` (the
+list's fill), ``decode_rows`` handed out, ``ran_ahead`` (launched with
+a step in flight), ``emitted_at`` on ``time.perf_counter``,
+``device_s`` (how long the device had the step: launch, or the step
+before it done, to fetched) and ``thread_cpu_s`` / ``process_cpu_s``,
+the advance of ``time.thread_time`` and ``time.process_time`` since the
+row before: the serve loop is one thread, so the first is all that
+thread did for one step, the caller's submit and collect included, and
+the difference of the two is the runtime's and every other thread's.
+:attr:`ServeEngine.first_token_log` gets one row a request where its
+first token is stamped: ``admitted_at`` / ``first_token_at`` (the
+engine's clock) and ``first_step``, the ``ordinal`` of the step row
+that emitted the token, which joins a request to its steps.  A row
+holds only what something reads (``serve/step_log.py``).
+``load_snapshot()``'s ``step_ms`` is the median ``device_s`` of the
+last 33 rows that held a decode row.
 
 Tracing: a ``serve_step`` that has work records the span tree below as
 ``jax.profiler.TraceAnnotation``s, so the spans land in the profiler's
@@ -225,7 +245,6 @@ import logging
 import math
 import os
 import time
-from collections import deque
 from typing import List, Optional
 
 import numpy as np
@@ -235,6 +254,7 @@ import jax.numpy as jnp
 
 from unicore_tpu.ops import moe
 
+from . import step_log
 from .attention import PagedMeta
 from .kv_pool import PagedKVPool, PoolExhausted
 from .sampling import finite_rows, sample_tokens, step_keys
@@ -314,6 +334,7 @@ class _Launched:
     width: int
     carried: int       # tokens the list carried
     launched_at: float
+    ran_ahead: bool       # launched with a step in flight
     seconds: float = 0.0  # launch (or the step before done) to fetched
 
 
@@ -453,8 +474,12 @@ class ServeEngine:
             self.watchdog = StepWatchdog(
                 float(step_timeout), context=self._watchdog_context
             )
-        # recent per-decode-step wall latencies (bench p99 feeds on it)
-        self.decode_ms = deque(maxlen=4096)
+        # a row a step emitted, a row a request at its first token
+        # (module docstring, "Metrics"); step_logs() hands out the logs
+        # of the engine built last
+        self.step_log = step_log.StepLog()
+        self.first_token_log = step_log.FirstTokenLog()
+        step_log.publish(self.step_log, self.first_token_log)
         self.stats = {
             "prefills": 0, "decode_steps": 0, "decode_tokens": 0,
             "generated_tokens": 0, "peak_pool_occupancy": 0.0,
@@ -488,7 +513,8 @@ class ServeEngine:
                 for name in ("k_pages", "v_pages", "latent_pages")
                 for x in self._leaves_named(self.pages, name)),
             # latent attention (module docstring): tokens the decode
-            # width served, tokens the prefill width served
+            # width served, tokens the prefill width served (the step
+            # log's ``carried`` by width; a benchmark test reads these)
             "latent_decode_tokens": 0, "latent_prefill_tokens": 0,
         }
         # live weight swaps installed via swap_weights (ISSUE 18);
@@ -1043,7 +1069,8 @@ class ServeEngine:
             step = self._in_flight = _Launched(
                 out=out, rows=rows, epochs=[r[0].evictions for r in rows],
                 row_of={r[0].sid: b for b, r in enumerate(rows) if r[3]},
-                width=w, carried=carried, launched_at=t0)
+                width=w, carried=carried, launched_at=t0,
+                ran_ahead=prev is not None)
             for seq, _, m, emit, _ in rows:
                 seq.launched += m
                 seq.in_flight += emit
@@ -1104,9 +1131,10 @@ class ServeEngine:
     def _emit_step(self, step, toks):
         """A fetched step into the scheduler's state: counters,
         quarantine, prefill watermark, ``register_prefix``, ``_emit``,
-        in row order.  A row whose sequence ended or was preempted after
-        the step was launched is dropped: its token is an OVERRUN
-        (module docstring), counted and never emitted."""
+        in row order, then the step's row in the step log.  A row whose
+        sequence ended or was preempted after the step was launched is
+        dropped: its token is an OVERRUN (module docstring), counted and
+        never emitted."""
         with _span(SPAN_EMIT):
             self._steps_emitted += 1
             B, w, dt = self.max_batch, step.width, step.seconds
@@ -1118,7 +1146,8 @@ class ServeEngine:
                     self.stats["tokens_overrun"] += row[3]
                 else:
                     rows.append((b, row))
-            self.stats["prefills"] += sum(1 for _, r in rows if not r[4])
+            decode_rows = sum(1 for _, r in rows if r[4])
+            self.stats["prefills"] += len(rows) - decode_rows
             if self.moe_layers:
                 assigned, touched = int(routed[0]), int(routed[1])
                 held = int(routed[2]) if self.moe_share else assigned
@@ -1133,12 +1162,10 @@ class ServeEngine:
                 self.stats["mixed_steps"] += 1
                 self.stats["mixed_tokens_carried"] += step.carried
                 self.stats["mixed_tokens_capacity"] += self._step_tokens(w)
-            if any(r[4] for _, r in rows):
+            if decode_rows:
                 self.stats["decode_time_s"] += dt
-                self.decode_ms.append(dt * 1e3)
                 self.stats["decode_steps"] += 1
-                self.stats["decode_tokens"] += sum(
-                    1 for _, r in rows if r[4])
+                self.stats["decode_tokens"] += decode_rows
                 if self.progress_path:
                     with open(self.progress_path, "a") as fh:
                         fh.write(f"{self.stats['decode_steps']}\n")
@@ -1159,6 +1186,9 @@ class ServeEngine:
                 if emit:
                     seq.in_flight -= 1
                     self._emit(seq, int(toks[b]))
+            self.step_log.write(
+                self._steps_emitted, w, step.carried, self._step_tokens(w),
+                decode_rows, step.ran_ahead, dt)
 
     def _emit(self, seq, token):
         """Append one sampled token and settle termination."""
@@ -1166,6 +1196,8 @@ class ServeEngine:
         self.stats["generated_tokens"] += 1
         if seq.first_token_at is None:
             seq.first_token_at = self._clock()  # same clock as enqueued_at
+            self.first_token_log.write(
+                seq.admitted_at, seq.first_token_at, self._steps_emitted)
         req = seq.req
         if req.eos_id is not None and token == req.eos_id:
             self.scheduler.finish(seq, "eos")
@@ -1633,7 +1665,8 @@ class ServeEngine:
         ``max_waiting`` (int or None) the bounded-queue shed line,
         ``draining`` (bool) admission closed (flag set or a wired
         shutdown requested), ``step_ms`` (float) median of the recent
-        decode-step wall latencies (0.0 until the first decode) — what
+        decode-step wall latencies (the step log's ``device_s`` over its
+        last 33 rows with a decode row; 0.0 until the first) — what
         the router multiplies queue depth by to project a request's
         wait against its deadline — and the prefix-cache hit surface:
         ``prefix_hits`` (int), ``prefix_tokens_saved`` (int),
@@ -1682,8 +1715,10 @@ class ServeEngine:
         narrow vector a layer): with ``free_pages`` x the page size, how
         many tokens of context this replica can still take."""
         sched = self.scheduler
-        recent = list(self.decode_ms)[-33:]
-        step_ms = float(sorted(recent)[len(recent) // 2]) if recent else 0.0
+        log = self.step_log
+        recent = np.sort(log.column("device_s")[
+            log.column("decode_rows") > 0][-33:])
+        step_ms = float(recent[len(recent) // 2]) * 1e3 if len(recent) else 0.0
         ps = self.pool.prefix_stats
         hit_rate = (ps["hits"] / ps["lookups"]) if ps["lookups"] else 0.0
         return {
