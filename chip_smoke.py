@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke: the trainer and the serve engine, once, on the TPU.
 
-    python chip_smoke.py             # one chip: train phase + serve phase
+    python chip_smoke.py             # one chip: train, kernel and serve phases
     python chip_smoke.py --chips 4   # four chips: the mesh phase, only
 
 Drives the system through the entry points a user calls
@@ -53,6 +53,23 @@ LM = dict(
     # mixed prompt lengths, three of them longer than the prefill chunk
     prompt_lens=(5, 17, 33, 40, 64, 70, 100, 150),
 )
+# the ragged kernel against its reference over ROW PATTERNS, at the shapes
+# two serve configurations hand it: opt_1.3b's 32 heads x 64 over a
+# float32 pool (widths 1 and 128) and the latent step's one head of 640
+# lanes under 128 query heads (16 rows x 64 cut into 256 tiles of 4 tokens)
+KERNEL = dict(
+    page_size=64, heads=32, head_dim=64, rows=208, table_pages=32,
+    num_pages=128, widths=(1, 128), pages_per_block=(1, 2, 4),
+    patterns=("zeros_first", "zeros_last", "zeros_between", "zeros_200",
+              "one_block", "odd_even", "partial_last"),
+    latent_lanes=640, latent_heads=128, latent_rows=16, latent_chunk=64,
+    latent_tile=4, latent_table_pages=48, latent_num_pages=96,
+    # inputs are whole bfloat16 numbers, so one pass on the MXU rounds
+    # only the probabilities (2^-9 of values of order one); a slot read
+    # stale or early is another page's numbers, or POISON
+    gap_limit=2e-2, latent_gap_limit=1e-3,
+)
+POISON = 1e4
 # |loss(variant) - loss(one device)| <= LOSS_RTOL * |loss(one device)| on
 # every update: same data, same init, no dropout; what differs is the
 # order of bf16 reductions and, under tp, the attention path (einsum in
@@ -664,6 +681,215 @@ def barrier_phase(n=8192, reps=8):
     }
 
 
+# -- the ragged kernel, row pattern by row pattern ----------------------
+
+def ragged_row_lengths(pattern, rows, blk, parity):
+    """Context length of each of ``rows`` kernel rows (0: an empty row)
+    for one named pattern; ``blk`` is a block's slots.  ``parity`` 1
+    gives the first live row one block more, so every later block lands
+    in the other K/V slot.  ``zeros_200`` takes the 204 rows it needs."""
+    part = blk // 2 + 1
+    if pattern == "zeros_200":
+        rows = max(rows, 204)
+    live = {
+        "zeros_first": {rows - 3: 3 * blk, rows - 2: blk + 1,
+                        rows - 1: 2 * blk},
+        "zeros_last": {0: 3 * blk, 1: blk + 1, 2: 2 * blk},
+        "zeros_between": {0: 2 * blk, 2: blk, 5: 3 * blk - 1, 7: 1,
+                          rows // 2: 2 * blk + 1, rows - 1: part},
+        "zeros_200": {0: blk + 3, 201: 2 * blk, 202: 5, 203: 3 * blk},
+        "one_block": {r: n for r, n in enumerate(
+            (blk, 1, blk - 1, blk, part, blk, 2, blk))},
+        "odd_even": {rows - 7 + r: n * blk for r, n in enumerate(
+            (1, 2, 3, 4, 5, 2, 1))},
+        "partial_last": {r: n for r, n in enumerate(
+            (blk + 1, 2 * blk - 1, 3 * blk + part, part, 4 * blk + 1,
+             blk + part))},
+    }[pattern]
+    lengths = [0] * rows
+    for r, n in live.items():
+        lengths[r] = n
+    first = min(live)
+    lengths[first] += parity * blk
+    return lengths
+
+
+def _bf16_whole(x):
+    """float32 numbers that bfloat16 holds exactly (low 16 bits cut)."""
+    import numpy as np
+
+    return (np.ascontiguousarray(x, np.float32).view(np.uint32)
+            & np.uint32(0xFFFF0000)).view(np.float32)
+
+
+def _pool_and_table(rng, lengths, tables_of, page_size, table_pages,
+                    num_pages, lanes):
+    """A pool of ``num_pages`` that reads POISON wherever no row may read:
+    every table gets pages of its own (page 0 is the trash page the tables
+    are padded with), filled up to its longest row.  ``tables_of[r]``
+    names the table row ``r`` walks (rows of one prompt share theirs)."""
+    import numpy as np
+
+    reach = {}
+    for r, n in enumerate(lengths):
+        reach[tables_of[r]] = max(reach.get(tables_of[r], 0), n)
+    check(sum(-(-n // page_size) for n in reach.values()) < num_pages,
+          f"the rows' pages do not fit a pool of {num_pages}")
+    pool = np.full((num_pages * page_size, lanes), POISON, np.float32)
+    pages = rng.permutation(np.arange(1, num_pages))
+    tables, at = {}, 0
+    for name, n in reach.items():
+        mine = pages[at:at + -(-n // page_size)]
+        at += len(mine)
+        row = np.zeros(table_pages, np.int32)
+        row[:len(mine)] = mine
+        tables[name] = row
+        slots = (mine[:, None] * page_size
+                 + np.arange(page_size)[None]).reshape(-1)[:n]
+        pool[slots] = _bf16_whole(rng.randn(n, lanes))
+    table = np.stack([tables[tables_of[r]] for r in range(len(lengths))])
+    return pool, table
+
+
+def ragged_row_case(pattern, size, pages_per_block, parity, width, seed):
+    """One per-head case: the operands of ``ragged_paged_attention`` as
+    numpy arrays.  A live row's queries sit at the end of its context: a
+    chunk of up to ``width`` tokens on odd rows, one decode token on even
+    ones."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    ps, heads, d = size["page_size"], size["heads"], size["head_dim"]
+    lengths = ragged_row_lengths(
+        pattern, size["rows"], pages_per_block * ps, parity)
+    positions = np.full((len(lengths), width), -1, np.int32)
+    for r, n in enumerate(lengths):
+        new = min(n, width if r % 2 else 1)
+        positions[r, :new] = n - new + np.arange(new)
+    k, table = _pool_and_table(rng, lengths, list(range(len(lengths))), ps,
+                               size["table_pages"], size["num_pages"],
+                               heads * d)
+    # values: the keys' numbers a lane further on (a POISON slot is POISON
+    # in every lane, so it stays one)
+    v = np.roll(k, 1, axis=1)
+    q = _bf16_whole(rng.randn(len(lengths), width, heads, d))
+    return dict(q=q, k_pages=k, v_pages=v, page_table=table, positions=positions,
+                lengths=np.asarray(lengths, np.int32), page_size=ps,
+                scale=d ** -0.5, pages_per_block=pages_per_block,
+                three_pass=False)
+
+
+def latent_row_case(form, size, pages_per_block, parity, seed):
+    """The latent step's shape, cut as ``serve/attention.py``
+    ``write_latent_and_attend`` cuts it: one K/V head of ``latent_lanes``,
+    ONE pool as keys and as values, three passes, a token's heads as query
+    cells.  ``"decode"``: one token a row, live and empty rows mixed.
+    ``"mixed"``: a rectangle of ``latent_chunk`` columns cut into tiles of
+    ``latent_tile`` tokens, so most kernel rows are empty, empty ones lie
+    between live ones, and the tiles of one prompt share a page table with
+    lengths that grow by a tile."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    ps, lanes, heads = (size["page_size"], size["latent_lanes"],
+                        size["latent_heads"])
+    rows, chunk, blk = (size["latent_rows"], size["latent_chunk"],
+                        pages_per_block * ps)
+    # (context, new tokens) of each rectangle row; the first live row's
+    # context grows by a block under ``parity``
+    if form == "decode":
+        chunk, tile = 1, 1
+        seqs = [(blk + 3, 1), (0, 0), (3 * blk, 1), (5, 1), (0, 0),
+                (2 * blk - 1, 1)] + [(0, 0)] * (rows - 7) + [(blk, 1)]
+    else:
+        tile = size["latent_tile"]
+        seqs = [(0, 0), (2 * blk + 1, 1), (blk - 2, chunk), (0, 0),
+                (7, 1), (3 * blk, chunk // 2 - 3), (0, 0), (0, 0),
+                (blk, 1), (0, tile + 1)] + [(0, 0)] * (rows - 11) + [
+                    (blk + 5, 2 * tile)]
+    first = next(i for i, (_, new) in enumerate(seqs) if new)
+    seqs[first] = (seqs[first][0] + parity * blk, seqs[first][1])
+    row_positions = np.full((rows, chunk), -1, np.int32)
+    for r, (context, new) in enumerate(seqs):
+        row_positions[r, :new] = context + np.arange(new)
+    tiles = chunk // tile
+    tile_positions = row_positions.reshape(rows * tiles, tile)
+    lengths = tile_positions.max(axis=1) + 1
+    pool, table = _pool_and_table(
+        rng, lengths, np.repeat(np.arange(rows), tiles), ps,
+        size["latent_table_pages"], size["latent_num_pages"], lanes)
+    q = _bf16_whole(rng.randn(rows * tiles, tile * heads, 1, lanes)) / 8
+    return dict(q=q, k_pages=pool, v_pages=pool, page_table=table,
+                positions=np.repeat(tile_positions, heads, axis=1),
+                lengths=lengths, page_size=ps,
+                scale=192 ** -0.5, pages_per_block=pages_per_block,
+                three_pass=True)
+
+
+def ragged_case_gap(case):
+    """The kernel's largest distance from ``paged_attention_reference``
+    (at ``highest`` precision) over the live query cells of ``case``, and
+    whether every cell of every row, empty ones too, came out finite.
+    The reference gathers the live rows alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from unicore_tpu.ops.pallas.paged_attention import (
+        ragged_paged_attention,
+    )
+    from unicore_tpu.serve.attention import paged_attention_reference
+
+    case = dict(case)
+    q, k_np, v_np, table, positions, lengths = (case.pop(n) for n in (
+        "q", "k_pages", "v_pages", "page_table", "positions", "lengths"))
+    k = jnp.asarray(k_np)
+    v = k if v_np is k_np else jnp.asarray(v_np)
+    out = np.asarray(ragged_paged_attention(
+        jnp.asarray(q), k, v, jnp.asarray(table), jnp.asarray(positions),
+        jnp.asarray(lengths), **case))
+    live = np.flatnonzero(lengths)
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(paged_attention_reference(
+            jnp.asarray(q[live]), k, v, jnp.asarray(table[live]),
+            jnp.asarray(positions[live]), jnp.asarray(lengths[live]),
+            case["page_size"], case["scale"]))
+    cells = positions[live] >= 0
+    return (float(np.abs(out[live] - ref)[cells].max()),
+            bool(np.isfinite(out).all()))
+
+
+def kernel_phase(seed, size):
+    """Every row pattern through the COMPILED ragged kernel against the
+    reference: the one place a DMA that is read before it has landed, or
+    waited for by nobody, can show (interpret mode runs a copy where it
+    is started)."""
+    gaps = {}
+    for pp in size["pages_per_block"]:
+        for parity in (0, 1):
+            cases = {
+                f"{pattern}-w{width}": ragged_row_case(
+                    pattern, size, pp, parity, width, seed)
+                for pattern in size["patterns"]
+                for width in size["widths"]}
+            cases.update({
+                f"latent_{form}": latent_row_case(
+                    form, size, pp, parity, seed)
+                for form in ("decode", "mixed")})
+            for name, case in cases.items():
+                gap, finite = ragged_case_gap(case)
+                limit = size["latent_gap_limit" if case["three_pass"]
+                             else "gap_limit"]
+                check(finite and gap <= limit,
+                      f"ragged kernel, {name}, {pp} pages a block, parity "
+                      f"{parity}: {gap} from the reference (limit {limit}"
+                      f"), finite {finite}")
+                gaps[f"{name}-pp{pp}-parity{parity}"] = gap
+    return {"cases": len(gaps), "gap_max": max(gaps.values()),
+            "latent_gap_max": max(
+                g for n, g in gaps.items() if n.startswith("latent")),
+            "worst": max(gaps, key=gaps.get)}
+
+
 # -- main --------------------------------------------------------------
 
 def _chip_checks_step(rep, what, want_kernel=True):
@@ -753,6 +979,8 @@ def main(argv=None):
             flash = rep["kernel_dispatch"].get("flash_attention", {})
             check(flash and set(flash.values()) == {"pallas"},
                   f"flash attention dispatch: {flash}")
+
+            emit("kernel", **kernel_phase(args.seed, KERNEL))
 
             rep = serve_phase(WORK, args.seed, LM)
             emit("serve", **rep)

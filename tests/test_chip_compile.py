@@ -157,22 +157,61 @@ def test_ragged_paged_attention_compiles(width, page_size, heads, dtype,
              ((bsz,), I32))
 
 
-# a serve cell with 30 heads of 128 (a one-head slab: 128 lanes hold one
-# head), 12 rows, an 8,192-token table and a float32 pool of 800 pages:
-# the shapes the full-attention layers of a hybrid decoder hand the kernel
-@pytest.mark.parametrize("width", [1, 64])
-def test_ragged_paged_attention_compiles_at_30_heads_of_128(
-        width, one_chip, compiled_kernels):
+# the kernel's shapes in the serve configurations, with what the shape
+# rules give each: (rows, query cells, K/V heads, head dim, pool pages,
+# table pages, three passes) -> (pages a block, scoped VMEM in MB).  The
+# second K/V slot is paid by ``vmem_limit_bytes``, not by smaller blocks:
+# every shape keeps the 4 pages of 64 it walked with one slot, and the
+# programs whose buffers pass the chip's default of 16 MB (the mixed ones
+# of ``opt_1.3b`` and both of the hybrid's 3,840 lanes) ask for more
+SERVE_KERNEL_SHAPES = {
+    "opt_1.3b-w1": ((32, 1, 32, 64, 288, 32, False), (4, 16.0)),
+    "opt_1.3b-w128": ((32, 128, 32, 64, 288, 32, False), (4, 23.0)),
+    "olmo_hybrid_7b-w1": ((12, 1, 30, 128, 800, 128, False), (4, 21.31)),
+    "olmo_hybrid_7b-w64": ((12, 64, 30, 128, 800, 128, False), (4, 27.56)),
+    "lfm2_24b_a2b-w1": ((32, 4, 8, 64, 800, 32, False), (4, 16.0)),
+    "lfm2_24b_a2b-w128": ((32, 512, 8, 64, 800, 32, False), (4, 17.0)),
+    "openpangu-w1": ((16, 128, 1, 640, 1152, 132, True), (4, 16.0)),
+    "openpangu-w64": ((256, 512, 1, 640, 1152, 132, True), (4, 16.0)),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SERVE_KERNEL_SHAPES))
+def test_serve_kernel_pages_per_block_and_vmem_limit(shape):
     from unicore_tpu.ops.pallas import paged_attention as pa
 
-    bsz, heads, d, page_size, context, num_pages = 12, 30, 128, 64, 8192, 800
-    assert pa.supported(heads, d, page_size, 4)
+    (_, cells, heads, d, _, table, _), (pages, limit_mb) = (
+        SERVE_KERNEL_SHAPES[shape])
+    pp = pa.pick_pages_per_block(table, 64, d, num_heads=heads, itemsize=4)
+    assert pp == pages
+    limit = pa.vmem_limit_bytes(cells, heads * d, heads, pp * 64, 4, 4)
+    assert round(limit / 2 ** 20, 2) == limit_mb
+    # both slots' blocks are inside it, and it is a fraction of a v5e's
+    # 128 MB of VMEM
+    assert pa.SLOTS * 2 * pp * 64 * heads * d * 4 < limit <= 32 << 20
+
+
+# ``opt_1.3b``'s two programs (32 heads x 64 in two-head slabs, a float32
+# pool of 288 pages, 32 rows, a table of 32 pages), the hybrid's (30 heads
+# of 128: a one-head slab, 12 rows, an 8,192-token table, 800 pages) and
+# the other configurations' kernels on their own; the step programs of
+# ``lfm2_24b_a2b`` and the latent step compile whole further down
+@pytest.mark.parametrize("shape", sorted(SERVE_KERNEL_SHAPES))
+def test_serve_kernel_shapes_compile_with_two_slots(shape, one_chip,
+                                                    compiled_kernels):
+    from unicore_tpu.ops.pallas import paged_attention as pa
+
+    rows, cells, heads, d, num_pages, table, three_pass = (
+        SERVE_KERNEL_SHAPES[shape][0])
+    assert pa.supported(heads, d, 64, 4)
     fn = functools.partial(
-        pa.ragged_paged_attention, page_size=page_size, scale=d ** -0.5)
-    pool = ((num_pages * page_size, heads * d), F32)
-    _compile(fn, one_chip, ((bsz, width, heads, d), F32), pool, pool,
-             ((bsz, context // page_size), I32), ((bsz, width), I32),
-             ((bsz,), I32))
+        pa.ragged_paged_attention, page_size=64, scale=d ** -0.5,
+        three_pass=three_pass)
+    pool = ((num_pages * 64, heads * d), F32)
+    text = _compile(fn, one_chip, ((rows, cells, heads, d), F32), pool, pool,
+                    ((rows, table), I32), ((rows, cells), I32),
+                    ((rows,), I32))
+    assert text.count("tpu_custom_call") == 1  # still one Mosaic kernel
 
 
 # the cell `lfm2_moe_longgen` whole: both step programs of the engine at

@@ -92,6 +92,24 @@ def test_train_and_serve_phases_rehearse_on_cpu(tmp_path):
         assert cmp["exact"] == cmp["tokens"] == 4
 
 
+def test_kernel_phase_rehearses_on_cpu():
+    """The kernel phase at a tiny width, interpreted: two of its row
+    patterns and both latent forms, either slot parity.  (Every pattern,
+    and what the interpreters can say of the kernel's invariant:
+    ``tests/test_serve.py`` ``test_ragged_kernel_row_patterns``.)"""
+    import chip_smoke as cs
+
+    tiny = dict(cs.KERNEL, page_size=8, heads=2, head_dim=64, rows=16,
+                table_pages=24, widths=(3,), pages_per_block=(2,),
+                patterns=("zeros_between", "odd_even"), latent_heads=2,
+                latent_rows=11, latent_chunk=16)
+    rep = cs.kernel_phase(1, tiny)
+    assert rep["cases"] == 8 and rep["gap_max"] < 2e-5
+    # a pool too small for the rows' pages is refused, not wrapped around
+    with pytest.raises(cs.SmokeFailure, match="do not fit"):
+        cs.ragged_row_case("odd_even", dict(tiny, num_pages=8), 2, 0, 3, 1)
+
+
 def test_mesh_phase_rehearses_on_four_virtual_devices(tmp_path):
     """The second rehearsal: what ``--chips 4`` runs, on four virtual
     CPU devices."""

@@ -156,7 +156,9 @@ def _serve_shapes(name, width):
 ])
 def test_serve_cells_pages_per_block(on_chip, name, width, geometry):
     """Both widths of the serve configurations: the compiled kernel
-    supports the shape, and 4 pages of 64 (256 slots) go into a block."""
+    supports the shape, and 4 pages of 64 (256 slots) go into a block,
+    with two K/V slots as with one (``tests/test_chip_compile.py`` pins
+    the VMEM limit that pays for the second)."""
     q, pool, table, eng = _serve_shapes(name, width)
     assert (q.shape[0], q.shape[2], q.shape[3], table.shape[1]) == geometry
     assert width in (1, eng["prefill_chunk"])
@@ -382,8 +384,12 @@ def test_pages_per_block_within_table_and_budget(heads, d, itemsize):
         pp = pa.pick_pages_per_block(table, page, d, num_heads=heads,
                                      itemsize=itemsize)
         assert 1 <= pp <= table, (table, page, pp)
+        # the budget is ONE slot's keys and values; the second slot does
+        # not shrink a block, the kernel's VMEM limit pays for it
         scratch = 2 * pp * page * heads * d * itemsize
         assert scratch <= pa._SCRATCH_BUDGET_BYTES or pp == 1
+        assert pa.vmem_limit_bytes(1, heads * d, heads, pp * page,
+                                   itemsize, itemsize) > pa.SLOTS * scratch
         # no more than the 256 slots it aims for, rounded up to a page
         assert (pp - 1) * page < 256
 

@@ -135,7 +135,9 @@ def test_kernel_body_entered_once_per_width(width, pool, kernel_entries):
         _engine(model, params, pool).trace_step_fns(widths=(width,))
         assert len(kernel_entries) == 1
     assert backend.dispatch_report()["ragged_paged_attention"][
-        "b4 w%d h4 d8 page4 %s" % (width, jnp.dtype(POOLS[pool]).name)
+        "b4 w%d h4 d8 page4 %s pp%d slots2" % (
+            width, jnp.dtype(POOLS[pool]).name,
+            kernel_entries[0]["pages_per_block"])
     ] == "pallas"
 
 

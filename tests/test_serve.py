@@ -7,6 +7,7 @@ every request's emitted tokens are IDENTICAL to decoding that request
 alone via the plain full-forward path — continuous batching and paging
 are pure capacity features, never accuracy features."""
 
+import itertools
 import random
 
 import jax
@@ -196,6 +197,116 @@ def test_ragged_kernel_matches_eager(rng, pages_per_block, heads, d):
         np.asarray(out)[active], np.asarray(ref)[active],
         atol=2e-5, rtol=2e-5,
     )
+
+
+# The four-point invariant of ``ops/pallas/paged_attention.py``, over the
+# ROW PATTERNS that decide whether a prefetch carried from row to row is
+# sound (``chip_smoke.py`` builds them, and runs the same ones COMPILED on
+# the chip).  The pool reads POISON wherever no row may read, so a slot
+# read stale or early is a gap of thousands, not noise.
+#
+# ``dma`` None: Pallas's plain interpreter, which runs a copy where it is
+# started and so shows a wrong page, slot or parity and nothing else.
+# ``"eager"`` / ``"on_wait"``: the TPU interpreter with its race detector
+# on.  It reports a read of a slot whose DMA was not waited for (point 1)
+# and a DMA into a slot whose reads are not all issued (point 3), whichever
+# way the copy is run; ``"on_wait"`` runs a copy only where it is waited
+# for, so an early read also reads stale numbers; ``"eager"`` counts a
+# semaphore up where a copy is started, so one started twice, never waited
+# for (point 1) or left in flight by the last program (point 4) prints a
+# non-zero count at the kernel's exit; a wait for a copy nobody started
+# (a row of length 0 that waits, point 2) hangs in both.  Point 2's other
+# half is the count of copies: exactly those of the live rows' blocks.
+_TRACE_KEYS = itertools.count()
+_ROW_SIZE = dict(page_size=8, heads=2, head_dim=64, rows=16, num_pages=128,
+                 table_pages=24, latent_heads=2)
+_ROW_CASES = [
+    (pattern, pp, parity, width, None)
+    for pattern in ("zeros_first", "zeros_last", "zeros_between",
+                    "one_block", "odd_even", "partial_last")
+    for pp in (1, 2, 4) for parity in (0, 1) for width in (1, 3)
+] + [
+    ("latent_decode", pp, parity, 1, None)
+    for pp in (1, 2, 4) for parity in (0, 1)
+] + [
+    # 208 and 256 rows: seven seconds a case in the interpreter
+    (pattern, pp, parity, 1, None)
+    for pattern in ("zeros_200", "latent_mixed")
+    for pp, parity in ((1, 0), (2, 1), (4, 0), (4, 1))
+] + [
+    (pattern, pp, parity, 3, "eager")
+    for pattern in ("zeros_between", "one_block", "odd_even",
+                    "partial_last")
+    for pp in (1, 2, 4) for parity in (0, 1)
+] + [
+    ("zeros_between", 1, 1, 3, "on_wait"), ("one_block", 2, 0, 1, "on_wait"),
+    ("odd_even", 4, 1, 3, "on_wait"), ("partial_last", 2, 1, 3, "on_wait"),
+    ("zeros_first", 4, 0, 1, "on_wait"), ("zeros_last", 1, 0, 3, "on_wait"),
+    ("latent_decode", 2, 1, 1, "eager"), ("latent_decode", 4, 0, 1, "on_wait"),
+    ("latent_mixed", 4, 1, 1, "eager"), ("latent_mixed", 2, 0, 1, "on_wait"),
+]
+
+
+@pytest.mark.parametrize(
+    "pattern,pages_per_block,parity,width,dma", _ROW_CASES,
+    ids=["%s-pp%d-parity%d-w%d-%s" % (*c[:4], c[4] or "plain")
+         for c in _ROW_CASES])
+def test_ragged_kernel_row_patterns(pattern, pages_per_block, parity, width,
+                                    dma, monkeypatch, capfd):
+    """Rows of length 0 first / last / between live rows / 200 in a row,
+    rows of exactly one block, odd and even block counts side by side, a
+    last block partly filled, and the latent step's shape (one head of
+    640 lanes, one pool as keys and values, three passes, 256 rows of
+    which 31 are live, the tiles of one prompt sharing a page table with
+    lengths growing by 4), at 1, 2 and 4 pages a block with the rows'
+    blocks starting in either K/V slot."""
+    import chip_smoke as cs
+    from unicore_tpu.ops.pallas import paged_attention as pa
+
+    size = dict(cs.KERNEL, **_ROW_SIZE)
+    if pattern.startswith("latent_"):
+        case = cs.latent_row_case(pattern[len("latent_"):], size,
+                                  pages_per_block, parity, seed=5)
+    else:
+        case = cs.ragged_row_case(pattern, size, pages_per_block, parity,
+                                  width, seed=5)
+    copies = {"started": 0, "waited": 0}
+    if dma is not None:
+        from jax._src.pallas.mosaic.interpret import (
+            interpret_pallas_call as tpu_interpreter,
+        )
+        from jax.experimental.pallas import tpu as pltpu
+
+        def counted(name, fn):
+            def call(*args, **kwargs):
+                copies[name] += 1
+                return fn(*args, **kwargs)
+            return call
+
+        for name, fn in (("started", "dma_start"), ("waited", "dma_wait")):
+            monkeypatch.setattr(tpu_interpreter, fn, counted(
+                name, getattr(tpu_interpreter, fn)))
+        # a seed of its own keys a trace of its own under ``_call``'s jit,
+        # so the counters above are the ones the trace calls
+        params = pltpu.InterpretParams(
+            dma_execution_mode=dma, detect_races=True,
+            random_seed=next(_TRACE_KEYS))
+        monkeypatch.setattr(pa, "pallas_interpret", lambda: params)
+    gap, finite = cs.ragged_case_gap(case)
+    assert finite  # empty rows and masked cells too
+    assert gap <= (2e-5 if not case["three_pass"] else 1e-4), gap
+    if dma is None:
+        return
+    # points 1 and 3: no read of a slot before its copy was waited for, no
+    # copy into a slot that is still read
+    assert not tpu_interpreter.races.races_found
+    # points 1, 2 and 4: exactly the live rows' blocks were copied (keys
+    # and values of each page), each waited for once, none left in flight
+    blocks = sum(-(-int(n) // (pages_per_block * size["page_size"]))
+                 for n in case["lengths"])
+    assert copies == {"started": 2 * pages_per_block * blocks,
+                      "waited": 2 * pages_per_block * blocks}, copies
+    assert "non-zero count" not in capfd.readouterr().out
 
 
 def test_ragged_decode_wrapper_matches_eager(rng):

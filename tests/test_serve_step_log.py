@@ -268,7 +268,9 @@ def test_first_step_joins_a_request_to_the_step_that_emitted_it(full):
 def test_first_step_under_a_prefix_hit_and_after_an_eviction(lm):
     """An injected clock counts calls of itself, so it never meets
     ``perf_counter``: the join is by ordinal."""
-    ticks = iter(range(1, 10_000))
+    # below zero, where ``perf_counter`` never reads (a machine that has
+    # been up for under three hours reads under 10,000 there)
+    ticks = iter(range(-10_000, 0))
     engine = engine_of(lm, ROWS + 1, clock=lambda: float(next(ticks)))
     doc = prompts(1, seed=11, shared=16, longest=7)[0]
     first = Request(prompt=doc + [9, 8, 7], max_new_tokens=2, request_id="a")
@@ -294,8 +296,8 @@ def test_first_step_under_a_prefix_hit_and_after_an_eviction(lm):
     assert hit[0]["first_step"] == cold[0]["first_step"] + 2
     assert evicted[1]["ordinal"] > hit[0]["first_step"]
     for f, step in (cold, hit, evicted):
-        # the injected clock's small whole numbers, perf_counter's large
-        assert f["admitted_at"] < f["first_token_at"] < 10_000 < step[
+        # the injected clock's negative whole numbers, perf_counter's positive
+        assert f["admitted_at"] < f["first_token_at"] < 0 < step[
             "emitted_at"]
     # a resumed sequence keeps its first admission: the row's prefill is
     # the result's first token less its wait

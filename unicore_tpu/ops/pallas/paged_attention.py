@@ -13,6 +13,36 @@ online-softmax accumulator per (head, query) — the gathered
 exists, and per-row ``lengths`` make the work RAGGED: a row holding 3
 pages stops after 3 DMAs regardless of the table width.
 
+The K/V scratch is TWO slots, and the DMAs of the block after this one
+are in flight while this one is multiplied: the next block of the row
+or, under the row's last block, block 0 of the NEXT LIVE ROW (the next
+row whose length is not 0, found by walking ``lengths`` in SMEM).  Block
+``n`` of the call, counted over its live rows, lands in slot ``n % 2``.
+Two words of SMEM scratch go from program to program: whether a block 0
+is in flight, and the slot the next block lands in.  The online softmax
+visits the same blocks in the same order as a kernel that waited for
+each, so the arithmetic is the same, operation for operation.  THE
+INVARIANT, which every caller's rows must find kept (the latent step
+hands over 256 rows of which most are empty, empty ones between live
+ones; ``tests/test_serve.py`` ``test_ragged_kernel_row_patterns`` and
+``chip_smoke.py``'s kernel phase name each point):
+
+1. every DMA that is started is waited for exactly once, before any read
+   of its destination slot, by the program that started it or, for the
+   carried block 0, by the next program that has a block;
+2. a row of length 0 starts no DMA and waits for none, wherever it lies
+   (first, last, between two live rows, many in a row), and touches
+   neither word of the SMEM scratch;
+3. no DMA writes a slot that a dot not yet issued still reads: the slot
+   of block ``n + 1`` is the one block ``n - 1`` was multiplied from,
+   and its DMAs start after block ``n - 1``'s dots in program order;
+4. the last program leaves no DMA in flight and no semaphore signalled:
+   the last live row finds no next live row and starts nothing.
+
+A Pallas interpreter runs a copy where it is started (or where it is
+waited for): it shows a wrong slot and, with its race detector, a wrong
+order, never a race's timing.  Only the compiled kernel on the chip can.
+
 Layout: the pool is ``[num_slots, H*D]`` — heads folded into the lane
 dimension, so a page is a ``[page_size, H*D]`` tile-aligned slab and one
 DMA moves it (a ``[.., H, 64]`` pool has a 64-wide minor dim, which the
@@ -57,9 +87,18 @@ from jax.experimental.pallas import tpu as pltpu
 from unicore_tpu.ops.backend import pallas_interpret
 
 _LANES = 128
-# scoped-VMEM budget for the two KV scratch buffers (q, out and the
-# accumulators are counted by the compiler on top of it)
+# K/V scratch slots: one is multiplied while the other is filled
+SLOTS = 2
+# VMEM budget for ONE slot's K and V blocks (what decides the pages a
+# block; ``vmem_limit_bytes`` counts both slots and everything else)
 _SCRATCH_BUDGET_BYTES = 8 << 20
+# the chip's default scoped-VMEM limit, and what the compiler is left for
+# its own temporaries (the [T, S] scores, a three-pass dot's split
+# operands) above the buffers ``vmem_limit_bytes`` counts
+_DEFAULT_SCOPED_VMEM_BYTES = 16 << 20
+_COMPILER_ROOM_BYTES = 6 << 20
+# the two words of SMEM scratch one program hands the next
+_IN_FLIGHT, _NEXT_SLOT = 0, 1
 
 
 def slab_heads(heads, head_dim):
@@ -82,8 +121,11 @@ def supported(heads, head_dim, page_size, itemsize):
 def pick_pages_per_block(num_table_pages, page_size, head_dim,
                          num_heads=8, itemsize=2):
     """Pages DMA'd per online-softmax block: ~256 gathered slots per
-    block — enough rows to amortize the DMA issue latency — held inside
-    the scratch budget.  The 256 has not been swept on this machine."""
+    block — enough rows to amortize the DMA issue latency — with one
+    slot's K and V held inside the scratch budget.  The second slot does
+    not shrink a block (``vmem_limit_bytes`` pays for it instead), so
+    every shape walks the blocks it walked with one slot.  The 256 has
+    not been swept on this machine."""
     def fits(pp):
         return (2 * pp * page_size * num_heads * head_dim * itemsize
                 <= _SCRATCH_BUDGET_BYTES)
@@ -92,6 +134,24 @@ def pick_pages_per_block(num_table_pages, page_size, head_dim,
     while pp > 1 and not fits(pp):
         pp -= 1
     return pp
+
+
+def vmem_limit_bytes(cells, lanes, heads, blk_slots, kv_itemsize,
+                     q_itemsize):
+    """Scoped VMEM one program may use, from its shapes alone: both
+    slots' K and V blocks, the queries and the outputs (each double-
+    buffered by the pipeline), the accumulator, the running max and sum
+    (a ``[cells, 1]`` column a head fills whole 128-lane tiles), and
+    ``_COMPILER_ROOM_BYTES``; never under the chip's default of 16 MB.
+    ``opt_1.3b``'s mixed program (128 cells x 2,048 lanes, blocks of 256
+    float32 slots) comes to 23 MB, the hybrid's (64 x 3,840) to 27.6 MB,
+    of the 128 MB of VMEM a v5e has."""
+    kv = SLOTS * 2 * blk_slots * lanes * kv_itemsize
+    piped = 2 * 2 * cells * lanes * q_itemsize
+    acc = cells * lanes * 4
+    stats = 2 * heads * (-(-cells // 8) * 8) * _LANES * 4
+    return max(_DEFAULT_SCOPED_VMEM_BYTES,
+               kv + piped + acc + stats + _COMPILER_ROOM_BYTES)
 
 
 def _dot(a, b, contract, three_pass):
@@ -116,9 +176,10 @@ def _dot(a, b, contract, three_pass):
 
 
 def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
-            k_scr, v_scr, m_scr, l_scr, acc_scr, sems, *, page_size,
+            k_scr, v_scr, m_scr, l_scr, acc_scr, sems, carry, *, page_size,
             pages_per_block, scale, heads, head_dim, three_pass):
     b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
     length = len_ref[b]
     n_table = pt_ref.shape[1]
     blk_slots = pages_per_block * page_size
@@ -136,22 +197,63 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
     l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def body(i, carry):
-        # issue all this block's page DMAs, then wait: table rows are
-        # padded with the trash page 0, so a clamped out-of-range read
-        # fetches page 0 — always a valid pool page, masked below
-        copies = []
+    # what one program hands the next (SMEM scratch outlives a program):
+    # whether block 0 of the next live row is in flight, and the slot the
+    # next block lands in
+    @pl.when(b == 0)
+    def _():
+        carry[_IN_FLIGHT] = 0
+        carry[_NEXT_SLOT] = 0
+
+    def copies(row, blk, slot):
+        """The DMAs of block ``blk`` of row ``row`` into ``slot``: the
+        same descriptors start them and wait for them.  Table rows are
+        padded with the trash page 0, so a clamped out-of-range read
+        fetches page 0 — always a valid pool page, masked below."""
+        out = []
         for j in range(pages_per_block):
-            page = pt_ref[b, jnp.minimum(i * pages_per_block + j,
-                                         n_table - 1)]
+            page = pt_ref[row, jnp.minimum(blk * pages_per_block + j,
+                                           n_table - 1)]
             rows = pl.ds(j * page_size, page_size)
             for src, dst, s in ((kp_hbm, k_scr, 0), (vp_hbm, v_scr, 1)):
-                cp = pltpu.make_async_copy(
-                    src.at[page], dst.at[rows], sems.at[s, j]
-                )
+                out.append(pltpu.make_async_copy(
+                    src.at[page], dst.at[slot, rows], sems.at[slot, s, j]))
+        return out
+
+    # the next row that has a block (``n_rows``: none): rows of length 0
+    # are stepped over here and do nothing below, so they neither start
+    # nor wait (invariant 2); an empty row does not search either (the
+    # latent step's trailing empty tiles would each walk to the end)
+    nxt = jax.lax.while_loop(
+        lambda r: jnp.logical_and(
+            r < n_rows, len_ref[jnp.minimum(r, n_rows - 1)] == 0),
+        lambda r: r + 1, jnp.where(n_blocks > 0, b + 1, n_rows))
+    has_next = nxt < n_rows
+    slot0 = carry[_NEXT_SLOT]
+
+    # the first live row of the call: nobody fetched its block 0
+    @pl.when(jnp.logical_and(n_blocks > 0, carry[_IN_FLIGHT] == 0))
+    def _():
+        for cp in copies(b, 0, slot0):
+            cp.start()
+
+    def body(i, loop_carry):
+        slot = (slot0 + i) % SLOTS
+        # the block after this one goes into the other slot while this
+        # one is multiplied: this row's next block or, under its last,
+        # block 0 of the next live row.  The other slot was read by the
+        # block before this one, whose dots are issued (invariant 3)
+        in_row = i + 1 < n_blocks
+
+        @pl.when(jnp.logical_or(in_row, has_next))
+        def _():
+            row = jnp.where(in_row, b, jnp.minimum(nxt, n_rows - 1))
+            for cp in copies(row, jnp.where(in_row, i + 1, 0), 1 - slot):
                 cp.start()
-                copies.append(cp)
-        for cp in copies:
+
+        # started once (by the block before, or above), waited once, here,
+        # before the first read of the slot (invariant 1)
+        for cp in copies(b, i, slot):
             cp.wait()
         cols = i * blk_slots + jax.lax.broadcasted_iota(
             jnp.int32, (1, blk_slots), 1
@@ -162,8 +264,8 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
         for sl in range(heads // group):
             lanes = pl.ds(sl * slab, slab)
             q = q_ref[0, :, lanes] * scale  # [T, slab]
-            k = k_scr[:, lanes]             # [S, slab]
-            v = v_scr[:, lanes]
+            k = k_scr[slot, :, lanes]       # [S, slab]
+            v = v_scr[slot, :, lanes]
             acc = acc_scr[:, lanes]
             for g in range(group):
                 h = sl * group + g
@@ -188,9 +290,17 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
                 # only this head's lanes of pv are its p @ v
                 acc = jnp.where(mine, acc * alpha + pv, acc)
             acc_scr[:, lanes] = acc
-        return carry
+        return loop_carry
 
     jax.lax.fori_loop(0, n_blocks, body, 0)
+
+    # a live row hands on what its last block started; the last live row
+    # started nothing, so the call ends with no DMA in flight (invariant 4)
+    @pl.when(n_blocks > 0)
+    def _():
+        carry[_IN_FLIGHT] = has_next.astype(jnp.int32)
+        carry[_NEXT_SLOT] = (slot0 + n_blocks) % SLOTS
+
     for sl in range(heads // group):
         lanes = pl.ds(sl * slab, slab)
         denom = jnp.zeros((t, slab), jnp.float32)
@@ -248,12 +358,13 @@ def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
         ],
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((blk_slots, hd), k_pages3.dtype),
-            pltpu.VMEM((blk_slots, hd), v_pages3.dtype),
+            pltpu.VMEM((SLOTS, blk_slots, hd), k_pages3.dtype),
+            pltpu.VMEM((SLOTS, blk_slots, hd), v_pages3.dtype),
             pltpu.VMEM((heads, t, 1), jnp.float32),   # running max
             pltpu.VMEM((heads, t, 1), jnp.float32),   # running sum
             pltpu.VMEM((t, hd), jnp.float32),         # accumulator
-            pltpu.SemaphoreType.DMA((2, pages_per_block)),
+            pltpu.SemaphoreType.DMA((SLOTS, 2, pages_per_block)),
+            pltpu.SMEM((2,), jnp.int32),              # program to program
         ],
     )
     return pl.pallas_call(
@@ -267,8 +378,12 @@ def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
         interpret=interpret,
         name="ragged_paged_attention",
         compiler_params=pltpu.CompilerParams(
-            # the scratch/DMA pattern serializes programs on-core anyway
+            # programs run in row order on one core: a row's last block
+            # starts the next live row's first, and SMEM carries the slot
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit_bytes(
+                t, hd, heads, blk_slots, k_pages3.dtype.itemsize,
+                q3.dtype.itemsize),
         ),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       positions.astype(jnp.int32)[:, :, None], q3, k_pages3, v_pages3)

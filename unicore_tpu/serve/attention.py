@@ -29,7 +29,8 @@ Two implementations:
   materializes.  Gated through ``ops/backend.py`` (``use_pallas``)
   and the kernel's static shape rule (``supported``); its page block is
   ``pick_pages_per_block``'s, from shape.  The path each compiled width
-  took is in ``backend.dispatch_report()``; a kernel the chip's
+  took is in ``backend.dispatch_report()``, with the pages a block and
+  the K/V slots of a shape that took the kernel; a kernel the chip's
   compiler refuses fails the step's compile.
 """
 
@@ -171,13 +172,24 @@ def _kernel_ok(q, k_pages, page_table, page_size):
     )
 
 
+def _kernel_choice(pages_per_block):
+    """What the kernel was given, for ``dispatch_report()``'s description
+    of a shape that took it: pages a block and K/V slots."""
+    if pages_per_block is None:
+        return ""
+    from unicore_tpu.ops.pallas import paged_attention as pl_pa
+
+    return " pp%d slots%d" % (pages_per_block, pl_pa.SLOTS)
+
+
 def paged_attention(q, k_pages, v_pages, page_table, positions, lengths,
                     page_size, scale):
     """Dispatching paged attention (see module docstring)."""
     from unicore_tpu.ops.backend import note_dispatch
 
     pages_per_block = _kernel_ok(q, k_pages, page_table, page_size)
-    desc = "b%d w%d h%d d%d page%d %s" % (*q.shape, page_size, q.dtype.name)
+    desc = "b%d w%d h%d d%d page%d %s%s" % (
+        *q.shape, page_size, q.dtype.name, _kernel_choice(pages_per_block))
     if note_dispatch("ragged_paged_attention", desc,
                      pages_per_block is not None):
         from unicore_tpu.ops.pallas import paged_attention as pl_pa
@@ -289,8 +301,9 @@ def write_latent_and_attend(q, entry, pages, paged, positions, scale,
     with jax.named_scope("mla_attend_" + form):
         if note_dispatch(
                 "latent_attention_" + form,
-                "b%d cells%d lanes%d page%d %s" % (
-                    B * tiles, t * H, W, paged.page_size, q.dtype.name),
+                "b%d cells%d lanes%d page%d %s%s" % (
+                    B * tiles, t * H, W, paged.page_size, q.dtype.name,
+                    _kernel_choice(pages_per_block)),
                 pages_per_block is not None):
             from unicore_tpu.ops.pallas import paged_attention as pl_pa
 
