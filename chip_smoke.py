@@ -759,24 +759,68 @@ def ragged_row_case(pattern, size, pages_per_block, parity, width, seed):
     import numpy as np
 
     rng = np.random.RandomState(seed)
-    ps, heads, d = size["page_size"], size["heads"], size["head_dim"]
+    ps = size["page_size"]
     lengths = ragged_row_lengths(
         pattern, size["rows"], pages_per_block * ps, parity)
     positions = np.full((len(lengths), width), -1, np.int32)
     for r, n in enumerate(lengths):
         new = min(n, width if r % 2 else 1)
         positions[r, :new] = n - new + np.arange(new)
+    return _per_head_case(rng, lengths, positions, size, pages_per_block)
+
+
+def _per_head_case(rng, lengths, positions, size, pages_per_block, **more):
+    """The operands of a per-head case from its rows' lengths and query
+    positions: a pool and tables of their own, queries of whole bfloat16
+    numbers."""
+    import numpy as np
+
+    ps, heads, d = size["page_size"], size["heads"], size["head_dim"]
     k, table = _pool_and_table(rng, lengths, list(range(len(lengths))), ps,
                                size["table_pages"], size["num_pages"],
                                heads * d)
     # values: the keys' numbers a lane further on (a POISON slot is POISON
     # in every lane, so it stays one)
     v = np.roll(k, 1, axis=1)
-    q = _bf16_whole(rng.randn(len(lengths), width, heads, d))
+    q = _bf16_whole(rng.randn(*positions.shape, heads, d))
     return dict(q=q, k_pages=k, v_pages=v, page_table=table, positions=positions,
                 lengths=np.asarray(lengths, np.int32), page_size=ps,
                 scale=d ** -0.5, pages_per_block=pages_per_block,
-                three_pass=False)
+                three_pass=False, **more)
+
+
+def window_row_case(size, pages_per_block, parity, width, seed):
+    """The row patterns a sliding layer brings, one call: a window that
+    starts in the middle of a page (row 0), an empty row after a windowed
+    one (1), a window that starts on a page's edge in the middle of a
+    block or, at one page a block, on a block's (2), one that starts in
+    block 0 (3), a row shorter than the window (4), a chunk whose first
+    query sees a block its last one does not (6), a windowed last row.
+    ``window`` is a block and three slots; ``blocks`` counts the blocks
+    the rows walk (those from each row's first visible one)."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    ps = size["page_size"]
+    blk = pages_per_block * ps
+    window = blk + 3
+    live = {0: 2 * blk + ps // 2 + 1 + window, 2: blk + ps + window,
+            3: window + 2, 4: max(1, window - 5), 6: 4 * blk + 1,
+            size["rows"] - 1: 2 * blk}
+    lengths = [0] * size["rows"]
+    for r, n in live.items():
+        lengths[r] = n
+    lengths[0] += parity * blk
+    positions = np.full((len(lengths), width), -1, np.int32)
+    blocks = 0
+    for r, n in enumerate(lengths):
+        new = min(n, width if r % 2 == 0 and r else 1)
+        positions[r, :new] = n - new + np.arange(new)
+        if n:
+            blocks += -(-n // blk) - min(
+                max(n - new - window + 1, 0) // blk, -(-n // blk) - 1)
+    return _per_head_case(rng, lengths, positions, size, pages_per_block,
+                          window=window, blocks=blocks)
 
 
 def latent_row_case(form, size, pages_per_block, parity, seed):
@@ -842,6 +886,8 @@ def ragged_case_gap(case):
     case = dict(case)
     q, k_np, v_np, table, positions, lengths = (case.pop(n) for n in (
         "q", "k_pages", "v_pages", "page_table", "positions", "lengths"))
+    case.pop("blocks", None)
+    window = case.get("window", 0)
     k = jnp.asarray(k_np)
     v = k if v_np is k_np else jnp.asarray(v_np)
     out = np.asarray(ragged_paged_attention(
@@ -852,7 +898,7 @@ def ragged_case_gap(case):
         ref = np.asarray(paged_attention_reference(
             jnp.asarray(q[live]), k, v, jnp.asarray(table[live]),
             jnp.asarray(positions[live]), jnp.asarray(lengths[live]),
-            case["page_size"], case["scale"]))
+            case["page_size"], case["scale"], window=window))
     cells = positions[live] >= 0
     return (float(np.abs(out[live] - ref)[cells].max()),
             bool(np.isfinite(out).all()))
@@ -875,6 +921,10 @@ def kernel_phase(seed, size):
                 f"latent_{form}": latent_row_case(
                     form, size, pp, parity, seed)
                 for form in ("decode", "mixed")})
+            cases.update({
+                f"window-w{width}": window_row_case(
+                    size, pp, parity, width, seed)
+                for width in size["widths"]})
             for name, case in cases.items():
                 gap, finite = ragged_case_gap(case)
                 limit = size["latent_gap_limit" if case["three_pass"]

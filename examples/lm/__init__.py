@@ -6,4 +6,4 @@ user code.  The reference ships only the BERT example; this exercises the
 decoder stack end-to-end the same way.
 """
 
-from . import hybrid, lfm2_moe, loss, model, pangu_moe, task  # noqa: F401 — trigger @register_* decorators
+from . import hybrid, laguna, lfm2_moe, loss, model, pangu_moe, task  # noqa: F401 — trigger @register_* decorators

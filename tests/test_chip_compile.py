@@ -290,3 +290,44 @@ def test_pangu_mla_step_programs_compile(width, one_chip, compiled_kernels):
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 14.5e9 < mem.argument_size_in_bytes < 14.7e9, mem
     assert held < 16e9, mem
+
+
+# the cell `laguna_swa_mixedlen`: the ragged kernel at the shapes the two
+# kinds of layer hand it (8 K/V heads of 128; 6 or 8 query cells a
+# position, so 768 / 1,024 cells a row of a mixed step; a global layer
+# over a table of 264 pages, a sliding one with its window over a row's
+# 11), in THREE passes (this model's step's rule), with what the shape
+# rules give each: 4 pages a block, and a scoped VMEM of up to 52 MB of the
+# chip's 128 (queries and outputs of 1,024 cells x 1,024 lanes are 4 MB a
+# buffer, the running max and sum of 8 heads 4 MB each, and 14 MB for the
+# three-pass temporaries of the unrolled slabs).  The whole step programs were compiled for the
+# described chip by hand (PERF.md section 6, PR 43: arguments 13.24 GB,
+# temporaries 0.42 GB); here the kernels alone, two seconds each
+WINDOW_KERNEL_SHAPES = {
+    "global-w1": ((32, 6, 3000, 264, 0), 16.0),
+    "global-w128": ((32, 768, 3000, 264, 0), 41.5),
+    "sliding-w1": ((32, 8, 329, 11, 512), 16.0),
+    "sliding-w128": ((32, 1024, 329, 11, 512), 52.0),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(WINDOW_KERNEL_SHAPES))
+def test_window_kernel_shapes_compile(shape, one_chip, compiled_kernels):
+    from unicore_tpu.ops.pallas import paged_attention as pa
+
+    (rows, cells, num_pages, table, window), limit_mb = (
+        WINDOW_KERNEL_SHAPES[shape])
+    heads, d = 8, 128
+    pp = pa.pick_pages_per_block(table, 64, d, num_heads=heads, itemsize=4)
+    assert pp == 4
+    limit = pa.vmem_limit_bytes(cells, heads * d, heads, pp * 64, 4, 4,
+                                three_pass_slabs=heads)
+    assert round(limit / 2 ** 20, 2) == limit_mb
+    fn = functools.partial(
+        pa.ragged_paged_attention, page_size=64, scale=d ** -0.5,
+        window=window, three_pass=True)   # this model's step's rule
+    pool = ((num_pages * 64, heads * d), F32)
+    text = _compile(fn, one_chip, ((rows, cells, heads, d), F32), pool, pool,
+                    ((rows, table), I32), ((rows, cells), I32),
+                    ((rows,), I32))
+    assert text.count("tpu_custom_call") == 1

@@ -94,7 +94,8 @@ def test_train_and_serve_phases_rehearse_on_cpu(tmp_path):
 
 def test_kernel_phase_rehearses_on_cpu():
     """The kernel phase at a tiny width, interpreted: two of its row
-    patterns and both latent forms, either slot parity.  (Every pattern,
+    patterns, both latent forms and the sliding layer's rows (PR 43),
+    either slot parity.  (Every pattern,
     and what the interpreters can say of the kernel's invariant:
     ``tests/test_serve.py`` ``test_ragged_kernel_row_patterns``.)"""
     import chip_smoke as cs
@@ -104,7 +105,7 @@ def test_kernel_phase_rehearses_on_cpu():
                 patterns=("zeros_between", "odd_even"), latent_heads=2,
                 latent_rows=11, latent_chunk=16)
     rep = cs.kernel_phase(1, tiny)
-    assert rep["cases"] == 8 and rep["gap_max"] < 2e-5
+    assert rep["cases"] == 10 and rep["gap_max"] < 2e-5
     # a pool too small for the rows' pages is refused, not wrapped around
     with pytest.raises(cs.SmokeFailure, match="do not fit"):
         cs.ragged_row_case("odd_even", dict(tiny, num_pages=8), 2, 0, 3, 1)

@@ -208,6 +208,50 @@ def test_the_latent_cells_kernel_choices(on_chip, width, rows, cells):
     assert moe.pick_block_rows(expected, held) == (8 if width == 1 else 32)
 
 
+@pytest.mark.parametrize("width", [1, 128])
+@pytest.mark.parametrize("kind,heads,table_pages", [
+    ("full_attention", 48, 264), ("sliding_attention", 64, 11)])
+def test_the_window_cells_kernel_choices(on_chip, kind, heads, table_pages,
+                                         width):
+    """``laguna_swa_mixedlen``: layers of two kinds hand the ragged kernel
+    the same 8 K/V heads of 128 with 6 or 8 query cells a position (768 /
+    1,024 to a row of a mixed step), a global layer over the 264 pages of
+    the context and a sliding one over a row's 11 window pages; both take
+    4 pages of 64 a block.  A share of 64 of 256 experts of width 512
+    sizes its blocks from the choices it can expect: 8 rows for a decode
+    step's 32 tokens, 32 for a mixed step's 512."""
+    from unicore_tpu.ops import moe
+    from unicore_tpu.serve.kv_pool import PagedKVPool
+
+    cfg = _config("laguna_xs2")
+    eng, kv, d = cfg["engine"], cfg["num_key_value_heads"], cfg["head_dim"]
+    assert heads in cfg["num_attention_heads_per_layer"] and kind in cfg[
+        "layer_types"]
+    assert width in (1, eng["prefill_chunk"]) and (kv, d) == (8, 128)
+    window = cfg["sliding_window"] if kind == "sliding_attention" else 0
+    pool = PagedKVPool(eng["num_pages"], eng["page_size"],
+                       prefix_cache=False, window=cfg["sliding_window"],
+                       num_window_pages=329, window_slack=8)
+    assert pool.window_reserve == 10
+    assert 1 + eng["max_batch"] * pool.window_reserve + 8 == 329
+    pages = (pool.window_row_pages(eng["prefill_chunk"]) if window
+             else cfg["max_position_embeddings"] // eng["page_size"])
+    assert pages == table_pages
+    q = SDS((eng["max_batch"], width * (heads // kv), kv, d), F32)
+    assert q.shape[1] == width * (6 if heads == 48 else 8)
+    slots = (329 if window else eng["num_pages"]) * eng["page_size"]
+    table = SDS((eng["max_batch"], pages), jnp.int32)
+    assert pa.supported(kv, d, eng["page_size"], 4)
+    assert serve_attention._kernel_ok(q, SDS((slots, kv * d), F32), table,
+                                      eng["page_size"]) == 4
+    held, k = cfg["num_experts"], cfg["num_experts_per_tok"]
+    assert (held, cfg["router_outputs"], cfg["moe_intermediate_size"]) == (
+        64, 256, 512)
+    step_tokens = eng["max_batch"] if width == 1 else 512
+    expected = -(-step_tokens * k * held // cfg["router_outputs"])
+    assert moe.pick_block_rows(expected, held) == (8 if width == 1 else 32)
+
+
 SD_SHAPES = {
     # name: (x, mask, bias), all bf16
     "bert_base": ((64, 12, 512, 512), None, (1, 12, 512, 512)),

@@ -406,5 +406,8 @@ def test_three_passes_multiply_float32_as_the_rest_of_the_step_does():
 
     assert "three_pass=True" in inspect.getsource(
         serve_attention.write_latent_and_attend)
-    assert "three_pass" not in inspect.getsource(
-        serve_attention.paged_attention)
+    # per-head K/V pages take them only where a model's step asks (PR 43:
+    # the window model's); nobody gets them by default
+    for fn in (serve_attention.paged_attention,
+               serve_attention.write_and_attend):
+        assert inspect.signature(fn).parameters["three_pass"].default is False

@@ -244,6 +244,14 @@ _ROW_CASES = [
     ("zeros_first", 4, 0, 1, "on_wait"), ("zeros_last", 1, 0, 3, "on_wait"),
     ("latent_decode", 2, 1, 1, "eager"), ("latent_decode", 4, 0, 1, "on_wait"),
     ("latent_mixed", 4, 1, 1, "eager"), ("latent_mixed", 2, 0, 1, "on_wait"),
+] + [
+    # a sliding layer's rows (PR 43; ``chip_smoke.window_row_case``)
+    ("window", pp, parity, width, None)
+    for pp in (1, 2, 4) for parity in (0, 1) for width in (1, 3)
+] + [
+    ("window", 1, 1, 3, "eager"), ("window", 2, 0, 1, "eager"),
+    ("window", 4, 1, 3, "eager"), ("window", 2, 1, 3, "on_wait"),
+    ("window", 4, 0, 1, "on_wait"),
 ]
 
 
@@ -258,8 +266,11 @@ def test_ragged_kernel_row_patterns(pattern, pages_per_block, parity, width,
     last block partly filled, and the latent step's shape (one head of
     640 lanes, one pool as keys and values, three passes, 256 rows of
     which 31 are live, the tiles of one prompt sharing a page table with
-    lengths growing by 4), at 1, 2 and 4 pages a block with the rows'
-    blocks starting in either K/V slot."""
+    lengths growing by 4), and a sliding layer's rows (a window that
+    starts mid-page, mid-block and in block 0, a row shorter than the
+    window, an empty row after a windowed one: only the blocks from each
+    row's first visible one are copied), at 1, 2 and 4 pages a block with
+    the rows' blocks starting in either K/V slot."""
     import chip_smoke as cs
     from unicore_tpu.ops.pallas import paged_attention as pa
 
@@ -267,6 +278,9 @@ def test_ragged_kernel_row_patterns(pattern, pages_per_block, parity, width,
     if pattern.startswith("latent_"):
         case = cs.latent_row_case(pattern[len("latent_"):], size,
                                   pages_per_block, parity, seed=5)
+    elif pattern == "window":
+        case = cs.window_row_case(size, pages_per_block, parity, width,
+                                  seed=5)
     else:
         case = cs.ragged_row_case(pattern, size, pages_per_block, parity,
                                   width, seed=5)
@@ -304,6 +318,9 @@ def test_ragged_kernel_row_patterns(pattern, pages_per_block, parity, width,
     # and values of each page), each waited for once, none left in flight
     blocks = sum(-(-int(n) // (pages_per_block * size["page_size"]))
                  for n in case["lengths"])
+    if pattern == "window":  # blocks behind the window: not read
+        assert case["blocks"] < blocks
+        blocks = case["blocks"]
     assert copies == {"started": 2 * pages_per_block * blocks,
                       "waited": 2 * pages_per_block * blocks}, copies
     assert "non-zero count" not in capfd.readouterr().out

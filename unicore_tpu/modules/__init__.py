@@ -5,8 +5,10 @@ from unicore_tpu.ops import softmax_dropout  # noqa: F401
 
 from .layer_norm import LayerNorm  # noqa: F401
 from .rotary import (  # noqa: F401
+    RotarySpec,
     apply_rotary,
     apply_rotary_qk,
+    apply_rotary_spec,
     rotary_cos_sin,
 )
 from .multihead_attention import (  # noqa: F401
@@ -25,6 +27,7 @@ from .transformer_decoder import (  # noqa: F401
     TransformerDecoderLayer,
 )
 from .pattern_decoder import (  # noqa: F401
+    AttentionSpec,
     ExpertFFN,
     ExpertSpec,
     FullAttentionMixer,

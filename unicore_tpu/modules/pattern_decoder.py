@@ -27,7 +27,16 @@ all addressed through :class:`~unicore_tpu.serve.attention.PagedMeta`:
   width]`` rectangle (``serve/attention.py`` ``write_and_attend``, which
   also folds grouped query heads).  QK-norm over the whole projection
   and no rotary (Olmo), or per head with rotary after it
-  (``qk_norm_per_head``, ``rope_theta``).
+  (``qk_norm_per_head``, ``rope_theta``).  A layer may bring an
+  :class:`AttentionSpec` of its own (``PatternDecoder.attention``, one
+  entry a layer): its query heads and an explicit ``head_dim`` (the
+  projections are ``heads x head_dim`` wide whatever the hidden size), a
+  SLIDING WINDOW (a query sees ``window`` keys up to its own; the
+  layer's pages are then the serve tier's window kind, ``k_window_pages``
+  / ``v_window_pages``, which the pool trims behind the window), its
+  rotary (:class:`~unicore_tpu.modules.rotary.RotarySpec`: part of the
+  head, YaRN), no QK-norm, and a per-head output gate ``o_h = sigmoid(x
+  W_g)_h * o_h``.
 - ``linear_attention``: one fixed-size recurrent state per SEQUENCE
   (``ssm_state`` ``[num_state_slots, H, dk, dv]`` float32) and the short
   convolution's tail (``conv_tail`` ``[num_state_slots, K - 1, channels]``),
@@ -81,7 +90,7 @@ from unicore_tpu.ops import moe
 from unicore_tpu.ops.gated_delta_rule import gated_delta_rule, short_conv
 
 from .multihead_attention import bert_init
-from .rotary import apply_rotary_qk
+from .rotary import RotarySpec, apply_rotary_qk, apply_rotary_spec
 
 FULL, LINEAR, CONV = "full_attention", "linear_attention", "conv"
 LATENT = "latent_attention"
@@ -118,6 +127,24 @@ class LatentSpec:
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionSpec:
+    """What one ``full_attention`` layer has of its own, where the layers
+    of a model differ: ``num_heads`` query heads of ``head_dim`` over the
+    decoder's K/V heads; ``window`` > 0: a sliding layer; ``rotary``: what
+    it rotates (None: nothing); no QK-norm (a layer with a spec has
+    none); ``gate``: a per-head sigmoid gate on the heads' outputs, from
+    the layer's input; ``three_pass``: the serve kernel's float32 dots in
+    three bfloat16 passes (``ops/pallas/paged_attention.py`` says who
+    asks)."""
+    num_heads: int
+    head_dim: int
+    window: int = 0
+    rotary: Optional[RotarySpec] = None
+    gate: bool = False
+    three_pass: bool = False
 
 
 def _float32_in_float32(a, b):
@@ -184,42 +211,57 @@ class FullAttentionMixer(nn.Module):
     kv_heads: int = 0            # 0: as many as query heads
     qk_norm_per_head: bool = False
     rope_theta: float = 0.0      # 0: no rotary
+    # the layer's own (module docstring); None: the fields above, heads
+    # of ``embed_dim // num_heads``, QK-norm, no window, no gate
+    spec: Optional[AttentionSpec] = None
 
     @nn.compact
     def __call__(self, x, positions=None, paged=None):
         B, T, D = x.shape
-        H, hd = self.num_heads, self.embed_dim // self.num_heads
+        sp = self.spec
+        H = sp.num_heads if sp else self.num_heads
+        hd = sp.head_dim if sp else self.embed_dim // H
         KV = self.kv_heads or H
+        normed = sp is None
+        window = sp.window if sp else 0
         # the norm over the whole projection (Olmo), or over each head
-        whole = not self.qk_norm_per_head
-        q = Linear(D, name="q_proj")(x)
+        whole = normed and not self.qk_norm_per_head
+        q = Linear(H * hd, name="q_proj")(x)
         if whole:
-            q = RMSNorm(D, self.eps, name="q_norm")(q)
+            q = RMSNorm(H * hd, self.eps, name="q_norm")(q)
         k = Linear(KV * hd, name="k_proj")(x)
         if whole:
             k = RMSNorm(KV * hd, self.eps, name="k_norm")(k)
         v = Linear(KV * hd, name="v_proj")(x)
         q = q.reshape(B, T, H, hd)
         k, v = (t.reshape(B, T, KV, hd) for t in (k, v))
-        if not whole:
+        if normed and not whole:
             q = RMSNorm(hd, self.eps, name="q_norm")(q)
             k = RMSNorm(hd, self.eps, name="k_norm")(k)
-        if self.rope_theta:
+        if sp is not None and sp.rotary is not None:
+            q, k = apply_rotary_spec(q, k, sp.rotary, positions=positions)
+        elif sp is None and self.rope_theta:
             q, k = apply_rotary_qk(q, k, base=self.rope_theta,
                                    positions=positions)
         scale = hd ** -0.5
-        ready = paged is not None and self.has_variable("pagedkv", "k_pages")
+        # a sliding layer's pages are the window kind's, under their own
+        # names: the engine tells the two kinds' bytes apart by them
+        names = ("k_window_pages", "v_window_pages") if window else (
+            "k_pages", "v_pages")
+        ready = paged is not None and self.has_variable("pagedkv", names[0])
         if paged is not None:
-            nslots = None if ready else int(paged.num_slots)
-            k_pages = self.variable("pagedkv", "k_pages", jnp.zeros,
+            nslots = None if ready else int(
+                paged.num_window_slots if window else paged.num_slots)
+            k_pages = self.variable("pagedkv", names[0], jnp.zeros,
                                     (nslots, KV * hd), k.dtype)
-            v_pages = self.variable("pagedkv", "v_pages", jnp.zeros,
+            v_pages = self.variable("pagedkv", names[1], jnp.zeros,
                                     (nslots, KV * hd), v.dtype)
         if ready:
             from unicore_tpu.serve.attention import write_and_attend
 
-            o = write_and_attend(q, k, v, k_pages, v_pages, paged, positions,
-                                 scale)
+            o = write_and_attend(
+                q, k, v, k_pages, v_pages, paged, positions, scale,
+                window=window, three_pass=bool(sp and sp.three_pass))
         else:
             from unicore_tpu.utils import causal_iota_mask
 
@@ -227,9 +269,17 @@ class FullAttentionMixer(nn.Module):
                 k, v = (jnp.repeat(t, H // KV, axis=2) for t in (k, v))
             s = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
             s = s + causal_iota_mask(T, T)[None, None]
+            if window:
+                at = jnp.arange(T)
+                s = s + jnp.where(at[None, :] <= at[:, None] - window,
+                                  -1e30, 0.0)[None, None]
             p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        return Linear(D, name="o_proj")(o.reshape(B, T, D))
+        if sp is not None and sp.gate:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(Linear(H, name="g_proj")(x))
+                o = o * gate[..., None].astype(o.dtype)
+        return Linear(D, name="o_proj")(o.reshape(B, T, H * hd))
 
 
 def _einsum(spec, a, b):
@@ -531,6 +581,7 @@ class PatternDecoderLayer(nn.Module):
     norm_placement: str = "output"
     experts: Optional[ExpertSpec] = None    # None: the dense FFN
     latent: Optional[LatentSpec] = None     # a latent_attention layer's
+    attention: Optional[AttentionSpec] = None  # a full_attention layer's own
 
     @nn.compact
     def __call__(self, x, positions=None, paged=None):
@@ -541,7 +592,8 @@ class PatternDecoderLayer(nn.Module):
         elif self.mixer == FULL:
             mixer = FullAttentionMixer(
                 self.embed_dim, self.num_heads, self.eps, self.kv_heads,
-                self.qk_norm_per_head, self.rope_theta, name="self_attn")
+                self.qk_norm_per_head, self.rope_theta, self.attention,
+                name="self_attn")
         elif self.mixer == LINEAR:
             mixer = LinearAttentionMixer(
                 self.embed_dim, self.linear_num_heads,
@@ -601,6 +653,9 @@ class PatternDecoder(nn.Module):
     ffn_types: Tuple[str, ...] = ()         # empty: dense everywhere
     experts: Optional[ExpertSpec] = None
     latent: Optional[LatentSpec] = None
+    # one entry a layer where the full_attention layers differ (None for
+    # a layer of another kind); empty: the fields above for all of them
+    attention: Tuple[Optional[AttentionSpec], ...] = ()
 
     @nn.compact
     def __call__(self, x, positions: Optional[jnp.ndarray] = None,
@@ -615,6 +670,7 @@ class PatternDecoder(nn.Module):
                 self.qk_norm_per_head, self.rope_theta,
                 self.short_conv_kernel_dim, self.norm_placement,
                 self.experts if sparse else None, self.latent,
+                self.attention[i] if self.attention else None,
                 name=f"layers_{i}",
             )(x, positions=positions, paged=paged)
         return RMSNorm(self.embed_dim, self.eps, name="final_layer_norm")(x)

@@ -61,13 +61,28 @@ unwritten/stale slots, since every real query position is below the
 row's length.  Inactive query columns (position -1) mask everything and
 come out finite (garbage by contract, discarded by the caller).
 
+A WINDOW (``window`` > 0, a sliding layer) admits for the query at
+position ``p`` the columns ``p - window < c <= p`` and no older one.  A
+row's walk then starts at the block that holds the first column its
+EARLIEST live query sees (``first_blocks``, a third scalar-prefetch
+operand worked out beside the call) and reads no block wholly behind it;
+"block 0" in the invariant above reads "the row's first block", and the
+slot a block lands in counts the blocks walked, not their index.  A block
+the row's later queries cannot see is still walked when an earlier query
+can: the mask decides per query.  With ``window == 0`` the traced program
+is the one it was before windows, equation for equation
+(``tests/test_serve_window.py``).
+
 The rule for float32 operands is the step's: they multiply in three
 bfloat16 passes, as ``Linear`` does outside the kernel at
-``Precision.HIGH`` (``three_pass=True``; the latent step asks for it).
-ONE exception is left: per-head K/V pages (``serve/attention.py``
-``write_and_attend``) take the one pass the kernel had before ``Linear``
-went to ``HIGH``, because every cell that runs them was measured and its
-limits read with it.  Ending the exception is ROADMAP M12's question,
+``Precision.HIGH`` (``three_pass=True``; the latent step asks for it,
+and so does the window model's, ``examples/lm/laguna.py``: at one pass a
+served token now and then got another of its held experts, PERF.md
+section 6, PR 43).  ONE exception is left: the per-head K/V pages of the
+three configurations that had them before (``serve/attention.py``
+``write_and_attend`` without ``three_pass``) take the one pass the kernel
+had before ``Linear`` went to ``HIGH``, because every cell that runs them
+was measured and its limits read with it.  Ending the exception is ROADMAP M12's question,
 not a caller's choice: no third arithmetic, and no new caller of the
 one-pass form.
 
@@ -137,7 +152,7 @@ def pick_pages_per_block(num_table_pages, page_size, head_dim,
 
 
 def vmem_limit_bytes(cells, lanes, heads, blk_slots, kv_itemsize,
-                     q_itemsize):
+                     q_itemsize, three_pass_slabs=0):
     """Scoped VMEM one program may use, from its shapes alone: both
     slots' K and V blocks, the queries and the outputs (each double-
     buffered by the pipeline), the accumulator, the running max and sum
@@ -145,13 +160,21 @@ def vmem_limit_bytes(cells, lanes, heads, blk_slots, kv_itemsize,
     ``_COMPILER_ROOM_BYTES``; never under the chip's default of 16 MB.
     ``opt_1.3b``'s mixed program (128 cells x 2,048 lanes, blocks of 256
     float32 slots) comes to 23 MB, the hybrid's (64 x 3,840) to 27.6 MB,
-    of the 128 MB of VMEM a v5e has."""
+    of the 128 MB of VMEM a v5e has.  ``three_pass_slabs``: the lane
+    slabs of a call that multiplies in three passes; the loop over slabs
+    is unrolled and the compiler keeps the split operands and the three
+    ``[cells, block]`` partial scores of more than one slab alive, two
+    score tiles a slab beyond the first (the window model's mixed
+    program, 1,024 cells x 8 slabs, needed 48.6 MB where the count
+    without them gave 38: compiled for a described v5e, PR 43; the latent
+    step has one slab and asks for nothing more)."""
+    room = max(0, three_pass_slabs - 1) * 2 * cells * blk_slots * 4
     kv = SLOTS * 2 * blk_slots * lanes * kv_itemsize
     piped = 2 * 2 * cells * lanes * q_itemsize
     acc = cells * lanes * 4
     stats = 2 * heads * (-(-cells // 8) * 8) * _LANES * 4
     return max(_DEFAULT_SCOPED_VMEM_BYTES,
-               kv + piped + acc + stats + _COMPILER_ROOM_BYTES)
+               kv + piped + acc + stats + _COMPILER_ROOM_BYTES + room)
 
 
 def _dot(a, b, contract, three_pass):
@@ -175,15 +198,22 @@ def _dot(a, b, contract, three_pass):
     return dot(a_hi, b_hi) + dot(a_hi, b_lo) + dot(a_lo, b_hi)
 
 
-def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
-            k_scr, v_scr, m_scr, l_scr, acc_scr, sems, carry, *, page_size,
-            pages_per_block, scale, heads, head_dim, three_pass):
+def _kernel(pt_ref, len_ref, *refs, page_size, pages_per_block, scale,
+            heads, head_dim, three_pass, window=0):
+    # a windowed call brings each row's first block as a third prefetched
+    # scalar operand; without a window every row starts at block 0
+    first_ref, refs = (refs[0], refs[1:]) if window else (None, refs)
+    (pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
+     k_scr, v_scr, m_scr, l_scr, acc_scr, sems, carry) = refs
     b = pl.program_id(0)
     n_rows = pl.num_programs(0)
     length = len_ref[b]
     n_table = pt_ref.shape[1]
     blk_slots = pages_per_block * page_size
     n_blocks = pl.cdiv(length, blk_slots)
+    # the blocks ``first .. n_blocks - 1`` are walked (a live row has one:
+    # ``first_blocks`` holds ``first`` under the row's last block)
+    first = first_ref[b] if window else 0
     group = slab_heads(heads, head_dim)
     slab = group * head_dim
     t = q_ref.shape[1]
@@ -231,14 +261,14 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
     has_next = nxt < n_rows
     slot0 = carry[_NEXT_SLOT]
 
-    # the first live row of the call: nobody fetched its block 0
+    # the first live row of the call: nobody fetched its first block
     @pl.when(jnp.logical_and(n_blocks > 0, carry[_IN_FLIGHT] == 0))
     def _():
-        for cp in copies(b, 0, slot0):
+        for cp in copies(b, first, slot0):
             cp.start()
 
     def body(i, loop_carry):
-        slot = (slot0 + i) % SLOTS
+        slot = (slot0 + (i - first if window else i)) % SLOTS
         # the block after this one goes into the other slot while this
         # one is multiplied: this row's next block or, under its last,
         # block 0 of the next live row.  The other slot was read by the
@@ -248,7 +278,9 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
         @pl.when(jnp.logical_or(in_row, has_next))
         def _():
             row = jnp.where(in_row, b, jnp.minimum(nxt, n_rows - 1))
-            for cp in copies(row, jnp.where(in_row, i + 1, 0), 1 - slot):
+            for cp in copies(row, jnp.where(
+                    in_row, i + 1, first_ref[row] if window else 0),
+                    1 - slot):
                 cp.start()
 
         # started once (by the block before, or above), waited once, here,
@@ -261,6 +293,9 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
         # bottom-right causal + unwritten-slot exclusion in one compare
         # (every real query position is < length by construction)
         valid = cols <= pos_q  # [T, S]
+        if window:
+            # and nothing older than the query's window
+            valid = jnp.logical_and(valid, cols > pos_q - window)
         for sl in range(heads // group):
             lanes = pl.ds(sl * slab, slab)
             q = q_ref[0, :, lanes] * scale  # [T, slab]
@@ -292,14 +327,15 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
             acc_scr[:, lanes] = acc
         return loop_carry
 
-    jax.lax.fori_loop(0, n_blocks, body, 0)
+    jax.lax.fori_loop(first, n_blocks, body, 0)
 
     # a live row hands on what its last block started; the last live row
     # started nothing, so the call ends with no DMA in flight (invariant 4)
     @pl.when(n_blocks > 0)
     def _():
         carry[_IN_FLIGHT] = has_next.astype(jnp.int32)
-        carry[_NEXT_SLOT] = (slot0 + n_blocks) % SLOTS
+        carry[_NEXT_SLOT] = (
+            slot0 + (n_blocks - first if window else n_blocks)) % SLOTS
 
     for sl in range(heads // group):
         lanes = pl.ds(sl * slab, slab)
@@ -336,19 +372,30 @@ def _kernel(pt_ref, len_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("page_size", "pages_per_block", "scale", "heads",
-                     "head_dim", "interpret", "three_pass"),
+                     "head_dim", "interpret", "three_pass", "window"),
 )
 def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
           page_size, pages_per_block, scale, heads, head_dim, interpret,
-          three_pass=False):
+          three_pass=False, window=0):
     bsz, t, hd = q3.shape
-    qo_spec = pl.BlockSpec((1, t, hd), lambda b, pt, ln: (b, 0, 0))
+    blk_slots = pages_per_block * page_size
+    # scalar-prefetch operands: tables and lengths and, under a window,
+    # each row's first block
+    scalars = [page_table.astype(jnp.int32), lengths.astype(jnp.int32)]
+    kernel = functools.partial(
+        _kernel, page_size=page_size, pages_per_block=pages_per_block,
+        scale=scale, heads=heads, head_dim=head_dim,
+        three_pass=three_pass,
+    )
+    if window:
+        scalars.append(first_blocks(positions, lengths, window, blk_slots))
+        kernel = functools.partial(kernel, window=window)
+    qo_spec = pl.BlockSpec((1, t, hd), lambda b, *_: (b, 0, 0))
     # [B, T, 1]: the block's last two dims are the array's own, which
     # the TPU lowering takes at any T (a (1, T) block of [B, T] is not)
-    pos_spec = pl.BlockSpec((1, t, 1), lambda b, pt, ln: (b, 0, 0))
-    blk_slots = pages_per_block * page_size
+    pos_spec = pl.BlockSpec((1, t, 1), lambda b, *_: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(bsz,),
         in_specs=[
             pos_spec,
@@ -368,11 +415,7 @@ def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
         ],
     )
     return pl.pallas_call(
-        functools.partial(
-            _kernel, page_size=page_size, pages_per_block=pages_per_block,
-            scale=scale, heads=heads, head_dim=head_dim,
-            three_pass=three_pass,
-        ),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, t, hd), q3.dtype),
         interpret=interpret,
@@ -383,22 +426,41 @@ def _call(q3, k_pages3, v_pages3, page_table, lengths, positions, *,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem_limit_bytes(
                 t, hd, heads, blk_slots, k_pages3.dtype.itemsize,
-                q3.dtype.itemsize),
+                q3.dtype.itemsize,
+                heads // slab_heads(heads, head_dim) if three_pass else 0),
         ),
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      positions.astype(jnp.int32)[:, :, None], q3, k_pages3, v_pages3)
+    )(*scalars, positions.astype(jnp.int32)[:, :, None], q3, k_pages3,
+      v_pages3)
+
+
+def first_blocks(positions, lengths, window, blk_slots):
+    """``[B]`` int32: the block each row's walk starts at under
+    ``window``, the one that holds the first column its earliest live
+    query sees (``min position - window + 1``), never past the row's last
+    block, 0 for a row with no live query."""
+    positions = positions.astype(jnp.int32)
+    live = positions >= 0
+    earliest = jnp.min(jnp.where(live, positions, jnp.iinfo(jnp.int32).max),
+                       axis=1)
+    column = jnp.where(jnp.any(live, axis=1),
+                       jnp.maximum(earliest - (window - 1), 0), 0)
+    last = jnp.maximum(-(-lengths.astype(jnp.int32) // blk_slots) - 1, 0)
+    return jnp.minimum(column // blk_slots, last)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, positions,
                            lengths, *, page_size, scale,
-                           pages_per_block=None, three_pass=False):
+                           pages_per_block=None, three_pass=False,
+                           window=0):
     """Mixed prefill+decode paged attention: q [B, T, H, D], flat pools
     [num_slots, H*D], page_table [B, P] (pad rows with page 0),
     positions [B, T] per-token global positions (-1 = inactive),
     lengths [B] valid token count incl. this step's (0 = inactive row).
     Returns [B, T, H, D].  ``three_pass``: float32 queries against a
     float32 pool multiply in three bfloat16 passes (``_dot``; the module
-    docstring says who asks); every other operand type takes one."""
+    docstring says who asks); every other operand type takes one.
+    ``window`` > 0: the query at position ``p`` sees the columns ``p -
+    window < c <= p`` only (module docstring)."""
     bsz, t, heads, d = q.shape
     num_pages = k_pages.shape[0] // page_size
     if pages_per_block is None:
@@ -416,6 +478,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, positions,
         interpret=pallas_interpret(),
         three_pass=bool(three_pass and q.dtype == jnp.float32
                         and k_pages.dtype == jnp.float32),
+        # no keyword where there is no window: the call, and so its
+        # trace, is the one it was
+        **({"window": int(window)} if window else {}),
     )
     return out.reshape(bsz, t, heads, d)
 

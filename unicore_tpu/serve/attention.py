@@ -71,7 +71,24 @@ class PagedMeta:
     (the layout of its tokens is the one above, like any other model's):
     the state-store slot of each row's SEQUENCE (a row is assigned anew
     every step, the state is not), out of range for an empty row so that
-    its write is dropped; ``num_state_slots`` sizes the store at init."""
+    its write is dropped; ``num_state_slots`` sizes the store at init.
+
+    A model with SLIDING-WINDOW layers gets a second kind of page for
+    them (``serve/kv_pool.py``), addressed by a table of its own:
+    ``window_page_table`` [B, Pw] int32 holds, for each row, the window
+    pages from the one with the first key the row's first query sees to
+    the one its last token is written to, padded with the window pool's
+    trash page 0; ``window_base`` [B] int32 is the position of the first
+    slot of that table's first page (a multiple of the page size), so
+    column ``c`` of the row's window view is position ``window_base +
+    c``; ``window_slot_mapping`` [N] int32 the tokens' write slots in the
+    window pool; ``num_window_slots`` sizes the window layers' pool
+    variables at init.  :meth:`windowed` hands a window layer the same
+    meta with these in the places of ``page_table`` / ``slot_mapping`` and
+    positions counted from ``window_base`` (:meth:`window_positions`): the trimmed table keeps
+    "column j is position j" (in the row's own frame), so the kernel's
+    masks are compares as they always were.  None for a model without a
+    window: its operands are what they were."""
 
     page_table: Any
     slot_mapping: Any
@@ -84,6 +101,10 @@ class PagedMeta:
     rect_positions: Any = None
     token_cell: Any = None
     last_token: Any = None
+    window_page_table: Any = None
+    window_slot_mapping: Any = None
+    window_base: Any = None
+    num_window_slots: int = 0
 
     # the two mixers that need rows (attention's kernel, a recurrent
     # layer's chain) go through these three; everything else runs on the
@@ -107,6 +128,21 @@ class PagedMeta:
             return positions.reshape(self.page_table.shape[0], -1)
         return self.rect_positions
 
+    def windowed(self):
+        """The meta as a sliding-window layer reads it: the window table
+        and write slots in the places of the global ones, lengths counted
+        from each row's ``window_base`` (0 stays 0)."""
+        return dataclasses.replace(
+            self, page_table=self.window_page_table,
+            slot_mapping=self.window_slot_mapping,
+            lengths=jnp.maximum(self.lengths - self.window_base, 0))
+
+    def window_positions(self, row_positions):
+        """``row_positions`` [B, width] counted from each row's
+        ``window_base`` (-1 stays -1)."""
+        return jnp.where(row_positions >= 0,
+                         row_positions - self.window_base[:, None], -1)
+
     def to_tokens(self, x, lead):
         """The way back: ``x`` [B, width, ...] in the tokens' own layout
         ``[*lead, ...]``."""
@@ -127,12 +163,13 @@ def gather_slots(pages, page_table, page_size):
 
 
 def paged_attention_reference(q, k_pages, v_pages, page_table, positions,
-                              lengths, page_size, scale):
+                              lengths, page_size, scale, window=0):
     """Eager gather-based paged attention (the oracle; CPU tier-1 path).
 
     ``positions`` [B, T]: global position of each query row (-1 =
     inactive row -> fully masked; output rows for those are garbage by
-    contract and discarded by the caller)."""
+    contract and discarded by the caller).  ``window`` > 0: a query sees
+    the ``window`` columns up to its own and nothing older."""
     del lengths  # the position compare subsumes the length mask
     bsz, _, heads, d = q.shape
     k = gather_slots(k_pages, page_table, page_size).reshape(
@@ -147,6 +184,10 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, positions,
     s = s + jnp.where(
         cols[None, None, None, :] > positions[:, None, :, None], -1e30, 0.0
     )
+    if window:
+        s = s + jnp.where(
+            cols[None, None, None, :] <= positions[:, None, :, None] - window,
+            -1e30, 0.0)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
@@ -183,13 +224,17 @@ def _kernel_choice(pages_per_block):
 
 
 def paged_attention(q, k_pages, v_pages, page_table, positions, lengths,
-                    page_size, scale):
-    """Dispatching paged attention (see module docstring)."""
+                    page_size, scale, window=0, three_pass=False):
+    """Dispatching paged attention (see module docstring).  Without
+    ``window`` / ``three_pass`` the call that reaches the kernel is the
+    call it was."""
     from unicore_tpu.ops.backend import note_dispatch
 
     pages_per_block = _kernel_ok(q, k_pages, page_table, page_size)
-    desc = "b%d w%d h%d d%d page%d %s%s" % (
-        *q.shape, page_size, q.dtype.name, _kernel_choice(pages_per_block))
+    desc = "b%d w%d h%d d%d page%d %s%s%s%s" % (
+        *q.shape, page_size, q.dtype.name, _kernel_choice(pages_per_block),
+        " window%d" % window if window else "",
+        " three-pass" if three_pass else "")
     if note_dispatch("ragged_paged_attention", desc,
                      pages_per_block is not None):
         from unicore_tpu.ops.pallas import paged_attention as pl_pa
@@ -197,15 +242,17 @@ def paged_attention(q, k_pages, v_pages, page_table, positions, lengths,
         return pl_pa.ragged_paged_attention(
             q, k_pages, v_pages, page_table, positions, lengths,
             page_size=page_size, scale=scale,
-            pages_per_block=pages_per_block,
+            pages_per_block=pages_per_block, three_pass=three_pass,
+            window=window,
         )
     return paged_attention_reference(
         q, k_pages, v_pages, page_table, positions, lengths, page_size,
-        scale,
+        scale, window=window,
     )
 
 
-def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
+def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale,
+                     window=0, three_pass=False):
     """One layer's paged step: this step's ``k``/``v`` (token-major,
     ``[..., H, D]`` over N tokens) scatter into the pool variables
     ``k_pages``/``v_pages`` (flax variables of collection ``"pagedkv"``,
@@ -223,13 +270,24 @@ def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
     group then ride as ``g`` query CELLS at one position, ``[B, T, kv *
     g, D] -> [B, T * g, kv, D]`` with the positions repeated, so the
     kernel runs as it is over ``kv`` heads and reads each page once for
-    the heads that share it.  With ``g == 1`` nothing is folded."""
+    the heads that share it.  With ``g == 1`` nothing is folded.
+
+    ``window`` > 0 (a sliding layer): ``k_pages`` / ``v_pages`` are the
+    window kind's, written and read through the window table
+    (:meth:`PagedMeta.windowed`), and a query sees ``window`` keys up to
+    its own.  ``three_pass``: the kernel's float32 dots in three bfloat16
+    passes, where a model's step asks for them (the kernel's module
+    docstring)."""
+    if window:
+        paged = paged.windowed()
     width = k_pages.value.shape[-1]
     k_pages.value = k_pages.value.at[paged.slot_mapping].set(
         k.astype(k_pages.value.dtype).reshape(-1, width))
     v_pages.value = v_pages.value.at[paged.slot_mapping].set(
         v.astype(v_pages.value.dtype).reshape(-1, width))
     rows, row_positions = paged.to_rows(q), paged.row_positions(positions)
+    if window:
+        row_positions = paged.window_positions(row_positions)
     B, T, H, D = rows.shape
     g = H * D // width
     if g > 1:
@@ -240,6 +298,7 @@ def write_and_attend(q, k, v, k_pages, v_pages, paged, positions, scale):
         rows, k_pages.value, v_pages.value,
         page_table=paged.page_table, positions=row_positions,
         lengths=paged.lengths, page_size=paged.page_size, scale=scale,
+        window=window, three_pass=three_pass,
     )
     if g > 1:
         o = o.reshape(B, T, g, H // g, D).swapaxes(2, 3).reshape(B, T, H, D)

@@ -75,6 +75,24 @@ one-token row riding a mixed step among them.  Both are the step log's
 ``carried`` summed by width (below), for every model: the counters stay
 only because ``tests/bench/test_bench_run_pangu.py`` reads them.
 
+A model with SLIDING-WINDOW layers (``model.attention_window`` > 0:
+window and global attention mixed) holds TWO KINDS of page
+(``kv_pool.py``): the global layers' keep every token, the sliding
+layers' are taken as a sequence moves on and RELEASED, at the step
+boundary, once no query still to come can see them
+(:meth:`ServeEngine._advance_window`, the ``serve/window-release``
+span).  A row's window table is trimmed to what the row sees and comes
+with the position of its first slot; table, base and write slots are
+three more entries of the step's one packed operand.  The window kind is
+sized from shape for a full batch (every row's reserve and one step's
+tokens), never configured; prefix hits are refused (a hit would start
+behind pages released long ago).  ``stats["cache_bytes_per_token"]`` is
+then the global layers', ``["cache_bytes_per_row_window"]`` what a row
+holds at most in the sliding layers, and every step's row in the step
+log says what the pool held and what ONE table for all layers would have
+held for the same sequences.  For a model without a window none of this
+exists: one kind of page, the operands it always had.
+
 THE STEP IN FLIGHT.  The device starts a step when the host launches
 it, and a host that fetches step N's tokens before it plans, assembles,
 transfers and launches step N+1 leaves the device idle for all of that,
@@ -151,7 +169,9 @@ preallocated ring of 4,096 rows written by index.
 :attr:`ServeEngine.step_log` gets one row a step EMITTED, at the end of
 ``_emit_step``: ``ordinal``, ``width``, ``carried`` / ``capacity`` (the
 list's fill), ``decode_rows`` handed out, ``ran_ahead`` (launched with
-a step in flight), ``emitted_at`` on ``time.perf_counter``,
+a step in flight), ``resident_kv_bytes`` / ``resident_kv_bytes_one_table``
+(:meth:`ServeEngine.kv_residency` as the row is written),
+``emitted_at`` on ``time.perf_counter``,
 ``device_s`` (how long the device had the step: launch, or the step
 before it done, to fetched) and ``thread_cpu_s`` / ``process_cpu_s``,
 the advance of ``time.thread_time`` and ``time.process_time`` since the
@@ -179,6 +199,9 @@ annotation costs well under a microsecond::
                               step in flight: the rule
         serve/admit           Scheduler.admit: prefix match, can_alloc, alloc
       serve/plan              _plan_rows
+        serve/window-release  a model with a window: pages behind every
+                              running sequence's window handed back, the
+                              planned rows' window pages taken
       serve/assemble          the numpy rows of one dispatch
         serve/state           a recurrent model's rows: each row's state slot
                               looked up, rows starting from zero counted
@@ -256,7 +279,7 @@ from unicore_tpu.ops import moe
 
 from . import step_log
 from .attention import PagedMeta
-from .kv_pool import PagedKVPool, PoolExhausted
+from .kv_pool import PagedKVPool, PoolExhausted, window_kind_for
 from .sampling import finite_rows, sample_tokens, step_keys
 from .scheduler import DEFAULT_REQUEST_RETRIES, Scheduler
 
@@ -270,6 +293,7 @@ SPAN_SCHEDULE = "serve/schedule"
 SPAN_ADMIT = "serve/admit"
 SPAN_STATE = "serve/state"
 SPAN_PLAN = "serve/plan"
+SPAN_WINDOW_RELEASE = "serve/window-release"
 SPAN_ASSEMBLE = "serve/assemble"
 SPAN_TRANSFER = "serve/transfer"
 SPAN_DISPATCH = "serve/dispatch-w{width}"
@@ -379,36 +403,20 @@ class ServeEngine:
         # beside its pages (module docstring): a slot per batch row, and
         # no prefix hits, which would start a prompt where no state is
         self.recurrent = bool(getattr(model, "has_recurrent_state", False))
-        self.prefix_cache_refused = bool(self.recurrent and prefix_cache)
+        # a model with sliding-window layers keeps a second kind of page
+        # for them (module docstring), and takes no prefix hits either: a
+        # hit would start where the window pages before it are long gone
+        self.window = int(getattr(model, "attention_window", 0) or 0)
+        self.prefix_cache_refused = bool(
+            (self.recurrent or self.window) and prefix_cache)
         if self.prefix_cache_refused:
             logger.warning(
-                "prefix cache REFUSED: the model has recurrent layers, and "
-                "a prefix hit would start a prompt past its shared pages "
-                "with no recurrent state for that boundary; every prompt "
-                "prefills from position 0")
-        self.pool = PagedKVPool(
-            self.num_pages, self.page_size,
-            prefix_cache=prefix_cache and not self.recurrent,
-            state_slots=self.max_batch if self.recurrent else 0)
-        self.table_width = self.pool.pages_for(self.max_context)
-        # unified=False is the bench A/B baseline: prefill rows and
-        # decode rows dispatch as two separate programs per step (the
-        # old split-program behavior) instead of one mixed dispatch
-        self.unified = bool(unified)
-        self.scheduler = Scheduler(
-            self.pool, self.max_batch,
-            prefill_token_budget=self.prefill_token_budget,
-            chaos_rate=chaos_rate, chaos_rng=chaos_rng,
-            max_waiting=max_waiting, request_retries=request_retries,
-        )
-        self.pages = self._init_pages()
-        # expert layers that count their routing on the device, and
-        # whether they hold a share of their experts (a third counter)
-        loads, _, held = self._moe_counters(self.pages)
-        self.moe_layers, self.moe_share = len(loads), bool(held)
-        # layers whose cache is a latent page (module docstring)
-        self.latent_layers = len(self._leaves_named(self.pages,
-                                                    "latent_pages"))
+                "prefix cache REFUSED: the model has %s, and a prefix hit "
+                "would start a prompt past its shared pages with no %s for "
+                "that boundary; every prompt prefills from position 0",
+                *(("recurrent layers", "recurrent state") if self.recurrent
+                  else ("sliding-window layers",
+                        "window pages of the tokens before it")))
         # prefill-chunk width: a prompt is admitted in <= this many
         # tokens per ragged step (bounded-TTFT slices).  0 = the default
         chunk = int(prefill_chunk) or DEFAULT_PREFILL_CHUNK
@@ -421,6 +429,39 @@ class ServeEngine:
         self.mixed_tokens = min(
             self.max_batch * self.prefill_chunk,
             max(MIXED_STEP_TOKENS, self.max_batch + self.prefill_chunk))
+        self.pool = PagedKVPool(
+            self.num_pages, self.page_size,
+            prefix_cache=prefix_cache and not self.prefix_cache_refused,
+            state_slots=self.max_batch if self.recurrent else 0,
+            # the window kind, from shape (module docstring)
+            **window_kind_for(self.window, self.page_size, self.max_batch,
+                              self.mixed_tokens))
+        self.table_width = self.pool.pages_for(self.max_context)
+        # pages of a row's window table: what one chunk's queries see
+        self.window_table_width = (
+            self.pool.window_row_pages(self.prefill_chunk)
+            if self.window else 0)
+        # unified=False is the bench A/B baseline: prefill rows and
+        # decode rows dispatch as two separate programs per step (the
+        # old split-program behavior) instead of one mixed dispatch
+        self.unified = bool(unified)
+        self.scheduler = Scheduler(
+            self.pool, self.max_batch,
+            prefill_token_budget=self.prefill_token_budget,
+            chaos_rate=chaos_rate, chaos_rng=chaos_rng,
+            max_waiting=max_waiting, request_retries=request_retries,
+        )
+        self.pages = self._init_pages()
+        # bytes a token takes in the sliding layers' pages (0: no window)
+        self._window_slot_bytes = self._slot_bytes("k_window_pages",
+                                                   "v_window_pages")
+        # expert layers that count their routing on the device, and
+        # whether they hold a share of their experts (a third counter)
+        loads, _, held = self._moe_counters(self.pages)
+        self.moe_layers, self.moe_share = len(loads), bool(held)
+        # layers whose cache is a latent page (module docstring)
+        self.latent_layers = len(self._leaves_named(self.pages,
+                                                    "latent_pages"))
         # the chunk-size -> compiled-width map, overridable so the
         # static audit (analysis/hlo_audit.py UL205) can check that it
         # never produces a lowering outside serve_step_widths()
@@ -508,10 +549,13 @@ class ServeEngine:
             # unless the layers hold a share)
             "moe_assignments_held": 0,
             # what one token holds in the pool over all layers, bytes
-            "cache_bytes_per_token": sum(
-                x.shape[1] * x.dtype.itemsize
-                for name in ("k_pages", "v_pages", "latent_pages")
-                for x in self._leaves_named(self.pages, name)),
+            # (a model with a window: over its GLOBAL layers) and what a
+            # row holds at most in the sliding layers, whatever its length
+            "cache_bytes_per_token": self._slot_bytes(
+                "k_pages", "v_pages", "latent_pages"),
+            "cache_bytes_per_row_window": (
+                self.window_table_width * self.page_size
+                * self._window_slot_bytes),
             # latent attention (module docstring): tokens the decode
             # width served, tokens the prefill width served (the step
             # log's ``carried`` by width; a benchmark test reads these)
@@ -526,6 +570,27 @@ class ServeEngine:
 
     # -- pool buffers --------------------------------------------------
 
+    def _slot_bytes(self, *names):
+        """Bytes one slot (one token) takes in the ``pagedkv`` leaves
+        called ``names``, over all layers."""
+        return sum(x.shape[1] * x.dtype.itemsize for name in names
+                   for x in self._leaves_named(self.pages, name))
+
+    def kv_residency(self):
+        """``(resident_kv_bytes, resident_kv_bytes_one_table)``: what the
+        pool's pages in use hold now, and what the same sequences would
+        hold if every layer kept every token (one table for all layers).
+        Counted from the two kinds' pages in use, no walk: without prefix
+        sharing the global pages in use ARE the sequences' lengths in
+        pages.  Equal for a model with one kind."""
+        page = self.page_size * self.stats["cache_bytes_per_token"]
+        held = self.pool.global_pages_in_use
+        if not self.window:
+            return held * page, held * page
+        window_page = self.page_size * self._window_slot_bytes
+        return (held * page + self.pool.window_pages_in_use * window_page,
+                held * (page + window_page))
+
     def _init_pages(self):
         """Allocate the per-layer k/v page buffers once (eval_shape over
         flax init — zero FLOPs, exactly like the dense ``init_cache``)."""
@@ -537,6 +602,7 @@ class ServeEngine:
             page_size=self.page_size,
             num_slots=self.num_slots,
             num_state_slots=self.pool.num_state_slots,
+            num_window_slots=self.pool.num_window_pages * self.page_size,
         )
         shapes = jax.eval_shape(
             lambda key, p: self.model.init(
@@ -647,7 +713,9 @@ class ServeEngine:
         sampled its token (that step's output is the program's fourth
         input, still on the device), or -1 for the token the host wrote.
         A model that holds a recurrent state gets one operand more, its
-        rows' state slots."""
+        rows' state slots; one with a window three more, each row's
+        window table, its tokens' window write slots and the position of
+        the table's first slot."""
         B, n = self.max_batch, self._step_tokens(width)
         ops = [("tokens", (1, n)), ("positions", (1, n)),
                ("page_table", (B, self.table_width)),
@@ -660,6 +728,9 @@ class ServeEngine:
             ops.append(("state_slots", (B,)))
         if width > 1:
             ops += [("rect_token", (B, width)), ("token_cell", (n,))]
+        if self.window:
+            ops += [("window_page_table", (B, self.window_table_width)),
+                    ("window_slot_mapping", (n,)), ("window_base", (B,))]
         return ops
 
     @staticmethod
@@ -739,6 +810,9 @@ class ServeEngine:
                         o["positions"][0], rect_token, mode="fill",
                         fill_value=-1),
                     token_cell=o.get("token_cell"), last_token=o["last"],
+                    window_page_table=o.get("window_page_table"),
+                    window_slot_mapping=o.get("window_slot_mapping"),
+                    window_base=o.get("window_base"),
                 )
                 logits, mutated = model.apply(
                     {"params": params, "pagedkv": pages}, o["tokens"],
@@ -926,6 +1000,25 @@ class ServeEngine:
                 break  # one row per sequence: the state is a chain
         return rows
 
+    def _advance_window(self, rows):
+        """The window kind at the step boundary (a model with a window):
+        hand back, for EVERY running sequence, the window pages no query
+        still to come can see (its next query sits at ``written()``), then
+        take the pages the planned rows write.  The step in flight may
+        still read what is handed back here; the device's order of steps
+        makes the reuse safe (``kv_pool.py``).  Releasing first, and for
+        all, is what keeps the pool's counted capacity true: nobody
+        holds more than its reserve beside what THIS step carries."""
+        pool = self.pool
+        with _span(SPAN_WINDOW_RELEASE):
+            for seq in self.scheduler.running:
+                pool.window_release(seq.sid, seq.written())
+        upto = {}
+        for seq, start, m, _, _ in rows:
+            upto[seq.sid] = start + m  # rows per seq are ascending
+        for sid, end in upto.items():
+            pool.window_extend(sid, end)
+
     def _dispatch(self, rows):
         """ONE ragged step over planned ``rows`` (mixed prefill-chunk
         and decode rows): build the per-row metadata, LAUNCH the unified
@@ -1005,6 +1098,17 @@ class ServeEngine:
                     if flat:
                         o["rect_token"][b, :m] = np.arange(at, at + m)
                         o["token_cell"][mine] = b * w + np.arange(m)
+                    if self.window:
+                        # the row's trimmed window table, where it starts,
+                        # and the tokens' write slots in the window kind
+                        wpages, base = self.pool.window_view(
+                            seq.sid, start, start + m)
+                        wpages = np.asarray(wpages, np.int32)
+                        o["window_page_table"][b, :len(wpages)] = wpages
+                        o["window_base"][b] = base
+                        o["window_slot_mapping"][mine] = (
+                            wpages[page_idx - base // self.page_size]
+                            * self.page_size + pos % self.page_size)
                     o["lengths"][b] = start + m
                     # the token of the list the row samples from
                     o["last"][b] = at + m - 1
@@ -1027,6 +1131,10 @@ class ServeEngine:
                         o["rect_token"][b] = N
                     o["page_table"][b] = 0
                     o["lengths"][b] = 0
+                    if self.window:
+                        o["window_slot_mapping"][mine] = 0
+                        o["window_page_table"][b] = 0
+                        o["window_base"][b] = 0
                     self._host_fault([seq], "row-assembly", exc)
                     continue
                 live.append((seq, start, m, emit, dec))
@@ -1188,7 +1296,7 @@ class ServeEngine:
                     self._emit(seq, int(toks[b]))
             self.step_log.write(
                 self._steps_emitted, w, step.carried, self._step_tokens(w),
-                decode_rows, step.ran_ahead, dt)
+                decode_rows, step.ran_ahead, dt, *self.kv_residency())
 
     def _emit(self, seq, token):
         """Append one sampled token and settle termination."""
@@ -1424,6 +1532,8 @@ class ServeEngine:
         unification."""
         with _span(SPAN_PLAN):
             rows = self._plan_rows(todo)
+            if self.window:
+                self._advance_window(rows)
         if not rows:
             return
         if self.unified:
@@ -1490,7 +1600,12 @@ class ServeEngine:
             self._settle()
         except Exception:  # noqa: BLE001 - the fault is the caller's to report
             self._in_flight = None
-            for seq in self.scheduler.running:
+            for seq in list(self.scheduler.running):
+                if self.window and seq.launched:
+                    # the window pages behind what it launched are gone:
+                    # its positions cannot be taken up again from
+                    # ``prefilled``, so it prefills anew
+                    self.scheduler.preempt(seq)
                 seq.in_flight = seq.launched = 0
 
     def _step_with_work(self):

@@ -65,6 +65,59 @@ Recurrent state slots (a model with linear-attention layers):
   recurrent layer has no state: the engine builds this pool with
   ``prefix_cache=False`` for such a model (state snapshots at page
   boundaries are what a hit would need).
+
+Two kinds of page (a model with sliding-window layers, ``window > 0``):
+
+- Its global layers keep every token, in the pages above.  A sliding
+  layer's query at position ``p`` sees the keys ``p - window < j <= p``
+  and nothing older, so its layers' pages are a SECOND KIND with device
+  arrays, free list and accounting of their own: a sequence holds a
+  table of each kind, and the window kind's is TRIMMED.  It holds the
+  pages from ``window_first`` (a page index, ``position // page_size``)
+  to the page of the last position planned, in position order, so that
+  the token at position ``p`` lies in entry ``p // page_size -
+  window_first`` at offset ``p % page_size``: "column j is position j"
+  holds in the frame that starts at ``window_first * page_size``, which
+  is all the kernel's masks need (``serve/attention.py``
+  ``PagedMeta.window_base``).  A ring of a fixed number of pages would
+  hold the same bytes, but position ``p`` would lie at column ``p mod
+  ring``: both masks would need the modulus, a row's walk would wrap, and
+  the one kernel every model shares would carry it.  Trimming keeps the
+  kernel's compare and moves the bookkeeping here, to the host.
+- :meth:`window_extend` takes window pages up to the last position a step
+  plans to write; :meth:`window_release` hands back, at the step
+  boundary, every page no query still to come can see: all of its
+  positions are ``<= next position - window``.  Both are counted
+  (``window_stats``).  A released page may be handed to another row in
+  the very NEXT step, while the step that last read it may still be
+  running or even queued behind one (the engine launches a step ahead):
+  that is safe because the device runs the steps it is handed in the
+  order they were launched, one at a time, each with the pool arrays the
+  step before it returned (they are donated from step to step), so the
+  new owner's write is ordered after the old owner's last read by the
+  data dependency itself.  Nothing on the host has to wait.  The same
+  holds for the pages :meth:`free` returns when a sequence ends or is
+  preempted with a step in flight.
+- Capacity is counted, not hoped for: every resident sequence RESERVES
+  :attr:`window_reserve` pages (what a row needs to see its window and
+  write one more page) and the pool keeps ``window_slack`` more for the
+  tokens one step carries beyond that (a prompt's chunks).  Admission
+  asks both kinds: :meth:`can_alloc` refuses a sequence the window kind
+  could not guarantee its reserve.  The engine sizes the window kind for
+  a full batch, so there a free row implies window pages; a smaller pool
+  simply admits fewer.  :meth:`window_extend` still raises
+  :class:`PoolExhausted` if the pages are not there.
+- Prefix hits are refused for such a model (the engine builds the pool
+  with ``prefix_cache=False``): a hit starts a prompt at the matched
+  point, where a sliding layer needs the ``window - 1`` tokens before it.
+  Their window pages were released long ago.  A hit would need them
+  still held (the last ``window`` tokens' window pages registered with
+  the shared global pages, and kept while the prefix is cached) or
+  recomputed (re-prefill the last ``window - 1`` tokens of the match into
+  the window kind only, skipping the global layers' writes).  Neither is
+  built.
+- For a model with no window none of this exists: no second free list,
+  no walk, and the tables and the step's operands are what they were.
 """
 
 import hashlib
@@ -85,12 +138,33 @@ def _page_digest(prev, tokens):
     return h.digest()
 
 
+def window_reserve_pages(window, page_size):
+    """Window pages a resident sequence reserves: what a row holds to see
+    ``window`` keys ending anywhere in a page, and one more page for the
+    rounding of the tokens it writes (0: no window)."""
+    return (window - 2) // page_size + 3 if window else 0
+
+
+def window_kind_for(window, page_size, rows, step_tokens):
+    """The window kind of a pool whose engine has ``rows`` batch rows and
+    carries ``step_tokens`` tokens a step, as :class:`PagedKVPool`'s
+    keywords: every row's reserve, the pages one step's tokens add, the
+    trash page.  ``{}`` for a model without a window."""
+    if not window:
+        return {}
+    slack = -(-int(step_tokens) // page_size)
+    return {"window": window, "window_slack": slack,
+            "num_window_pages": 1 + rows * window_reserve_pages(
+                window, page_size) + slack}
+
+
 class PagedKVPool:
     """Fixed-capacity refcounted page allocator with per-sequence page
     tables and an optional shared-prefix page index."""
 
     def __init__(self, num_pages, page_size, prefix_cache=True,
-                 state_slots=0):
+                 state_slots=0, window=0, num_window_pages=0,
+                 window_slack=0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "reserved trash page)")
@@ -122,6 +196,29 @@ class PagedKVPool:
         # the expensive part of the hot admission path
         self._match_memo = None
         self._index_gen = 0
+        # the window kind (docstring): tokens a sliding layer sees, its
+        # pages (page 0 the trash page, as above), the pages kept beyond
+        # the residents' reserves
+        self.window = int(window)
+        self.num_window_pages = int(num_window_pages)
+        self.window_slack = int(window_slack)
+        if self.window:
+            if self.prefix_cache:
+                raise ValueError(
+                    "a pool with a window kind takes no prefix hits "
+                    "(prefix_cache=False): a hit would start behind "
+                    "window pages released long ago")
+            if self.num_window_pages < 1 + self.window_reserve \
+                    + self.window_slack:
+                raise ValueError(
+                    f"num_window_pages {self.num_window_pages} cannot "
+                    f"hold one sequence's reserve of {self.window_reserve}"
+                    f" pages beside the slack of {self.window_slack} and "
+                    "the trash page")
+        self._window_free = list(range(self.num_window_pages - 1, 0, -1))
+        self._window_tables = {}   # seq_id -> [page, ...], trimmed
+        self._window_first = {}    # seq_id -> page index of the table's [0]
+        self.window_stats = {"taken": 0, "released": 0, "row_pages_peak": 0}
         self.prefix_stats = {
             "lookups": 0, "hits": 0, "tokens_saved": 0,
             "pages_shared": 0, "cache_evictions": 0,
@@ -189,8 +286,12 @@ class PagedKVPool:
     def can_alloc(self, num_tokens, tokens=None):
         """Whether a new sequence of ``num_tokens`` tokens fits —
         with ``tokens`` the check credits shared-prefix pages the
-        allocation would not actually consume."""
+        allocation would not actually consume.  Both kinds of page are
+        asked: the window kind must be able to guarantee one more
+        resident its reserve."""
         if self.num_state_slots and not self._state_free:
+            return False
+        if self.window and not self._window_admits(len(self._tables) + 1):
             return False
         need = self.pages_for(num_tokens)
         shared, shared_pages = self._match_chain(tokens, num_tokens)
@@ -203,7 +304,9 @@ class PagedKVPool:
         alongside :meth:`check_invariants`); a warm prefix cache is
         idle by design."""
         return (not self._tables
-                and self.num_free_pages == self.num_usable_pages)
+                and self.num_free_pages == self.num_usable_pages
+                and len(self._window_free)
+                == max(self.num_window_pages - 1, 0))
 
     # -- page acquisition ----------------------------------------------
 
@@ -260,7 +363,16 @@ class PagedKVPool:
                 f"({shared} shared), "
                 f"{self._new_page_budget(shared_pages)} free"
             )
+        if self.window and not self._window_admits(len(self._tables) + 1):
+            raise PoolExhausted(
+                f"the window kind cannot guarantee sequence {seq_id!r} its "
+                f"reserve of {self.window_reserve} pages "
+                f"({len(self._tables)} resident, "
+                f"{self.num_window_pages - 1} window pages)")
         self._take_state_slot(seq_id)
+        if self.window:
+            self._window_tables[seq_id] = []
+            self._window_first[seq_id] = 0
         self._acquire_shared(shared_pages)
         table = list(shared_pages)
         for _ in range(need):
@@ -315,9 +427,89 @@ class PagedKVPool:
         self._shared_tokens.pop(seq_id, None)
         if self.num_state_slots:
             self._state_free.append(self._state_of.pop(seq_id))
+        if self.window:
+            del self._window_first[seq_id]
+            self._window_free.extend(
+                reversed(self._window_tables.pop(seq_id)))
         for p in reversed(pages):
             self._release(p)
         return pages
+
+    # -- the window kind -----------------------------------------------
+
+    @property
+    def window_reserve(self):
+        return window_reserve_pages(self.window, self.page_size)
+
+    def window_row_pages(self, chunk):
+        """The most window pages ONE row of ``chunk`` query tokens
+        addresses: the keys ``window - 1`` before its first query to its
+        last, wherever they fall in their pages."""
+        return (self.window + int(chunk) - 2) // self.page_size + 2
+
+    def _window_admits(self, residents):
+        return (residents * self.window_reserve + self.window_slack
+                <= self.num_window_pages - 1)
+
+    @property
+    def window_pages_in_use(self):
+        return max(self.num_window_pages - 1, 0) - len(self._window_free)
+
+    @property
+    def global_pages_in_use(self):
+        return self.num_usable_pages - self.num_free_pages
+
+    def window_extend(self, seq_id, upto):
+        """Hold window pages through position ``upto - 1`` (the last one
+        the step being planned writes)."""
+        table = self._window_tables[seq_id]
+        need = (self.pages_for(upto) - self._window_first[seq_id]
+                - len(table))
+        if need > len(self._window_free):
+            raise PoolExhausted(
+                f"sequence {seq_id!r} needs {need} more window page(s), "
+                f"{len(self._window_free)} free")
+        for _ in range(need):
+            table.append(self._window_free.pop())
+        self.window_stats["taken"] += max(need, 0)
+
+    def window_release(self, seq_id, next_position):
+        """Hand back every window page no query still to come can see:
+        the next query sits at ``next_position`` and sees nothing at or
+        before ``next_position - window``.  Returns how many went."""
+        first = self._window_first[seq_id]
+        keep_from = max(first,
+                        (int(next_position) - self.window + 1)
+                        // self.page_size)
+        table = self._window_tables[seq_id]
+        gone = min(keep_from - first, len(table))
+        if gone <= 0:
+            return 0
+        self._window_free.extend(reversed(table[:gone]))
+        del table[:gone]
+        # a table trimmed to nothing restarts at the first page still seen
+        self._window_first[seq_id] = first + gone if table else keep_from
+        self.window_stats["released"] += gone
+        return gone
+
+    def window_view(self, seq_id, start, end):
+        """``(pages, base)`` of a row whose queries sit at ``start ..
+        end - 1``: the window pages from the one with the first key its
+        first query sees to the one its last token is written to, and
+        the position of the first slot of the first of them."""
+        first = self._window_first[seq_id]
+        table = self._window_tables[seq_id]
+        lo = max(first, max(int(start) - self.window + 1, 0)
+                 // self.page_size)
+        hi = (int(end) - 1) // self.page_size
+        if hi - first >= len(table):
+            raise IndexError(
+                f"position {end - 1} beyond the window pages of sequence "
+                f"{seq_id!r} (pages {first}..{first + len(table) - 1})")
+        pages = table[lo - first:hi - first + 1]
+        self.window_stats["row_pages_peak"] = max(
+            self.window_stats["row_pages_peak"], len(pages))
+        return pages, lo * self.page_size
 
     # -- recurrent state slots -----------------------------------------
 
@@ -447,3 +639,24 @@ class PagedKVPool:
             assert len(self._state_free) + len(held) == self.num_state_slots
             assert set(self._state_of) == set(self._tables), (
                 "a state slot must live and die with its sequence's pages")
+        if self.window:
+            wfree = set(self._window_free)
+            assert len(wfree) == len(self._window_free), (
+                "duplicate pages in the window free list")
+            held = [p for t in self._window_tables.values() for p in t]
+            assert len(set(held)) == len(held), "window page held twice"
+            assert not set(held) & wfree, "window page both held and free"
+            assert 0 not in wfree and 0 not in held, (
+                "window trash page 0 was handed out")
+            assert wfree | set(held) == set(
+                range(1, self.num_window_pages)), "window pages leaked"
+            assert (set(self._window_tables) == set(self._window_first)
+                    == set(self._tables)), (
+                "a window table must live and die with its sequence")
+            assert self._window_admits(len(self._tables)), (
+                "more residents than the window kind can guarantee")
+            for sid, table in self._window_tables.items():
+                # never past the sequence's own length, never a page
+                # wholly behind what was released
+                assert (self._window_first[sid] + len(table)
+                        <= self.pages_for(self._lens[sid])), (sid, table)

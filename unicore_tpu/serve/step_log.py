@@ -39,6 +39,11 @@ STEP_ROW = np.dtype([
     ("decode_rows", np.int32),
     # launched with a step in flight
     ("ran_ahead", np.bool_),
+    # what the pool's pages in use held as the row was written, and what
+    # ONE table for all layers would have held for the same sequences
+    # (``ServeEngine.kv_residency``; equal for a model with one kind of
+    # page): a window model's saving, step by step
+    ("resident_kv_bytes", np.int64), ("resident_kv_bytes_one_table", np.int64),
     # ``time.perf_counter`` as the row was written: what ``between``
     # selects by, on the clock of whoever timed the calls
     ("emitted_at", np.float64),
@@ -116,11 +121,13 @@ class StepLog(_Ring):
         self._process_cpu = time.process_time()
 
     def write(self, ordinal, width, carried, capacity, decode_rows,
-              ran_ahead, device_s):
+              ran_ahead, device_s, resident_kv_bytes=0,
+              resident_kv_bytes_one_table=0):
         """The row of one emitted step; reads the three clocks itself."""
         thread_cpu, process_cpu = time.thread_time(), time.process_time()
         self._store((
             ordinal, width, carried, capacity, decode_rows, ran_ahead,
+            resident_kv_bytes, resident_kv_bytes_one_table,
             time.perf_counter(), device_s,
             thread_cpu - self._thread_cpu, process_cpu - self._process_cpu))
         self._thread_cpu, self._process_cpu = thread_cpu, process_cpu
